@@ -1102,6 +1102,29 @@ class Accelerator:
 
         return train_step
 
+    def _state_out_shardings(self, optimizer):
+        """``out_shardings`` that hand ``(params, opt_state)`` back where
+        ``prepare`` placed them (metrics: the compiler's choice), or None when
+        that is not known (several models, state not yet initialised) or there
+        is one device. Left to itself GSPMD shards what the plan replicates —
+        under FSDP the biases and norms came back ``P('dp_shard')`` — so the
+        second call saw new input shardings and compiled the step again."""
+        import jax
+
+        if self.mesh.size == 1 or len(self._models) != 1 or optimizer.opt_state is None:
+            return None
+
+        def placed(tree):
+            # only what prepare placed on this mesh: a leaf that lives
+            # elsewhere (the fp16 scaler's scalars) stays the compiler's choice
+            def on_mesh(x):
+                sharding = getattr(x, "sharding", None)
+                return sharding if getattr(sharding, "mesh", None) == self.mesh else None
+
+            return jax.tree_util.tree_map(on_mesh, tree)
+
+        return placed(self._models[0]), placed(optimizer.opt_state), None
+
     def _track_step(self, step_fn, optimizer, kind: str = "train_step"):
         # The functional loop threads (params, opt_state) locally while
         # ``save_state`` reads ``optimizer.opt_state`` / ``self._models`` — and
@@ -1172,21 +1195,25 @@ class Accelerator:
 
             try:
                 if _tel.is_enabled():
-                    if not perf_cost[1] and _perf.capture_enabled():
-                        perf_cost[1] = True
-                        if cached_exec[0] is not None:
-                            # warm restart: the cost analysis rides the loaded
-                            # executable — no capture AOT compile either
-                            perf_cost[0] = _perf.capture_from_executable(
-                                kind, cached_exec[0]
-                            )
-                        else:
-                            perf_cost[0] = _perf.capture_compiled(
-                                kind, step_fn, (params, opt_state, batch),
-                                mesh=self.mesh,
-                            )
-                    step_telemetry.set_step_cost(perf_cost[0])
                     with step_telemetry.step():
+                        # inside the step's window: the capture's AOT compile
+                        # is the one compile this function gets (the jit call
+                        # below reuses the executable), so it is the first
+                        # step's compile
+                        if not perf_cost[1] and _perf.capture_enabled():
+                            perf_cost[1] = True
+                            if cached_exec[0] is not None:
+                                # warm restart: the cost analysis rides the
+                                # loaded executable — no compile at all
+                                perf_cost[0] = _perf.capture_from_executable(
+                                    kind, cached_exec[0]
+                                )
+                            else:
+                                perf_cost[0] = _perf.capture_compiled(
+                                    kind, step_fn, (params, opt_state, batch),
+                                    mesh=self.mesh,
+                                )
+                        step_telemetry.set_step_cost(perf_cost[0])
                         new_params, new_opt_state, metrics = run_step(params, opt_state, batch)
                 else:
                     new_params, new_opt_state, metrics = run_step(params, opt_state, batch)
@@ -1222,8 +1249,10 @@ class Accelerator:
         if hasattr(step_fn, "_cache_size"):
             # surface the jitted step's cache counter through the tracking
             # wrapper (the serving engine's jit_cache_sizes idiom) so callers
-            # can assert frozen caches post-warmup
+            # can assert frozen caches post-warmup, and its AOT ``lower`` so
+            # they can read the program the step compiles to
             step_and_track._cache_size = step_fn._cache_size
+            step_and_track.lower = step_fn.lower
         return step_and_track
 
     def prepare_train_step(
@@ -1301,7 +1330,10 @@ class Accelerator:
 
         if not self.jit_config.disable_jit:
             donate = self.jit_config.donate_params if donate is None else donate
-            train_step = jax.jit(train_step, donate_argnums=(0, 1) if donate else ())
+            train_step = jax.jit(
+                train_step, donate_argnums=(0, 1) if donate else (),
+                out_shardings=self._state_out_shardings(optimizer),
+            )
             self._register_compiled("train_step", train_step)
 
         return self._track_step(train_step, optimizer, kind="train_step")
@@ -1355,7 +1387,10 @@ class Accelerator:
 
         if not self.jit_config.disable_jit:
             donate = self.jit_config.donate_params if donate is None else donate
-            train_loop = jax.jit(train_loop, donate_argnums=(0, 1) if donate else ())
+            train_loop = jax.jit(
+                train_loop, donate_argnums=(0, 1) if donate else (),
+                out_shardings=self._state_out_shardings(optimizer),
+            )
             self._register_compiled("train_loop", train_loop)
 
         return self._track_step(train_loop, optimizer, kind="train_loop")

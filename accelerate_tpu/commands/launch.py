@@ -136,9 +136,7 @@ def build_launch_env(cfg: ClusterConfig) -> dict[str, str]:
     if cfg.debug:
         env["ACCELERATE_DEBUG_MODE"] = "true"
     if cfg.use_cpu:
-        # platform selection happens via jax.config.update in PartialState —
-        # setting JAX_PLATFORMS here can hang backend init on some TPU-plugin
-        # installs, config.update never does
+        # PartialState selects the platform (jax.config.update) from this
         env["ACCELERATE_USE_CPU"] = "true"
         n = cfg.num_processes or 8
         flags = os.environ.get("XLA_FLAGS", "")

@@ -349,6 +349,12 @@ class CompileCache:
             from jax.experimental import serialize_executable as _se
 
             payload = pickle.dumps(_se.serialize(compiled), protocol=pickle.HIGHEST_PROTOCOL)
+            # the devices the executable runs on (the attribute serialize()
+            # itself reads): load() must hand exactly these back, or jax binds
+            # the executable to every device of the backend
+            device_ids = [
+                int(d.id) for d in compiled._executable._unloaded_executable.device_list
+            ]
         except Exception as exc:
             return StoreResult("error", reason=f"serialize: {type(exc).__name__}: {exc}")
         self._sweep_stale_staging()
@@ -372,6 +378,7 @@ class CompileCache:
                     "file": PAYLOAD_NAME,
                     "bytes": len(payload),
                     "crc32": zlib.crc32(payload) & 0xFFFFFFFF,
+                    "device_ids": device_ids,
                 },
                 "created_unix": round(time.time(), 3),
             }
@@ -503,7 +510,16 @@ class CompileCache:
             blob = pf.read()
         from jax.experimental import serialize_executable as _se
 
-        executable = _se.deserialize_and_load(*pickle.loads(blob))
+        import jax
+
+        by_id = {d.id: d for d in jax.devices()}
+        try:
+            execution_devices = [by_id[i] for i in spec["device_ids"]]
+        except (KeyError, TypeError) as exc:
+            raise CompileCacheCorrupt(f"manifest names no usable devices: {exc!r}")
+        executable = _se.deserialize_and_load(
+            *pickle.loads(blob), execution_devices=execution_devices
+        )
         return executable, size
 
     def _quarantine(self, entry: str, reason: str) -> Optional[str]:

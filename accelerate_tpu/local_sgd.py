@@ -94,7 +94,7 @@ def make_local_sgd_train_step(
     import jax
     import jax.numpy as jnp
     import optax
-    from .utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_rep = int(mesh.shape[dp_axis])

@@ -131,7 +131,7 @@ class LlamaConfig:
     # 1.5× fwd+bwd on v5e for BERT-base. False → O(1)-in-depth compile time.
     unroll_layers: bool = True
     # default attention implementation for forwards that don't pass one
-    # explicitly: "auto" | "xla" | "flash" | "fused" (ops.attention impls)
+    # explicitly: "auto" | "xla" | "flash" (ops.attention impls)
     attn_impl: str = "auto"
     # None → matmuls in the param dtype; "fp8" → QKV/O and MLP projections run
     # through ops.fp8.fp8_dot (delayed scaling, e4m3 fwd / e5m2 bwd) with the

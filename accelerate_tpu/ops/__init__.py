@@ -5,4 +5,3 @@ from .flash_attention import (
     paged_attention_decode,
     paged_attention_prefill,
 )
-from .fused_attention import fused_attention

@@ -11,18 +11,23 @@ Layouts: ``q,k,v: [batch, seq, heads, head_dim]`` (BSHD). GQA supported via
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from ..logging import get_logger
+
+logger = get_logger(__name__)
+
 
 def segment_mask(segment_ids: jax.Array) -> jax.Array:
     """[B, S] ids → [B, 1, Sq, Skv] bool allow-mask: attend iff same id.
 
-    The single definition of segment semantics — the xla path and the off-TPU
-    kernel fallbacks all build their masks here so the three impls cannot
+    The single definition of segment semantics — the xla path and the flash
+    kernel's off-TPU reference both build their masks here so they cannot
     drift."""
     return segment_ids[:, None, :, None] == segment_ids[:, None, None, :]
 
@@ -51,17 +56,15 @@ def dot_product_attention(
 
     ``impl``:
 
-    - "xla" — einsum, fused by XLA on the MXU. Fastest at short S (the whole
-      score tensor is small enough that XLA's fusions win — measured on v5e).
+    - "xla" — einsum, fused by XLA on the MXU.
     - "flash" — the in-tree blocked streaming kernel (``ops.flash_attention``):
       online softmax, in-kernel GQA, block-sparse causal/window/segment
-      skipping. Wins past the measured crossover (see ``ATTN_CROSSOVER_S``).
-    - "fused" — our single-pass Pallas kernel (``ops.fused_attention``): whole
-      score block in VMEM, one kernel for fwd and one for bwd. Within ~20% of
-      xla at S=128–256; available for fusion-hostile surrounding graphs.
-    - "auto" — picks flash vs xla from the measured crossover table
-      (``ATTN_CROSSOVER_S``, derived from ``benchmarks/attention/run.py``),
-      keyed by dtype and mask sparsity.
+      skipping. On a TPU a shape the kernel cannot tile raises; it never gives
+      way to the einsum path.
+    - "auto" — picks flash vs xla from the crossover table
+      (``ATTN_CROSSOVER_S``), keyed by dtype and mask sparsity. The only impl
+      that may choose the einsum path itself; each choice is logged once per
+      shape (:func:`_log_auto_choice`).
 
     Masking comes in three forms:
 
@@ -86,30 +89,17 @@ def dot_product_attention(
             if mask is None and _flash_supported(q, k, causal=causal, window=window)
             else "xla"
         )
-    if impl in ("flash", "fused"):
+        _log_auto_choice(
+            impl, tuple(q.shape), tuple(k.shape), str(q.dtype), causal, window,
+            mask is not None,
+        )
+    if impl == "flash":
         if mask is not None:
             raise ValueError(
-                f"impl={impl!r} does not support an arbitrary mask (causal and "
+                "impl='flash' does not support an arbitrary mask (causal and "
                 "segment_ids only); use impl='xla', or express padding/packing "
                 "as segment_ids"
             )
-        if impl == "fused":
-            if window is not None:
-                raise ValueError(
-                    "impl='fused' does not support window (the short-S single-"
-                    "pass kernel has no band masking); use impl='flash' or 'xla'"
-                )
-            from .fused_attention import fused_attention, fused_supported
-
-            # off-TPU the wrapper falls back to the einsum path, any shape
-            if jax.default_backend() == "tpu" and not fused_supported(q, k):
-                raise ValueError(
-                    f"impl='fused' does not support shapes q={q.shape} k={k.shape} "
-                    "(needs Sq == Skv, S a multiple of 128 and ≤ 1024, D a "
-                    "multiple of 64 and ≤ 256, q-heads divisible by kv-heads, "
-                    "and the per-row score block within VMEM); use impl='xla'"
-                )
-            return fused_attention(q, k, v, causal=causal, scale=scale, segment_ids=segment_ids)
         from .flash_attention import flash_attention
 
         return flash_attention(
@@ -126,14 +116,14 @@ def dot_product_attention(
     return _xla_attention(q, k, v, causal=causal, mask=mask, scale=scale, window=window)
 
 
-# Measured flash-vs-xla crossover (fwd+bwd step time, v5e, B=8, H=12, D=64;
-# benchmarks/attention/run.py is the generating grid): the einsum path wins
-# below the listed S, the streaming kernel at/after it. Sparser masks move
-# the crossover EARLIER — the block-skip lattice drops whole tiles, so the
-# kernel's streamed work shrinks while the einsum path still materializes
-# (and masks) every score. f32 crosses earlier than bf16 because the f32
-# score tensor doubles the einsum path's HBM traffic but the kernel's VMEM
-# accumulators are f32 either way.
+# Flash-vs-xla crossover: "auto" takes the einsum path below the listed S and
+# the streaming kernel at/after it. NOT measured on the current kernel (the
+# table predates it; benchmarks/attention/run.py is the grid that would
+# calibrate it on a chip). The ordering is by argument: sparser masks move the
+# crossover EARLIER — the block-skip lattice drops whole tiles while the
+# einsum path still materializes (and masks) every score — and f32 crosses
+# earlier than bf16 because the f32 score tensor doubles the einsum path's
+# HBM traffic while the kernel's VMEM accumulators are f32 either way.
 ATTN_CROSSOVER_S = {
     ("bf16", "dense"): 512,
     ("bf16", "causal"): 384,
@@ -144,16 +134,28 @@ ATTN_CROSSOVER_S = {
 }
 
 
+@functools.lru_cache(maxsize=None)
+def _log_auto_choice(impl, q_shape, k_shape, dtype, causal, window, has_mask) -> None:
+    """One INFO line per distinct (choice, shape): ``impl="auto"`` is the one
+    place a kernel may give way to the einsum path, so what it chose is on
+    record. Cached on its arguments, i.e. once per shape per process (tracing
+    calls this, not the compiled step)."""
+    logger.info(
+        f"attention impl=auto chose {impl!r}: q={q_shape} k={k_shape} {dtype} "
+        f"causal={causal} window={window} mask={has_mask} on {jax.default_backend()}"
+    )
+
+
 def _flash_supported(q, k, *, causal: bool = False, window: Optional[int] = None) -> bool:
-    try:
-        if jax.default_backend() != "tpu":
-            return False
-    except Exception:
+    from .flash_attention import flash_kernel_mode, flash_tileable
+
+    # the kernel exists on a TPU, and anywhere under the tests' interpret mode
+    mode = flash_kernel_mode()
+    if mode == "off" or (mode == "on" and jax.default_backend() != "tpu"):
         return False
-    # flash kernel wants seq multiples of its block size…
-    if not (q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0 and q.shape[-1] in (64, 128, 256)):
+    if not (flash_tileable(q.shape, k.shape) and q.shape[-1] in (64, 128, 256)):
         return False
-    # …and only wins past the measured crossover for this dtype × sparsity
+    # …and "auto" only takes it past the crossover for this dtype × sparsity
     sparsity = "window" if window is not None else ("causal" if causal else "dense")
     dkey = "bf16" if q.dtype == jnp.bfloat16 else "f32"
     return k.shape[1] >= ATTN_CROSSOVER_S[(dkey, sparsity)]

@@ -6,8 +6,7 @@ stock JAX kernel (``jax.experimental.pallas.ops.tpu.flash_attention``); that
 wrapper materialized repeated KV in HBM for GQA, supported no sliding-window
 or block-sparse masking, and had no interpret mode, so tier-1 never exercised
 its dataflow. This module replaces it with an in-tree blocked online-softmax
-kernel (fwd + custom_vjp bwd with recompute-from-logsumexp, the pattern
-``ops/fused_attention.py`` demonstrates at short S):
+kernel (fwd + custom_vjp bwd with recompute-from-logsumexp):
 
 - grid ``(B·H, q_blocks, kv_blocks)`` with the kv axis innermost; f32 online
   softmax carried in VMEM scratch across kv steps;
@@ -43,13 +42,14 @@ def flash_kernel_mode() -> str:
     functions bake it in at compile time — flipping the env var mid-run does
     not retrace warm jit entries):
 
-    - ``"on"`` (default): the in-tree Pallas kernel when the backend is TPU,
-      einsum reference everywhere else;
+    - ``"on"`` (default): the in-tree Pallas kernel when the backend is TPU
+      (a shape it cannot tile raises there), einsum reference off-TPU;
     - ``"off"`` (``ACCELERATE_FLASH_KERNEL=0``): einsum reference always —
       the kill switch, byte-identical to ``impl="xla"``;
     - ``"interpret"`` (``ACCELERATE_FLASH_KERNEL=interpret``): the Pallas
       kernel in interpreter mode on ANY backend — how CPU CI drives the
-      kernel's exact dataflow (including the backward) in tier-1."""
+      kernel's exact dataflow (including the backward) in tier-1. A test
+      tool: no backend takes it by default."""
     raw = os.environ.get("ACCELERATE_FLASH_KERNEL", "1").strip().lower()
     if raw in ("0", "off", "false"):
         return "off"
@@ -169,10 +169,10 @@ def _flash_fwd_kernel(
     q_ref,       # [1, bq, D]       this (head, q-block) tile
     k_ref,       # [1, bkv, D]      the kv block the clamped index map selected
     v_ref,       # [1, bkv, D]
-    segq_ref,    # [1, bq] int32
-    segkv_ref,   # [1, bkv] int32
+    segq_ref,    # [1, 1, bq] int32   per-token vectors ride the row layout
+    segkv_ref,   # [1, 1, bkv] int32  of _row_spec
     o_ref,       # [1, bq, D]
-    lse_ref,     # [1, bq] f32
+    lse_ref,     # [1, 1, bq] f32
     acc_ref,     # VMEM [bq, D] f32   online-softmax accumulators,
     m_ref,       # VMEM [bq, 1] f32   carried across the kv grid steps
     l_ref,       # VMEM [bq, 1] f32
@@ -209,7 +209,7 @@ def _flash_fwd_kernel(
         k = k_ref[0]
         v = v_ref[0]
         s = _dot_nt2(q, k) * cfg.scale  # [bq, bkv] f32
-        allow = _allow_mask(cfg, s.shape, qi, blk, segq_ref[0], segkv_ref[0])
+        allow = _allow_mask(cfg, s.shape, qi, blk, segq_ref[0, 0], segkv_ref[0, 0])
         if allow is not None:
             s = jnp.where(allow, s, -jnp.inf)
         m_prev, l_prev = m_ref[...], l_ref[...]
@@ -226,7 +226,7 @@ def _flash_fwd_kernel(
     @pl.when(t == pl.num_programs(2) - 1)
     def _finalize():
         o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[:, 0] + jnp.log(l_ref[:, 0])
+        lse_ref[0, 0] = m_ref[:, 0] + jnp.log(l_ref[:, 0])
 
 
 def _flash_dq_kernel(
@@ -235,8 +235,8 @@ def _flash_dq_kernel(
     k_ref,      # [1, bkv, D]
     v_ref,      # [1, bkv, D]
     segq_ref, segkv_ref,
-    lse_ref,    # [1, bq] f32
-    delta_ref,  # [1, bq] f32: sum(do * o) per row, precomputed
+    lse_ref,    # [1, 1, bq] f32
+    delta_ref,  # [1, 1, bq] f32: sum(do * o) per row, precomputed
     do_ref,     # [1, bq, D]
     dq_ref,     # [1, bq, D]
     dq_acc_ref,  # VMEM [bq, D] f32
@@ -245,8 +245,7 @@ def _flash_dq_kernel(
 ):
     """dq kernel: same grid and lattice walk as the forward, recomputing
     probabilities from the saved logsumexp (``p = exp(s - lse)``) instead of
-    re-running the online softmax — the fused_attention recompute pattern,
-    blocked."""
+    re-running the online softmax."""
     from jax.experimental import pallas as pl
 
     g, qi, t = pl.program_id(0), pl.program_id(1), pl.program_id(2)
@@ -265,12 +264,12 @@ def _flash_dq_kernel(
         v = v_ref[0]
         do = do_ref[0]
         s = _dot_nt2(q, k) * cfg.scale
-        allow = _allow_mask(cfg, s.shape, qi, blk, segq_ref[0], segkv_ref[0])
+        allow = _allow_mask(cfg, s.shape, qi, blk, segq_ref[0, 0], segkv_ref[0, 0])
         if allow is not None:
             s = jnp.where(allow, s, -jnp.inf)
-        p = jnp.exp(s - lse_ref[0][:, None])  # [bq, bkv] f32, masked -> 0
+        p = jnp.exp(s - lse_ref[0, 0][:, None])  # [bq, bkv] f32, masked -> 0
         dp = _dot_nt2(do, v)                  # [bq, bkv] f32
-        ds = p * (dp - delta_ref[0][:, None])
+        ds = p * (dp - delta_ref[0, 0][:, None])
         dq_acc_ref[...] += _dot_nn2(ds.astype(k.dtype), k) * cfg.scale
 
     @pl.when(t == pl.num_programs(2) - 1)
@@ -286,8 +285,8 @@ def _flash_dkdv_kernel(
     k_ref,        # [1, bkv, D]  this kv head's block
     v_ref,        # [1, bkv, D]
     segq_ref, segkv_ref,
-    lse_ref,      # [1, bq] f32
-    delta_ref,    # [1, bq] f32
+    lse_ref,      # [1, 1, bq] f32
+    delta_ref,    # [1, 1, bq] f32
     dk_ref,       # [1, bkv, D]
     dv_ref,       # [1, bkv, D]
     dk_acc_ref,   # VMEM [bkv, D] f32
@@ -320,13 +319,13 @@ def _flash_dkdv_kernel(
         k = k_ref[0]
         v = v_ref[0]
         s = _dot_nt2(q, k) * cfg.scale  # [bq, bkv] f32
-        allow = _allow_mask(cfg, s.shape, qb, j, segq_ref[0], segkv_ref[0])
+        allow = _allow_mask(cfg, s.shape, qb, j, segq_ref[0, 0], segkv_ref[0, 0])
         if allow is not None:
             s = jnp.where(allow, s, -jnp.inf)
-        p = jnp.exp(s - lse_ref[0][:, None])  # [bq, bkv] f32
+        p = jnp.exp(s - lse_ref[0, 0][:, None])  # [bq, bkv] f32
         dv_acc_ref[...] += _dot_tn2(p.astype(do.dtype), do)   # pᵀ do
         dp = _dot_nt2(do, v)
-        ds = p * (dp - delta_ref[0][:, None])
+        ds = p * (dp - delta_ref[0, 0][:, None])
         dk_acc_ref[...] += _dot_tn2(ds.astype(q.dtype), q) * cfg.scale
 
     @pl.when(t == pl.num_programs(2) - 1)
@@ -340,6 +339,20 @@ def _clamped_block(ids, counts, b, qi, t):
     block once t runs past the active count — the repeated block index is what
     lets Mosaic elide the DMA for skipped steps."""
     return ids[b, qi, jnp.minimum(t, jnp.maximum(counts[b, qi] - 1, 0))]
+
+
+def _row_spec(width, index_map):
+    """BlockSpec of a per-token vector (segment ids, lse, delta). They are kept
+    ``[N, 1, S]`` so a ``(1, 1, width)`` block meets Mosaic's tiling rule (the
+    second-minor block dim equals the array's, the minor one is a multiple of
+    128 or the whole of S); ``index_map`` gives ``(row, block)``."""
+    from jax.experimental import pallas as pl
+
+    def index(*args):
+        row, blk = index_map(*args)
+        return row, 0, blk
+
+    return pl.BlockSpec((1, 1, width), index)
 
 
 def _flash_pallas_call(kernel, cfg, grid, in_specs, out_specs, out_shape, scratch):
@@ -365,7 +378,8 @@ def _flash_call(q3, k3, v3, seg, cfg):
 
 
 def _flash_call_fwd(q3, k3, v3, seg, cfg):
-    """q3 [B·H, S, D]; k3/v3 [B·Hkv, S, D]; seg [B, S] int32."""
+    """q3 [B·H, S, D]; k3/v3 [B·Hkv, S, D]; seg [B, S] int32. The saved lse
+    is ``[B·H, 1, S]`` (the row layout of :func:`_row_spec`)."""
     from jax.experimental import pallas as pl
 
     BH, S, D = q3.shape
@@ -389,20 +403,20 @@ def _flash_call_fwd(q3, k3, v3, seg, cfg):
             lambda g, qi, t, ids, cnt: (
                 kv_batch(g), _clamped_block(ids, cnt, g // H, qi, t), 0),
         ),
-        pl.BlockSpec((1, bq), lambda g, qi, t, ids, cnt: (g // H, qi)),
-        pl.BlockSpec(
-            (1, bkv),
+        _row_spec(bq, lambda g, qi, t, ids, cnt: (g // H, qi)),
+        _row_spec(
+            bkv,
             lambda g, qi, t, ids, cnt: (
                 g // H, _clamped_block(ids, cnt, g // H, qi, t)),
         ),
     ]
     out_specs = [
         pl.BlockSpec((1, bq, D), lambda g, qi, t, ids, cnt: (g, qi, 0)),
-        pl.BlockSpec((1, bq), lambda g, qi, t, ids, cnt: (g, qi)),
+        _row_spec(bq, lambda g, qi, t, ids, cnt: (g, qi)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((BH, S, D), q3.dtype),
-        jax.ShapeDtypeStruct((BH, S), jnp.float32),
+        jax.ShapeDtypeStruct((BH, 1, S), jnp.float32),
     ]
     from jax.experimental.pallas import tpu as pltpu
 
@@ -414,7 +428,7 @@ def _flash_call_fwd(q3, k3, v3, seg, cfg):
     out, lse = _flash_pallas_call(
         partial(_flash_fwd_kernel, cfg=cfg),
         cfg, (BH, nq, nkv), in_specs, out_specs, out_shape, scratch,
-    )(ids, counts, q3, k3, v3, seg, seg)
+    )(ids, counts, q3, k3, v3, seg[:, None], seg[:, None])
     return out, (q3, k3, v3, seg, lse, out)
 
 
@@ -430,7 +444,8 @@ def _flash_call_bwd(cfg, res, do):
     B = BH // H
     ids, counts, idsT, countsT = _block_lattice(seg, cfg)
     # delta = Σ_d do·o per row: elementwise, O(S·D) — no score-shaped tensor
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)[:, None]
+    seg = seg[:, None]  # [B, 1, S], the row-vector layout of _row_spec
 
     def kv_batch(g):
         return (g // H) * Hkv + (g % H) // groups
@@ -441,7 +456,7 @@ def _flash_call_bwd(cfg, res, do):
         lambda g, qi, t, ids, cnt: (
             kv_batch(g), _clamped_block(ids, cnt, g // H, qi, t), 0),
     )
-    row_spec = pl.BlockSpec((1, bq), lambda g, qi, t, ids, cnt: (g, qi))
+    row_spec = _row_spec(bq, lambda g, qi, t, ids, cnt: (g, qi))
     dq = _flash_pallas_call(
         partial(_flash_dq_kernel, cfg=cfg),
         cfg,
@@ -450,9 +465,9 @@ def _flash_call_bwd(cfg, res, do):
             q_spec,
             kv_spec,
             kv_spec,
-            pl.BlockSpec((1, bq), lambda g, qi, t, ids, cnt: (g // H, qi)),
-            pl.BlockSpec(
-                (1, bkv),
+            _row_spec(bq, lambda g, qi, t, ids, cnt: (g // H, qi)),
+            _row_spec(
+                bkv,
                 lambda g, qi, t, ids, cnt: (
                     g // H, _clamped_block(ids, cnt, g // H, qi, t)),
             ),
@@ -478,8 +493,8 @@ def _flash_call_bwd(cfg, res, do):
             0,
         ),
     )
-    rowT_spec = pl.BlockSpec(
-        (1, bq),
+    rowT_spec = _row_spec(
+        bq,
         lambda a, j, t, ids, cnt: (
             q_batch(a, t),
             _clamped_block(ids, cnt, a // Hkv, j, t // groups),
@@ -495,14 +510,14 @@ def _flash_call_bwd(cfg, res, do):
             qT_spec,
             kvT_spec,
             kvT_spec,
-            pl.BlockSpec(
-                (1, bq),
+            _row_spec(
+                bq,
                 lambda a, j, t, ids, cnt: (
                     a // Hkv,
                     _clamped_block(ids, cnt, a // Hkv, j, t // groups),
                 ),
             ),
-            pl.BlockSpec((1, bkv), lambda a, j, t, ids, cnt: (a // Hkv, j)),
+            _row_spec(bkv, lambda a, j, t, ids, cnt: (a // Hkv, j)),
             rowT_spec,
             rowT_spec,
         ],
@@ -529,6 +544,13 @@ def _reference_attention(q, k, v, *, causal, scale, segment_ids, window):
     return _xla_attention(q, k, v, causal=causal, mask=mask, scale=scale, window=window)
 
 
+def flash_tileable(q_shape, k_shape, block_q: int = 128, block_kv: int = 128) -> bool:
+    """Shapes (BSHD) the blocked kernel takes: self-attention whose sequence
+    is a whole number of blocks (a sequence shorter than a block is one block)."""
+    Sq, Skv = q_shape[1], k_shape[1]
+    return Sq == Skv and Sq % min(block_q, Sq) == 0 and Skv % min(block_kv, Skv) == 0
+
+
 def flash_attention(
     q: jax.Array,  # [B, S, H, D]
     k: jax.Array,
@@ -547,9 +569,11 @@ def flash_attention(
     of padding/packing masks; ``window`` adds a causal sliding-window band
     (requires ``causal=True``). Both feed the block-skip lattice, so fully
     masked (q_block, kv_block) tiles cost nothing. Dispatch is governed by
-    :func:`flash_kernel_mode`; shapes the blocked kernel cannot tile
-    (cross-attention, S not a multiple of the block size) fall back to the
-    einsum reference."""
+    :func:`flash_kernel_mode`. On a TPU a shape the blocked kernel cannot tile
+    (:func:`flash_tileable`) raises: this function IS the explicit request for
+    the kernel, and only ``dot_product_attention(impl="auto")`` may choose the
+    einsum path. Off-TPU there is no Mosaic compiler and the einsum reference
+    is the implementation, unless the tests' interpret mode is on."""
     if window is not None:
         if not causal:
             raise ValueError(
@@ -564,24 +588,42 @@ def flash_attention(
     sm_scale = 1.0 / math.sqrt(D) if scale is None else float(scale)
 
     mode = flash_kernel_mode()
-    use_kernel = mode == "interpret" or (mode == "on" and jax.default_backend() == "tpu")
-    bq, bkv = min(block_q, Sq), min(block_kv, Skv)
-    tileable = Sq == Skv and Sq % bq == 0 and Skv % bkv == 0
-    if not (use_kernel and tileable):
+    on_tpu = jax.default_backend() == "tpu"
+    tileable = flash_tileable(q.shape, k.shape, block_q, block_kv)
+    if mode == "on" and on_tpu and not tileable:
+        raise ValueError(
+            f"flash attention cannot tile q={q.shape} k={k.shape}: it needs "
+            f"Sq == Skv and S a multiple of the block ({block_q}/{block_kv}) or "
+            "shorter than it. Use impl='xla', or impl='auto' to let the dispatcher choose."
+        )
+    if not (tileable and (mode == "interpret" or (mode == "on" and on_tpu))):
         return _reference_attention(
             q, k, v, causal=causal, scale=scale, segment_ids=segment_ids, window=window
         )
+    return _flash_kernel(
+        q, k, v, segment_ids, causal=causal, sm_scale=sm_scale, window=window,
+        block_q=block_q, block_kv=block_kv, interpret=mode == "interpret",
+    )
 
+
+def _flash_kernel(q, k, v, segment_ids, *, causal, sm_scale, window,
+                  block_q=128, block_kv=128, interpret=False):
+    """The kernel path of :func:`flash_attention`, past its dispatch (BSHD
+    in/out; the shape is already known to be :func:`flash_tileable`). Also
+    what ``tests/test_tpu_compile.py`` hands to the chip's compiler, since
+    the dispatch above sees the sandbox's CPU."""
+    B, Sq, H, D = q.shape
+    Skv, Hkv = k.shape[1], k.shape[2]
     cfg = _FlashConfig(
         scale=sm_scale,
         causal=causal,
         window=window,
-        block_q=bq,
-        block_kv=bkv,
+        block_q=min(block_q, Sq),
+        block_kv=min(block_kv, Skv),
         h=H,
         hkv=Hkv,
         use_seg=segment_ids is not None,
-        interpret=mode == "interpret",
+        interpret=interpret,
     )
     seg = (
         segment_ids.astype(jnp.int32)
@@ -752,24 +794,26 @@ def paged_attention_decode(
 
 def _paged_prefill_kernel(
     tables_ref,  # [B, W] int32 scalar-prefetch (drives the k/v index maps)
-    qpos_ref,    # [B, S] int32 scalar-prefetch: absolute position of each query
-    q_ref,       # [1, S, H, D]             this row's chunk of queries
+    qpos_ref,    # [1, Sq, 1] int32 VMEM: absolute position of each query (a
+                 # column: SMEM scalar-prefetch operands only yield scalars)
+    q_ref,       # [1, Sq, H, D]            this row's tile of the query chunk
     k_ref,       # [1, block_size, Hkv, D]  the block the index map selected
     v_ref,       # [1, block_size, Hkv, D]
-    o_ref,       # [1, S, H, D]
-    acc_ref,     # VMEM [H, S, D] f32   online-softmax accumulators,
-    m_ref,       # VMEM [H, S, 1] f32   carried across the W grid steps
-    l_ref,       # VMEM [H, S, 1] f32
+    o_ref,       # [1, Sq, H, D]
+    acc_ref,     # VMEM [H, Sq, D] f32  online-softmax accumulators,
+    m_ref,       # VMEM [H, Sq, 1] f32  carried across the W grid steps
+    l_ref,       # VMEM [H, Sq, 1] f32
     *,
     block_size: int,
     groups: int,
     scale: float,
 ):
-    """One (row, logical-block) grid step of paged chunked-prefill attention.
+    """One (row, query-tile, logical-block) grid step of paged chunked-prefill
+    attention.
 
-    Same shape of walk as :func:`_paged_decode_kernel` — grid ``(B, W)``,
+    Same shape of walk as :func:`_paged_decode_kernel` — grid ``(B, S/Sq, W)``,
     block axis innermost, BlockSpec index maps DMA physical block
-    ``tables[b, w]`` into VMEM — but with ``S > 1`` queries per row, so the
+    ``tables[b, w]`` into VMEM — but with ``Sq > 1`` queries per tile, so the
     score/PV contractions are real ``[H, S, d] x [H, d, bs]`` matmuls on the
     MXU (``dot_general`` batched over heads) instead of the decode kernel's
     VPU broadcast-reduce. Causality inside the chunk and raggedness against
@@ -781,7 +825,7 @@ def _paged_prefill_kernel(
     past every query and are silenced by the same predicate)."""
     from jax.experimental import pallas as pl  # deferred with pallas_call's
 
-    b, w = pl.program_id(0), pl.program_id(1)
+    w = pl.program_id(2)
 
     @pl.when(w == 0)
     def _init():
@@ -805,7 +849,7 @@ def _paged_prefill_kernel(
         qh, kh, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
     )                                                  # [H, S, bs]
     pos = w * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    s = jnp.where(pos <= qpos_ref[b][None, :, None], s, -jnp.inf)
+    s = jnp.where(pos <= qpos_ref[...], s, -jnp.inf)
 
     m_prev, l_prev = m_ref[...], l_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))  # [H, S, 1]
@@ -821,9 +865,33 @@ def _paged_prefill_kernel(
     m_ref[...] = m_new
     l_ref[...] = l_new
 
-    @pl.when(w == pl.num_programs(1) - 1)
+    @pl.when(w == pl.num_programs(2) - 1)
     def _finalize():
         o_ref[0] = (acc_ref[...] / l_ref[...]).transpose(1, 0, 2).astype(o_ref.dtype)
+
+
+# One prefill program holds its query tile for ALL heads: q/o tiles (double
+# buffered), the f32 upcast and its head-major copy, acc/m/l and the score
+# temporaries, every one padded to 128 lanes. The v5e compiler reported 6.8 MB
+# of scoped VMEM at H*Sq = 2048 rows, D <= 128, against its 16 MB limit; twice
+# that does not fit.
+_PREFILL_TILE_ROWS = 2048
+
+
+def _prefill_query_tile(S: int, H: int, D: int) -> int:
+    """Queries per program: all of S when ``H*S`` rows fit the budget above,
+    else the largest divisor of S that does and is a multiple of 8 (the
+    sublane tiling of the ``[Sq, 1]`` position column)."""
+    budget = max(1, _PREFILL_TILE_ROWS * 128 // (H * max(D, 128)))
+    if S <= budget:
+        return S
+    for sq in range(budget - budget % 8, 0, -8):
+        if S % sq == 0:
+            return sq
+    raise ValueError(
+        f"paged prefill kernel cannot tile S={S} queries of H={H}, D={D}: no "
+        f"multiple of 8 up to {budget} divides S"
+    )
 
 
 def paged_attention_prefill(
@@ -851,26 +919,28 @@ def paged_attention_prefill(
     if H % Hkv:
         raise ValueError(f"q heads {H} not a multiple of kv heads {Hkv}")
     sm_scale = (1.0 / math.sqrt(D)) if scale is None else float(scale)
+    Sq = _prefill_query_tile(S, H, D)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block tables + per-query positions
-        grid=(B, W),
+        num_scalar_prefetch=1,  # block tables
+        grid=(B, S // Sq, W),
         in_specs=[
-            pl_.BlockSpec((1, S, H, D), lambda b, w, tables, qpos: (b, 0, 0, 0)),
+            pl_.BlockSpec((1, Sq, 1), lambda b, i, w, tables: (b, i, 0)),
+            pl_.BlockSpec((1, Sq, H, D), lambda b, i, w, tables: (b, i, 0, 0)),
             pl_.BlockSpec(
                 (1, block_size, Hkv, D),
-                lambda b, w, tables, qpos: (tables[b, w], 0, 0, 0),
+                lambda b, i, w, tables: (tables[b, w], 0, 0, 0),
             ),
             pl_.BlockSpec(
                 (1, block_size, Hkv, D),
-                lambda b, w, tables, qpos: (tables[b, w], 0, 0, 0),
+                lambda b, i, w, tables: (tables[b, w], 0, 0, 0),
             ),
         ],
-        out_specs=pl_.BlockSpec((1, S, H, D), lambda b, w, tables, qpos: (b, 0, 0, 0)),
+        out_specs=pl_.BlockSpec((1, Sq, H, D), lambda b, i, w, tables: (b, i, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((H, S, D), jnp.float32),
-            pltpu.VMEM((H, S, 1), jnp.float32),
-            pltpu.VMEM((H, S, 1), jnp.float32),
+            pltpu.VMEM((H, Sq, D), jnp.float32),
+            pltpu.VMEM((H, Sq, 1), jnp.float32),
+            pltpu.VMEM((H, Sq, 1), jnp.float32),
         ],
     )
     kernel = partial(
@@ -886,7 +956,7 @@ def paged_attention_prefill(
         interpret=interpret,
     )(
         block_tables.astype(jnp.int32),
-        jnp.asarray(q_positions, jnp.int32).reshape(B, S),
+        jnp.asarray(q_positions, jnp.int32).reshape(B, S, 1),
         q,
         k_pool,
         v_pool,

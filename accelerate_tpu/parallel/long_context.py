@@ -295,7 +295,7 @@ def make_context_parallel_attention(
     ``attention_fn`` hook of the model family (the moral twin of the reference's
     ``maybe_context_parallel`` buffer-sharding context, ``accelerator.py:4056``).
     """
-    from ..utils.jax_compat import shard_map
+    from jax import shard_map
 
     if axis_name is None:
         axis_name = "sp" if strategy == "ulysses" else "cp"

@@ -87,7 +87,7 @@ def make_pipeline_forward(
     """
     import jax
     import jax.numpy as jnp
-    from ..utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     pp = int(mesh.shape[axis_name])
@@ -206,7 +206,7 @@ def make_pipeline_train_step_1f1b(
     """
     import jax
     import jax.numpy as jnp
-    from ..utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     pp = int(mesh.shape[axis_name])

@@ -348,7 +348,7 @@ def make_fused_zero1_update(tx, plan: Zero1BucketPlan, mesh, state_specs) -> Cal
     import optax
     from jax.sharding import PartitionSpec as P
 
-    from ..utils.jax_compat import shard_map
+    from jax import shard_map
 
     axis = plan.axis
     names = plan.bucket_names

@@ -112,15 +112,11 @@ class PartialState:
         coordinator = kwargs.pop("coordinator_address", None) or os.environ.get(
             "ACCELERATE_COORDINATOR_ADDRESS"
         )
-        from .utils.jax_compat import distributed_is_initialized
-
-        if coordinator and not distributed_is_initialized():
+        if coordinator and not jax.distributed.is_initialized():
             if "cpu" in str(getattr(jax.config, "jax_platforms", "") or ""):
                 # CPU-backend multi-process (tests, dev boxes): collectives
                 # need an explicit implementation or the backend refuses them
-                from .utils.jax_compat import enable_cpu_multiprocess_collectives
-
-                enable_cpu_multiprocess_collectives()
+                jax.config.update("jax_cpu_collectives_implementation", "gloo")
             init_kwargs = {}
             if kwargs.get("local_device_ids") is not None:
                 init_kwargs["local_device_ids"] = kwargs.pop("local_device_ids")
@@ -296,10 +292,8 @@ class PartialState:
         yield chunk
 
     def destroy_process_group(self) -> None:
-        from .utils.jax_compat import distributed_is_initialized
-
         jax = _jax()
-        if distributed_is_initialized():
+        if jax.distributed.is_initialized():
             jax.distributed.shutdown()
 
     @classmethod

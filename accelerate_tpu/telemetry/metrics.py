@@ -417,7 +417,9 @@ class MetricsRegistry:
 _ACTIVE: Optional[MetricsRegistry] = None
 _SERVER = None  # (http.server instance, thread)
 _SHUTTING_DOWN = False  # /healthz readiness: flipped before the socket dies
-_LAST_SNAPSHOT = 0.0
+# "never": time.monotonic() starts near 0 on a freshly booted machine, where a
+# 0.0 here read as "a snapshot was just taken" for the whole first interval
+_LAST_SNAPSHOT = float("-inf")
 _SNAPSHOT_LOCK = threading.Lock()
 #: snapshot throttle, parsed ONCE at enable() (the hot loops call
 #: maybe_snapshot every step — no per-step env reads)
@@ -447,7 +449,7 @@ def disable() -> None:
     global _ACTIVE, _LAST_SNAPSHOT
     stop_server()
     _ACTIVE = None
-    _LAST_SNAPSHOT = 0.0
+    _LAST_SNAPSHOT = float("-inf")
 
 
 def maybe_enable_from_env() -> Optional[MetricsRegistry]:

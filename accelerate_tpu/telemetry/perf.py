@@ -5,10 +5,11 @@ where device time goes, which functions are compute-bound vs HBM-bound, and
 how the measured step compares to the chip's roofline. This module is the
 shared substrate:
 
-- **Hardware peak registry** — bf16 peak FLOP/s and HBM bandwidth per chip
-  generation (public TPU specs), with a *nominal* CPU fallback so dev-box runs
-  still produce relative MFU numbers (env-overridable). ``bench.py`` and the
-  telemetry layer both read THIS table, so they can never disagree on peaks.
+- **Hardware peak table** — bf16 peak FLOP/s and HBM bandwidth per chip,
+  keyed by ``device_kind`` (public TPU specs). A TPU kind that is not in it is
+  an error, and a CPU has no peak: MFU and roofline fields are then absent
+  (``None``), never nominal. ``bench.py`` and the telemetry layer both read
+  THIS table, so they can never disagree on peaks.
 - **Compile-time cost capture** — :func:`capture_compiled` lowers a jitted
   step function once (AOT), records XLA's own ``cost_analysis()`` (FLOPs,
   bytes accessed — remat recompute *included*: hardware utilization, not
@@ -21,11 +22,10 @@ shared substrate:
   ``roofline`` bucket (``compute-bound`` vs ``hbm-bound``), and the report
   CLI's "performance" section can plot the MFU trend per function.
 
-The capture costs one extra XLA compile per step function (the AOT executable
-is not shared with the jit call cache). It only runs while telemetry is
-enabled and can be killed independently with ``ACCELERATE_PERF_CAPTURE=0``;
-the compile it triggers is *excluded* from step compile/execute accounting
-(see :func:`~accelerate_tpu.telemetry.step_profiler.exclude_compiles`).
+The capture compiles the step function ahead of its first call; the jit call
+then reuses that executable, so the compile is counted once, as the first
+step's. It only runs while telemetry is enabled and can be killed
+independently with ``ACCELERATE_PERF_CAPTURE=0``.
 """
 
 from __future__ import annotations
@@ -38,110 +38,70 @@ from . import events as tel
 
 PERF_CAPTURE_ENV_VAR = "ACCELERATE_PERF_CAPTURE"
 
-# bf16 peak FLOP/s per chip by device kind (public TPU specs; fall back to
-# v5e for unknown TPU generations). THE peak table — bench.py imports it.
-PEAK_FLOPS = {
-    "TPU v2": 45e12,
-    "TPU v3": 123e12,
-    "TPU v4": 275e12,
-    "TPU v5 lite": 197e12,
-    "TPU v5e": 197e12,
-    "TPU v5": 459e12,
-    "TPU v5p": 459e12,
-    "TPU v6 lite": 918e12,
-    "TPU v6e": 918e12,
+# (bf16 peak FLOP/s, HBM bytes/s) of one chip, keyed by the ``device_kind`` JAX
+# reports. Source: Google Cloud TPU documentation, the per-generation system
+# architecture pages (v5e: 197 TFLOP/s bf16, 819 GB/s). THE peak table —
+# bench.py imports it; a kind that is missing is an error, not a default.
+DEVICE_PEAKS = {
+    "TPU v2": (45e12, 700e9),
+    "TPU v3": (123e12, 900e9),
+    "TPU v4": (275e12, 1228e9),
+    "TPU v5 lite": (197e12, 819e9),
+    "TPU v5e": (197e12, 819e9),
+    "TPU v5": (459e12, 2765e9),
+    "TPU v5p": (459e12, 2765e9),
+    "TPU v6 lite": (918e12, 1640e9),
+    "TPU v6e": (918e12, 1640e9),
 }
-
-# HBM bandwidth per chip in bytes/s (public specs), for roofline ridge points
-HBM_BYTES_PER_S = {
-    "TPU v2": 700e9,
-    "TPU v3": 900e9,
-    "TPU v4": 1228e9,
-    "TPU v5 lite": 819e9,
-    "TPU v5e": 819e9,
-    "TPU v5p": 2765e9,
-    "TPU v6 lite": 1640e9,
-    "TPU v6e": 1640e9,
-}
-
-# Nominal CPU stand-ins: dev boxes have no published "peak"; these make MFU a
-# *relative* signal (comparable run-over-run on the same box), never an
-# absolute utilization claim. Override per box via the env knobs.
-CPU_PEAK_FLOPS_ENV_VAR = "ACCELERATE_CPU_PEAK_FLOPS"
-CPU_HBM_GBPS_ENV_VAR = "ACCELERATE_CPU_HBM_GBPS"
-_CPU_NOMINAL_FLOPS = 1e11
-_CPU_NOMINAL_HBM_GBPS = 25.0
 
 
 @dataclass(frozen=True)
 class HardwarePeaks:
     """Peak throughput of one chip: ``flops`` (bf16 FLOP/s) and
-    ``hbm_bytes_per_s``. ``nominal=True`` marks the CPU/dev-box stand-in whose
-    MFU numbers are relative, not absolute (``source`` says where the numbers
-    came from: ``table`` / ``env`` / ``cpu-nominal``)."""
+    ``hbm_bytes_per_s``, as :data:`DEVICE_PEAKS` lists them."""
 
     device_kind: str
     flops: float
-    hbm_bytes_per_s: Optional[float]
-    nominal: bool = False
-    source: str = "table"
+    hbm_bytes_per_s: float
 
     @property
-    def ridge_intensity(self) -> Optional[float]:
+    def ridge_intensity(self) -> float:
         """FLOP/byte at the roofline ridge: below it a kernel is HBM-bound."""
-        if not self.hbm_bytes_per_s or not self.flops:
-            return None
         return self.flops / self.hbm_bytes_per_s
 
 
-def peaks_for_device(device: Optional[Any] = None) -> HardwarePeaks:
-    """Peak registry lookup for ``device`` (default: ``jax.devices()[0]``).
-
-    TPUs match on ``device_kind`` prefix, unknown TPU kinds fall back to v5e;
-    anything else gets the *nominal* CPU peaks (env-overridable via
-    ``ACCELERATE_CPU_PEAK_FLOPS`` FLOP/s / ``ACCELERATE_CPU_HBM_GBPS`` GB/s)
-    so MFU stays a usable relative signal on dev boxes."""
+def peaks_for_device(device: Optional[Any] = None) -> Optional[HardwarePeaks]:
+    """:data:`DEVICE_PEAKS` lookup for ``device`` (default:
+    ``jax.devices()[0]``). A device that is not a TPU has no peak (``None``):
+    a utilization of a dev box's CPU is not a number this package reports. A
+    TPU whose ``device_kind`` is not in the table raises."""
     if device is None:
         import jax
 
         device = jax.devices()[0]
-    kind = str(getattr(device, "device_kind", "") or "")
-    for name, flops in PEAK_FLOPS.items():
-        if kind.startswith(name):
-            return HardwarePeaks(kind, flops, HBM_BYTES_PER_S.get(name))
-    if "TPU" in kind.upper():
-        return HardwarePeaks(
-            kind, PEAK_FLOPS["TPU v5e"], HBM_BYTES_PER_S["TPU v5e"], source="table"
-        )
-    from ..utils.environment import parse_optional_float_from_env
-
-    env_flops = parse_optional_float_from_env(CPU_PEAK_FLOPS_ENV_VAR)
-    env_bw = parse_optional_float_from_env(CPU_HBM_GBPS_ENV_VAR)
-    return HardwarePeaks(
-        kind or "cpu",
-        env_flops if env_flops else _CPU_NOMINAL_FLOPS,
-        (env_bw if env_bw else _CPU_NOMINAL_HBM_GBPS) * 1e9,
-        nominal=True,
-        source="env" if (env_flops or env_bw) else "cpu-nominal",
-    )
-
-
-def device_peak_flops(device: Optional[Any] = None, include_nominal: bool = False) -> float:
-    """Peak bf16 FLOP/s, or ``0.0`` for non-TPU devices unless
-    ``include_nominal`` (bench payloads omit MFU on dev boxes; telemetry
-    reports relative MFU there instead)."""
-    peaks = peaks_for_device(device)
-    if peaks.nominal and not include_nominal:
-        return 0.0
-    return peaks.flops
-
-
-def device_hbm_bandwidth(device: Optional[Any] = None, include_nominal: bool = False) -> Optional[float]:
-    """Peak HBM bytes/s, or ``None`` for non-TPU devices unless ``include_nominal``."""
-    peaks = peaks_for_device(device)
-    if peaks.nominal and not include_nominal:
+    if getattr(device, "platform", None) != "tpu":
         return None
-    return peaks.hbm_bytes_per_s
+    kind = str(device.device_kind)
+    try:
+        flops, hbm = DEVICE_PEAKS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak FLOP/s and HBM bandwidth on record for device_kind {kind!r}: "
+            "add it to accelerate_tpu.telemetry.perf.DEVICE_PEAKS with its source"
+        ) from None
+    return HardwarePeaks(kind, flops, hbm)
+
+
+def device_peak_flops(device: Optional[Any] = None) -> float:
+    """Peak bf16 FLOP/s, or ``0.0`` for a device that is not a TPU."""
+    peaks = peaks_for_device(device)
+    return peaks.flops if peaks else 0.0
+
+
+def device_hbm_bandwidth(device: Optional[Any] = None) -> Optional[float]:
+    """Peak HBM bytes/s, or ``None`` for a device that is not a TPU."""
+    peaks = peaks_for_device(device)
+    return peaks.hbm_bytes_per_s if peaks else None
 
 
 # ------------------------------------------------------------- MFU math ----
@@ -183,13 +143,15 @@ def arithmetic_intensity(flops: float, bytes_accessed: float) -> Optional[float]
     return flops / bytes_accessed
 
 
-def roofline_bucket(intensity: Optional[float], peaks: HardwarePeaks) -> Optional[str]:
+def roofline_bucket(
+    intensity: Optional[float], peaks: Optional[HardwarePeaks]
+) -> Optional[str]:
     """``"compute-bound"`` when the kernel's arithmetic intensity clears the
-    chip's ridge point (peak FLOPs / peak HBM bytes), else ``"hbm-bound"``."""
-    ridge = peaks.ridge_intensity
-    if intensity is None or ridge is None:
+    chip's ridge point (peak FLOPs / peak HBM bytes), else ``"hbm-bound"``;
+    ``None`` where there is no peak to compare with."""
+    if intensity is None or peaks is None:
         return None
-    return "compute-bound" if intensity >= ridge else "hbm-bound"
+    return "compute-bound" if intensity >= peaks.ridge_intensity else "hbm-bound"
 
 
 # -------------------------------------------------------- cost capture ----
@@ -197,12 +159,13 @@ def roofline_bucket(intensity: Optional[float], peaks: HardwarePeaks) -> Optiona
 class CompiledCost:
     """One step function's XLA-reported cost: what `cost_analysis()` /
     `memory_analysis()` said at compile time, plus the derived roofline
-    placement against the chip's peaks."""
+    placement against the chip's peaks (``peaks`` is ``None`` off-TPU, and
+    every field derived from it then is too)."""
 
     name: str
     flops: float
     bytes_accessed: float
-    peaks: HardwarePeaks
+    peaks: Optional[HardwarePeaks]
     memory: Optional[dict] = None
 
     @property
@@ -214,21 +177,22 @@ class CompiledCost:
         return roofline_bucket(self.intensity, self.peaks)
 
     def mfu(self, step_seconds: float) -> Optional[float]:
-        return mfu(self.flops, step_seconds, self.peaks.flops)
+        return mfu(self.flops, step_seconds, self.peaks.flops) if self.peaks else None
 
     def record(self) -> dict:
         """The ``perf`` event payload (stable field names — schema in
         docs/telemetry.md)."""
+        import jax
+
         out = {
             "fn": self.name,
             "flops": self.flops,
             "bytes_accessed": self.bytes_accessed,
             "arithmetic_intensity": _round(self.intensity),
             "roofline": self.roofline,
-            "peak_flops": self.peaks.flops,
-            "peak_hbm_bytes_per_s": self.peaks.hbm_bytes_per_s,
-            "peak_source": self.peaks.source,
-            "device_kind": self.peaks.device_kind,
+            "peak_flops": self.peaks.flops if self.peaks else None,
+            "peak_hbm_bytes_per_s": self.peaks.hbm_bytes_per_s if self.peaks else None,
+            "device_kind": str(jax.devices()[0].device_kind),
         }
         if self.memory:
             out.update({f"memory_{k}": v for k, v in self.memory.items()})
@@ -241,7 +205,7 @@ def _round(x: Optional[float], digits: int = 6) -> Optional[float]:
 
 def capture_enabled() -> bool:
     """Cost capture runs iff telemetry is on and ``ACCELERATE_PERF_CAPTURE``
-    is not explicitly falsy (it costs one extra XLA compile per step fn)."""
+    is not explicitly falsy."""
     if not tel.is_enabled():
         return False
     return os.environ.get(PERF_CAPTURE_ENV_VAR, "").strip().lower() not in (
@@ -289,19 +253,17 @@ def capture_compiled(
     analysis; emits one ``perf`` event and a capacity check (see
     :func:`~accelerate_tpu.telemetry.memory.check_memory_fit`).
 
-    The compile this triggers is excluded from the step profiler's
-    compile-second accounting, so step records keep meaning "compiles the
-    *training* path paid". Since the compile is already paid, the executable
-    is also EXPORTED to the persistent compile cache (when configured —
+    The compile this triggers is the function's own: the jit call that
+    follows with the same arguments reuses the executable and compiles
+    nothing, so the step profiler counts it like any other. Since the
+    compile is already paid, the executable is also EXPORTED to the
+    persistent compile cache (when configured —
     :mod:`accelerate_tpu.compile_cache`), which is what lets the next
     restart generation skip this function's compile entirely. Never raises:
     an uncapturable backend returns ``None`` and training proceeds
     untouched."""
-    from . import step_profiler
-
     if not hasattr(fn, "lower"):
         return None  # eager (disable_jit) or already-AOT: nothing to lower
-    c0, s0 = step_profiler.raw_compile_snapshot()
     try:
         lowered = fn.lower(*args, **(kwargs or {}))
         compiled = lowered.compile()
@@ -315,9 +277,6 @@ def capture_compiled(
             maybe_export(name, lowered, compiled, mesh=mesh)
         except Exception:
             pass  # an unexportable backend must not cost the capture
-    finally:
-        c1, s1 = step_profiler.raw_compile_snapshot()
-        step_profiler.exclude_compiles(c1 - c0, s1 - s0)
     if cost is None:
         return None
     tel.emit("perf", **cost.record())

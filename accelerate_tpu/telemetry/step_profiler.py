@@ -31,10 +31,6 @@ _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 _compile_count = 0
 _compile_secs = 0.0
-# compiles triggered by telemetry itself (the perf cost-capture's AOT
-# compile) — subtracted so step records only count what TRAINING paid
-_excluded_count = 0
-_excluded_secs = 0.0
 _listener_installed = False
 
 # data-wait seconds accumulated by the dataloader since the last step boundary
@@ -61,26 +57,9 @@ def install_compile_listener() -> None:
 
 
 def compile_snapshot() -> "tuple[int, float]":
-    """(total backend compiles, total compile seconds) charged to training so
-    far in this process — compiles the telemetry layer itself triggered (perf
-    cost capture) are excluded."""
-    return _compile_count - _excluded_count, _compile_secs - _excluded_secs
-
-
-def raw_compile_snapshot() -> "tuple[int, float]":
-    """Unadjusted compile totals, for bracketing a telemetry-internal compile
-    (see :func:`exclude_compiles`)."""
+    """(total backend compiles, total compile seconds) in this process so
+    far, since the listener was installed."""
     return _compile_count, _compile_secs
-
-
-def exclude_compiles(count: int, seconds: float) -> None:
-    """Mark ``count`` compiles / ``seconds`` as telemetry-internal: they will
-    not appear in step records' ``compile_s`` or the report's compile totals.
-    Called by :func:`~accelerate_tpu.telemetry.perf.capture_compiled` around
-    its AOT compile."""
-    global _excluded_count, _excluded_secs
-    _excluded_count += max(0, int(count))
-    _excluded_secs += max(0.0, float(seconds))
 
 
 def record_data_wait(seconds: float) -> None:

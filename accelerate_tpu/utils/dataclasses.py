@@ -189,25 +189,25 @@ class JitConfig(KwargsHandler):
     ``utils/dataclasses.py:1024``). Under JAX, jit is default-on; these knobs tune it.
 
     ``donate_params``: donate param/opt-state buffers to the train step (halves HBM
-    for the update). ``persistent_cache_dir`` enables the XLA compilation cache so the
-    reference's "regional compilation" compile-latency win (``benchmarks/torch.compile``)
-    is matched by cache reuse. ``remat_policy`` names a jax.checkpoint policy for
-    activation rematerialisation.
+    for the update). ``persistent_cache_dir`` places JAX's persistent compilation
+    cache — unless ``JAX_COMPILATION_CACHE_DIR`` is set: the cache then lives
+    where the environment says and no code moves it (the path is part of the
+    cache's key, and the machine that runs the program decides where a cache
+    survives). ``remat_policy`` names a jax.checkpoint policy for activation
+    rematerialisation.
     """
 
     disable_jit: bool = field(
         default_factory=lambda: parse_flag_from_env("ACCELERATE_TPU_DISABLE_JIT", False)
     )
     donate_params: bool = True
-    persistent_cache_dir: Optional[str] = field(
-        default_factory=lambda: os.environ.get("ACCELERATE_TPU_COMPILE_CACHE")
-    )
+    persistent_cache_dir: Optional[str] = None
     remat_policy: Optional[str] = None  # e.g. "nothing_saveable", "dots_saveable"
 
     def apply(self) -> None:
         import jax
 
-        if self.persistent_cache_dir:
+        if self.persistent_cache_dir and not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
             jax.config.update("jax_compilation_cache_dir", self.persistent_cache_dir)
         if self.disable_jit:
             jax.config.update("jax_disable_jit", True)

@@ -103,7 +103,7 @@ def is_pytest_available() -> bool:
 
 @functools.lru_cache(maxsize=None)
 def is_tpu_available() -> bool:
-    """True when the default JAX backend is a TPU (incl. tunneled/virtual TPUs)."""
+    """True when the default JAX backend is a TPU."""
     import jax
 
     try:

@@ -1,59 +1,20 @@
-"""Version-bridging shims over renamed jax APIs.
+"""Host-side wire collectives every caller goes through.
 
-The package targets the modern spelling ``jax.shard_map(..., check_vma=...,
-axis_names=...)``; jax < 0.6 ships the same functionality as
-``jax.experimental.shard_map.shard_map(..., check_rep=..., auto=...)``.
-One shim keeps every call site on the modern spelling and translates for
-older installs, so kernels and parallel schedules run unmodified on both.
+Written for the one installed JAX (0.9): ``jax.shard_map``,
+``jax.distributed.is_initialized`` and the gloo flag are called directly at
+their call sites. What stays here are the two ``multihost_utils`` wrappers,
+because they do something the library calls do not: they feed the per-rank
+collective schedule (the jaxlint R4 runtime cross-check) and hand back a host
+array of the caller's dtype.
 """
 
 from __future__ import annotations
 
-from typing import Optional
-
-
-def distributed_is_initialized() -> bool:
-    """``jax.distributed.is_initialized()`` on any installed jax (the public
-    predicate only exists from 0.4.38; older installs expose the same fact as
-    a live coordinator client on the internal global state)."""
-    import jax
-
-    if hasattr(jax.distributed, "is_initialized"):
-        return bool(jax.distributed.is_initialized())
-    try:
-        from jax._src import distributed as _dist
-
-        return _dist.global_state.client is not None
-    except Exception:  # pragma: no cover - exotic jax builds
-        return False
-
-
-def enable_cpu_multiprocess_collectives() -> None:
-    """Turn on cross-process collectives for the CPU backend (gloo).
-
-    The CPU backend refuses multi-process computations unless its collectives
-    implementation is selected; the flag spelling changed across jax versions.
-    Must run before the backend initializes — the multi-host bootstrap calls
-    it right before ``jax.distributed.initialize``. A no-op when neither flag
-    exists (ancient jax) — the subsequent collective raises its own error.
-    """
-    import jax
-
-    for flag, value in (
-        ("jax_cpu_collectives_implementation", "gloo"),
-        ("jax_cpu_enable_gloo_collectives", True),
-    ):
-        try:
-            jax.config.update(flag, value)
-            return
-        except (AttributeError, ValueError):
-            continue
-
 
 def broadcast_one_to_all(x, is_source: bool):
-    """``multihost_utils.broadcast_one_to_all`` that preserves the input dtype
-    (old-jax gloo CPU collectives upcast sub-int32 payloads to int32 in the
-    underlying psum, mangling raw-bytes broadcasts)."""
+    """``multihost_utils.broadcast_one_to_all`` as a host array of the input's
+    dtype (raw-bytes broadcasts must come back as bytes), recorded in the
+    per-rank collective schedule."""
     import numpy as np
     from jax.experimental import multihost_utils
 
@@ -73,8 +34,8 @@ def broadcast_one_to_all(x, is_source: bool):
 
 
 def process_allgather(x, tiled: bool = False):
-    """``multihost_utils.process_allgather`` preserving the input dtype (same
-    old-jax gloo upcast as :func:`broadcast_one_to_all`)."""
+    """``multihost_utils.process_allgather`` as a host array of the input's
+    dtype, recorded like :func:`broadcast_one_to_all`."""
     import numpy as np
     from jax.experimental import multihost_utils
 
@@ -88,47 +49,3 @@ def process_allgather(x, tiled: bool = False):
     if out.dtype != in_dtype:
         out = out.astype(in_dtype)
     return out
-
-
-def shard_map(
-    f,
-    *,
-    mesh,
-    in_specs,
-    out_specs,
-    check_vma: Optional[bool] = None,
-    axis_names=None,
-):
-    """``jax.shard_map`` on any installed jax.
-
-    ``check_vma`` maps to the pre-0.6 ``check_rep``; ``axis_names`` (the axes
-    manual inside the body) maps to the pre-0.6 ``auto`` (its complement over
-    the mesh axes — partial-manual mode, which old jax only supports with
-    replication checking off).
-    """
-    try:
-        from jax import shard_map as _new  # jax >= 0.6 spelling
-    except ImportError:
-        _new = None
-    if _new is not None:
-        kwargs = {}
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return _new(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-    from jax.experimental.shard_map import shard_map as _old
-
-    kwargs = {}
-    if check_vma is not None:
-        kwargs["check_rep"] = check_vma
-    if axis_names is not None and frozenset(mesh.axis_names) - frozenset(axis_names):
-        # the modern partial-manual mode (auto axes) lowers to a PartitionId
-        # instruction old XLA's SPMD partitioner rejects; run fully manual
-        # instead — axes unmentioned by the specs replicate their operands, so
-        # the body computes identically on every auto-axis slice and the
-        # result matches (at the cost of redundant compute on those slices).
-        # Replication checking cannot see that equivalence: off.
-        kwargs["check_rep"] = False
-    return _old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)

@@ -46,14 +46,20 @@ def main(argv=None) -> int:
 
     import jax  # noqa: E402  (env must be set before backends init)
 
+    from benchmarks._common import detect_backend, device_record
     from accelerate_tpu.telemetry import events as tel
+
+    detect_backend()  # fails where there is no chip and no CPU was asked for
+    # cold must be cold: a hit in JAX's persistent cache would hide the
+    # compile the repo's own AOT cache (--cache-dir) is measured against
+    jax.config.update("jax_enable_compilation_cache", False)
 
     # the serve path builds an engine without an Accelerator, which is what
     # normally honors the env kill switch — do it explicitly here so the
     # compile_cache records land in this leg's telemetry dir either way
     tel.maybe_enable_from_env()
 
-    out = {"mode": args.mode, "generation": args.generation}
+    out = {"mode": args.mode, "generation": args.generation, "device": device_record()}
     if args.mode == "train":
         import numpy as np
         import optax

@@ -12,6 +12,12 @@ one shared cache directory:
   serving engine — cold warmup compiles the whole bucket lattice, warm
   warmup loads it.
 
+The parent only sequences the children and never touches JAX: each child in
+turn is the one process that holds the chip, and says which device it ran on.
+The cache directory the legs share is the repo's own AOT cache; JAX's
+persistent compilation cache is switched off in the children (a hit there
+would make the cold leg warm) and is never moved.
+
 The payload carries both wall times per leg plus the ``compile_cache``
 telemetry counts (hit/miss/store/corrupt), so a "warm" leg that silently
 recompiled is visible as miss>0 instead of a fake win.
@@ -80,14 +86,16 @@ def _run_leg(mode: str, cache_dir: str, telemetry_dir: str, generation: int,
     return child
 
 
-def run_restart_bench(on_tpu: bool, root: str, modes: "tuple[str, ...]" = ("train", "serve")) -> dict:
+def run_restart_bench(root: str, modes: "tuple[str, ...]" = ("train", "serve")) -> dict:
     cache_dir = os.path.join(root, "cache")
     os.makedirs(cache_dir, exist_ok=True)
     legs = {}
+    device = None
     metrics = {"train": "restart_to_first_step_s", "serve": "boot_to_first_token_s"}
     for mode, metric in ((m, metrics[m]) for m in modes):
         cold = _run_leg(mode, cache_dir, os.path.join(root, f"tel-{mode}-cold"), 0)
         warm = _run_leg(mode, cache_dir, os.path.join(root, f"tel-{mode}-warm"), 1)
+        device = warm["device"]
         legs[mode] = {
             "metric": metric,
             "cold_s": cold[metric],
@@ -105,7 +113,8 @@ def run_restart_bench(on_tpu: bool, root: str, modes: "tuple[str, ...]" = ("trai
         "bench": "compile_time_restart",
         "unit": "speedup(cold/warm restart-to-first-step)",
         "value": legs.get("train", first)["speedup"],
-        "on_tpu": on_tpu,
+        "device": device,
+        "on_tpu": device["platform"] == "tpu",
         **legs,
     }
 
@@ -124,11 +133,10 @@ if __name__ == "__main__":
 
         emit(run_bench_compile_time(on_tpu=detect_backend()))
     else:
-        on_tpu = detect_backend()
         modes = tuple(m.strip() for m in args.modes.split(",") if m.strip())
         if args.keep_dir:
             os.makedirs(args.keep_dir, exist_ok=True)
-            emit(run_restart_bench(on_tpu, args.keep_dir, modes))
+            emit(run_restart_bench(args.keep_dir, modes))
         else:
             with tempfile.TemporaryDirectory() as tmp:
-                emit(run_restart_bench(on_tpu, tmp, modes))
+                emit(run_restart_bench(tmp, modes))

@@ -10,9 +10,9 @@ an automatic trace window enabled, then prints:
 - one JSON line (bench.py conventions, last line on stdout) with the same
   fields for drivers/tests.
 
-On a dev box this exercises the whole observatory on the CPU backend (MFU is
-*relative* there — nominal peaks, see docs/performance.md); on a TPU it is a
-real utilization reading of the bench step.
+With ``JAX_PLATFORMS=cpu`` this rehearses the whole observatory on the CPU
+backend, where there is no peak: MFU and the roofline bucket are null. On a
+TPU it is a utilization reading of the bench step.
 """
 
 import argparse
@@ -101,12 +101,12 @@ def run_bench_perf(
         return {
             "bench": "perf",
             "unit": "mfu(p50)",
-            "value": mfu.get("p50", 0.0),
+            "value": mfu.get("p50"),  # null off-TPU: a CPU has no peak
             "mfu": {k: mfu.get(k) for k in ("p50", "mean", "max") if k in mfu},
             "roofline": fn.get("roofline"),
             "arithmetic_intensity": fn.get("arithmetic_intensity"),
             "flops_per_step": fn.get("flops"),
-            "peak_source": fn.get("peak_source"),
+            "device_kind": fn.get("device_kind"),
             "overlap_ratio": trace.get("comms_overlap_ratio"),
             "trace_windows": trace.get("windows", 0),
             "top_ops": (trace.get("top_ops") or [])[:3],

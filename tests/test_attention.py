@@ -13,7 +13,6 @@ from accelerate_tpu.ops.attention import (
     make_padding_mask,
 )
 from accelerate_tpu.ops.flash_attention import flash_attention
-from accelerate_tpu.ops.fused_attention import fused_attention, fused_supported
 from accelerate_tpu.test_utils.testing import require_tpu
 
 
@@ -82,77 +81,6 @@ class TestSegmentIds:
             )
 
 
-class TestFusedKernel:
-    def test_supported_shapes(self):
-        q = jnp.zeros((4, 128, 12, 64))
-        k = jnp.zeros((4, 128, 12, 64))
-        assert fused_supported(q, k)
-        assert fused_supported(q, jnp.zeros((4, 128, 4, 64)))  # GQA
-        assert not fused_supported(q, jnp.zeros((4, 256, 12, 64)))  # cross-len
-        assert not fused_supported(jnp.zeros((4, 96, 12, 64)), jnp.zeros((4, 96, 12, 64)))
-
-    def test_fused_impl_dispatch_and_fallback(self):
-        """impl='fused' routes through fused_attention; off-TPU it must equal
-        the xla path exactly (same mask construction)."""
-        q, k, v = _qkv()
-        seg = np.ones((2, 32), np.int32)
-        seg[:, 24:] = 0
-        out_fused = dot_product_attention(q, k, v, segment_ids=jnp.asarray(seg), impl="fused")
-        out_xla = dot_product_attention(q, k, v, segment_ids=jnp.asarray(seg), impl="xla")
-        np.testing.assert_allclose(np.asarray(out_fused), np.asarray(out_xla), atol=1e-6)
-
-    def test_fused_rejects_arbitrary_mask(self):
-        q, k, v = _qkv()
-        with pytest.raises(ValueError):
-            dot_product_attention(q, k, v, mask=jnp.ones((2, 1, 32, 32), bool), impl="fused")
-
-
-@require_tpu
-class TestFusedParityTPU:
-    """Single-pass Pallas kernel vs einsum reference on real TPU hardware."""
-
-    def test_fused_matches_xla_under_padding(self):
-        b, s, h, d = 4, 128, 12, 64
-        keys = jax.random.split(jax.random.PRNGKey(2), 3)
-        q, k, v = (jax.random.normal(kk, (b, s, h, d), jnp.float32) for kk in keys)
-        seg = np.ones((b, s), np.int32)
-        seg[:, 100:] = 0
-        seg = jnp.asarray(seg)
-        out_fused = dot_product_attention(q, k, v, segment_ids=seg, impl="fused")
-        out_xla = dot_product_attention(q, k, v, segment_ids=seg, impl="xla")
-        np.testing.assert_allclose(
-            np.asarray(out_fused[:, :100]), np.asarray(out_xla[:, :100]), atol=1e-2
-        )
-
-    def test_fused_grads_match_xla(self):
-        b, s, h, d = 4, 128, 12, 64
-        keys = jax.random.split(jax.random.PRNGKey(3), 3)
-        q, k, v = (jax.random.normal(kk, (b, s, h, d), jnp.float32) for kk in keys)
-        seg = np.ones((b, s), np.int32)
-        seg[:, 96:] = 0
-        seg = jnp.asarray(seg)
-
-        def loss(impl, q, k, v):
-            out = dot_product_attention(q, k, v, segment_ids=seg, impl=impl)
-            return jnp.sum(out[:, :96] ** 2)
-
-        gf = jax.grad(lambda *a: loss("fused", *a), argnums=(0, 1, 2))(q, k, v)
-        gx = jax.grad(lambda *a: loss("xla", *a), argnums=(0, 1, 2))(q, k, v)
-        for a, b_ in zip(gf, gx):
-            rel = float(jnp.abs(a - b_).max() / (jnp.abs(b_).max() + 1e-9))
-            assert rel < 2e-2, rel
-
-    def test_fused_causal_gqa(self):
-        b, s, h, d = 4, 128, 8, 64
-        keys = jax.random.split(jax.random.PRNGKey(4), 3)
-        q = jax.random.normal(keys[0], (b, s, h, d), jnp.float32)
-        k = jax.random.normal(keys[1], (b, s, 2, d), jnp.float32)
-        v = jax.random.normal(keys[2], (b, s, 2, d), jnp.float32)
-        out_fused = dot_product_attention(q, k, v, causal=True, impl="fused")
-        out_xla = dot_product_attention(q, k, v, causal=True, impl="xla")
-        np.testing.assert_allclose(np.asarray(out_fused), np.asarray(out_xla), atol=1e-2)
-
-
 @require_tpu
 class TestFlashParityTPU:
     """Pallas kernel vs einsum reference on real TPU hardware."""
@@ -208,23 +136,23 @@ class TestAttnImplConfigKnob:
     def test_config_default_is_auto_and_round_trips(self):
         replace, cfg, _, _, _ = self._setup()
         assert cfg.attn_impl == "auto"
-        assert replace(cfg, attn_impl="fused").attn_impl == "fused"
+        assert replace(cfg, attn_impl="flash").attn_impl == "flash"
         assert cfg.attn_impl == "auto"  # frozen original untouched
 
-    def test_fused_knob_matches_xla_off_tpu(self):
-        """impl='fused' falls back to the xla mask path off TPU, so wiring
-        the knob through the config must reproduce attn_impl='xla' exactly."""
+    def test_flash_knob_matches_xla_off_tpu(self):
+        """Off TPU the flash impl IS the einsum reference, so wiring the knob
+        through the config must reproduce attn_impl='xla' exactly."""
         replace, cfg, params, ids, llama_forward = self._setup()
-        out_fused = llama_forward(params, ids, replace(cfg, attn_impl="fused"))
+        out_flash = llama_forward(params, ids, replace(cfg, attn_impl="flash"))
         out_xla = llama_forward(params, ids, replace(cfg, attn_impl="xla"))
         np.testing.assert_allclose(
-            np.asarray(out_fused), np.asarray(out_xla), atol=1e-6
+            np.asarray(out_flash), np.asarray(out_xla), atol=1e-6
         )
 
     def test_explicit_argument_overrides_config(self):
         replace, cfg, params, ids, llama_forward = self._setup()
         out_arg = llama_forward(
-            params, ids, replace(cfg, attn_impl="fused"), attention_impl="xla"
+            params, ids, replace(cfg, attn_impl="flash"), attention_impl="xla"
         )
         out_xla = llama_forward(params, ids, replace(cfg, attn_impl="xla"))
         assert np.array_equal(np.asarray(out_arg), np.asarray(out_xla))

@@ -80,11 +80,11 @@ def test_load_only_probe_never_compiles(tmp_path, step_fn, step_args):
     loaded, key = cc.maybe_load_executable("step", step_fn, step_args, directory=str(tmp_path))
     assert loaded is None  # empty cache: miss, and load-only must NOT compile
     _populate(tmp_path, step_fn, step_args)
-    c0 = sp.raw_compile_snapshot()[0]
+    c0 = sp.compile_snapshot()[0]
     loaded, key = cc.maybe_load_executable("step", step_fn, step_args, directory=str(tmp_path))
     assert loaded is not None and key is not None
     got = loaded(*step_args)
-    assert sp.raw_compile_snapshot()[0] == c0  # zero backend compiles on the warm path
+    assert sp.compile_snapshot()[0] == c0  # zero backend compiles on the warm path
     np.testing.assert_array_equal(
         np.asarray(step_fn(*step_args)["w"]), np.asarray(got["w"])
     )

@@ -311,10 +311,31 @@ class TestDispatch:
         with pytest.raises(ValueError, match="causal"):
             dot_product_attention(q, k, v, window=8, impl="xla")
 
-    def test_fused_rejects_window(self):
+    def test_explicit_flash_never_gives_way_on_tpu(self, monkeypatch):
+        """On a TPU an explicitly requested kernel that cannot take the shape
+        raises and names it; only impl="auto" may choose the einsum path (and
+        off-TPU the einsum reference is the implementation)."""
         q, k, v = _qkv(s=32)
-        with pytest.raises(ValueError, match="window"):
-            dot_product_attention(q, k, v, causal=True, window=8, impl="fused")
+        kx = jnp.concatenate([k, k], axis=1)  # cross-attention: Sq != Skv
+        vx = jnp.concatenate([v, v], axis=1)
+        ref = dot_product_attention(q, kx, vx, impl="xla")
+        assert bool(jnp.all(flash_attention(q, kx, vx) == ref))  # CPU: reference
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(ValueError, match=r"cannot tile q=\(2, 32, "):
+            dot_product_attention(q, kx, vx, impl="flash")
+        assert bool(jnp.all(dot_product_attention(q, kx, vx, impl="auto") == ref))
+
+    def test_auto_choice_is_logged_once_per_shape(self, monkeypatch):
+        from accelerate_tpu.ops import attention as attn
+
+        lines = []
+        monkeypatch.setattr(attn.logger, "info", lambda msg, *a, **k: lines.append(msg))
+        attn._log_auto_choice.cache_clear()
+        q, k, v = _qkv(s=32)
+        for _ in range(2):
+            dot_product_attention(q, k, v, causal=True, impl="auto")
+        assert len(lines) == 1
+        assert "chose 'xla'" in lines[0] and "q=(2, 32," in lines[0]
 
     def test_xla_window_band(self):
         """The xla path's band mask equals an explicit additive window mask."""

@@ -20,6 +20,34 @@ def test_native_builds():
     assert is_native_available()
 
 
+def test_rebuild_is_keyed_on_source_content_not_mtimes(monkeypatch, tmp_path):
+    """A copied or freshly checked-out tree has no mtimes worth trusting: the
+    library is rebuilt when the sources' content (or the flags) changed, and
+    only then."""
+    import os
+
+    from accelerate_tpu.native import build
+
+    assert is_native_available()  # built, and stamped with the sources' digest
+    digest = build._source_digest()
+    assert not build._needs_build(digest)
+    src = build._sources()[0]
+    stat = os.stat(src)
+    try:
+        os.utime(src, (stat.st_atime, os.path.getmtime(build._LIB) + 3600))  # "newer"
+        assert not build._needs_build(build._source_digest())
+    finally:
+        os.utime(src, (stat.st_atime, stat.st_mtime))
+    edited = tmp_path / "src"
+    edited.mkdir()
+    for path in build._sources():
+        (edited / os.path.basename(path)).write_bytes(open(path, "rb").read())
+    (edited / os.path.basename(src)).write_bytes(open(src, "rb").read() + b"\n// edited\n")
+    monkeypatch.setattr(build, "_SRC_DIR", str(edited))
+    assert build._source_digest() != digest
+    assert build._needs_build(build._source_digest())
+
+
 def test_parallel_collate_matches_stack():
     rng = np.random.default_rng(0)
     samples = [rng.normal(size=(128, 64)).astype(np.float32) for _ in range(32)]

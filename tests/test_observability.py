@@ -274,8 +274,10 @@ class TestMetricsExporter:
         tel.enable(out_dir=str(tmp_path), run_id="m")
         metrics.enable()
         metrics.inc("x_total")
-        assert metrics.maybe_snapshot() is True
-        assert metrics.maybe_snapshot() is False  # inside the interval
+        # explicit clock readings near zero: a freshly booted machine, where
+        # time.monotonic() is smaller than the interval, must still snapshot
+        assert metrics.maybe_snapshot(now=5.0) is True
+        assert metrics.maybe_snapshot(now=3000.0) is False  # inside the interval
         tel.disable()
         recs = [json.loads(l) for l in open(tmp_path / "events-rank0.jsonl")]
         assert sum(1 for r in recs if r["kind"] == "metrics") == 1

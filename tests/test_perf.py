@@ -23,8 +23,7 @@ from accelerate_tpu.utils.dataclasses import ProfileConfig
 @pytest.fixture(autouse=True)
 def _telemetry_clean(monkeypatch):
     for var in ("ACCELERATE_TELEMETRY", "ACCELERATE_TELEMETRY_DIR",
-                "ACCELERATE_PERF_CAPTURE", "ACCELERATE_CPU_PEAK_FLOPS",
-                "ACCELERATE_CPU_HBM_GBPS", "ACCELERATE_TRACE_EVERY",
+                "ACCELERATE_PERF_CAPTURE", "ACCELERATE_TRACE_EVERY",
                 "ACCELERATE_TRACE_STEPS", "ACCELERATE_TRACE_AT",
                 "ACCELERATE_TRACE_DIR"):
         monkeypatch.delenv(var, raising=False)
@@ -33,40 +32,50 @@ def _telemetry_clean(monkeypatch):
 
 
 class _FakeDevice:
-    def __init__(self, kind):
+    def __init__(self, kind, platform="tpu"):
         self.device_kind = kind
+        self.platform = platform
+
+
+V5E = perf.HardwarePeaks("TPU v5 lite", 197e12, 819e9)
+
+
+@pytest.fixture
+def v5e_peaks(monkeypatch):
+    """The plumbing from captured cost to per-step mfu/roofline, driven on the
+    CPU by standing a v5e's peaks in for the lookup (a CPU itself has none)."""
+    monkeypatch.setattr(perf, "peaks_for_device", lambda device=None: V5E)
 
 
 # ------------------------------------------------------------ peak registry --
 
 
 @pytest.mark.smoke
-def test_peak_registry_table_and_fallbacks(monkeypatch):
-    v5e = perf.peaks_for_device(_FakeDevice("TPU v5e"))
+def test_peak_table_is_keyed_by_device_kind():
+    v5e = perf.peaks_for_device(_FakeDevice("TPU v5 lite"))
     assert v5e.flops == 197e12 and v5e.hbm_bytes_per_s == 819e9
-    assert not v5e.nominal and v5e.source == "table"
+    assert v5e.device_kind == "TPU v5 lite"
     assert v5e.ridge_intensity == pytest.approx(197e12 / 819e9)
-    # unknown TPU generations fall back to v5e instead of reporting nothing
-    unknown = perf.peaks_for_device(_FakeDevice("TPU v99 mega"))
-    assert unknown.flops == 197e12 and not unknown.nominal
-    # non-TPU: nominal peaks keep MFU a usable relative signal on dev boxes
-    cpu = perf.peaks_for_device(_FakeDevice(""))
-    assert cpu.nominal and cpu.flops > 0 and cpu.source == "cpu-nominal"
-    monkeypatch.setenv("ACCELERATE_CPU_PEAK_FLOPS", "2e12")
-    monkeypatch.setenv("ACCELERATE_CPU_HBM_GBPS", "100")
-    tuned = perf.peaks_for_device(_FakeDevice("cpu"))
-    assert tuned.flops == 2e12 and tuned.hbm_bytes_per_s == 100e9
-    assert tuned.nominal and tuned.source == "env"
+    # every entry is one (FLOP/s, bytes/s) pair: no kind has half a peak
+    assert all(len(v) == 2 and min(v) > 0 for v in perf.DEVICE_PEAKS.values())
 
 
-def test_device_peak_helpers_gate_nominal_peaks():
-    """bench.py omits MFU on dev boxes (no absolute peak exists); the
-    telemetry path opts into the nominal stand-in explicitly."""
-    cpu = _FakeDevice("cpu")
+def test_unknown_tpu_kind_raises():
+    """A device that is not in the table is an error, not a default: an
+    unknown TPU once borrowed the v5e's peaks and reported an MFU against them."""
+    with pytest.raises(ValueError, match="TPU v99 mega"):
+        perf.peaks_for_device(_FakeDevice("TPU v99 mega"))
+    with pytest.raises(ValueError, match="DEVICE_PEAKS"):
+        perf.device_peak_flops(_FakeDevice("TPU v5 lite pod"))  # no prefix match either
+
+
+def test_cpu_has_no_peak():
+    """No nominal stand-in: off-TPU there is no peak, so no MFU or roofline."""
+    cpu = _FakeDevice("cpu", platform="cpu")
+    assert perf.peaks_for_device(cpu) is None
+    assert perf.peaks_for_device() is None  # the suite's own CPU devices
     assert perf.device_peak_flops(cpu) == 0.0
-    assert perf.device_peak_flops(cpu, include_nominal=True) > 0
     assert perf.device_hbm_bandwidth(cpu) is None
-    assert perf.device_hbm_bandwidth(cpu, include_nominal=True) > 0
     tpu = _FakeDevice("TPU v4")
     assert perf.device_peak_flops(tpu) == 275e12
     assert perf.device_hbm_bandwidth(tpu) == 1228e9
@@ -91,8 +100,7 @@ def test_roofline_bucket_straddles_ridge():
     assert perf.roofline_bucket(ridge, peaks) == "compute-bound"  # >= is compute
     assert perf.roofline_bucket(ridge / 2, peaks) == "hbm-bound"
     assert perf.roofline_bucket(None, peaks) is None
-    no_bw = perf.HardwarePeaks("x", 1e12, None)
-    assert perf.roofline_bucket(100.0, no_bw) is None
+    assert perf.roofline_bucket(100.0, None) is None  # off-TPU: no peak, no bucket
 
 
 def test_train_flops_per_sample_golden():
@@ -129,7 +137,7 @@ def _events(tmp_path):
     return out
 
 
-def test_capture_compiled_records_cost_and_memory(tmp_path):
+def test_capture_compiled_records_cost_and_memory(tmp_path, v5e_peaks):
     tel.enable(str(tmp_path))
 
     @jax.jit
@@ -165,8 +173,9 @@ def test_capture_tolerates_unlowerable_fn(tmp_path):
     assert perf.capture_compiled("eager", lambda x: x, (1,)) is None
 
 
-def test_capture_compile_excluded_from_step_accounting(tmp_path):
-    """The capture's AOT compile must not inflate step compile_s/compiles."""
+def test_capture_compile_is_the_functions_one_compile(tmp_path):
+    """The capture's AOT compile is counted, once, and the jit call that
+    follows reuses its executable: the function costs one compile in all."""
     from accelerate_tpu.telemetry import step_profiler
 
     tel.enable(str(tmp_path))
@@ -176,12 +185,13 @@ def test_capture_compile_excluded_from_step_accounting(tmp_path):
     def fn(x):
         return x * 2 + 1
 
-    ones = jnp.ones((8, 8))  # the array-creation compile is real training cost
+    ones = jnp.ones((8, 8))
     c0, s0 = step_profiler.compile_snapshot()
     perf.capture_compiled("fn", fn, (ones,))
     c1, s1 = step_profiler.compile_snapshot()
-    assert c1 == c0  # the AOT compile was bracketed out
-    assert s1 == pytest.approx(s0, abs=1e-6)
+    assert c1 == c0 + 1 and s1 > s0
+    fn(ones).block_until_ready()
+    assert step_profiler.compile_snapshot()[0] == c1
 
 
 # ----------------------------------------------------- accelerator integration
@@ -213,7 +223,7 @@ def _tiny_train(tmp_path, steps=4, handlers=None):
     return acc
 
 
-def test_accelerator_steps_carry_mfu_and_roofline(tmp_path):
+def test_accelerator_steps_carry_mfu_and_roofline(tmp_path, v5e_peaks):
     tel.enable(str(tmp_path))
     _tiny_train(tmp_path)
     tel.disable()
@@ -227,9 +237,24 @@ def test_accelerator_steps_carry_mfu_and_roofline(tmp_path):
         assert s["roofline"] in ("compute-bound", "hbm-bound")
         assert s["perf_fn"] == "train_step"
         assert s["arithmetic_intensity"] > 0
-    # only the training path's jit compile lands in step records — the AOT
-    # capture compile is excluded (one compile total, on the first step)
+    # the capture's AOT compile is the step function's one compile (the jit
+    # call reuses it), and it lands in the first step's record
     assert sum(s["compiles"] for s in steps) == 1 and steps[0]["compiles"] == 1
+
+
+def test_accelerator_steps_carry_no_mfu_off_tpu(tmp_path):
+    """On the CPU there is no peak: the cost is still captured, the step
+    records carry no mfu/roofline rather than a nominal one."""
+    tel.enable(str(tmp_path))
+    _tiny_train(tmp_path)
+    tel.disable()
+    events = _events(tmp_path)
+    (captured,) = [e for e in events if e["kind"] == "perf"]
+    assert captured["flops"] > 0 and captured["peak_flops"] is None
+    assert captured["roofline"] is None and captured["device_kind"] == "cpu"
+    steps = [e for e in events if e["kind"] == "step"]
+    assert len(steps) == 4
+    assert all("mfu" not in s and "roofline" not in s for s in steps)
 
 
 def test_accelerator_capture_kill_switch(tmp_path, monkeypatch):

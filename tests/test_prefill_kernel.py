@@ -223,5 +223,8 @@ def test_engine_multi_chunk_prefill_through_interpreted_kernel(monkeypatch):
             for i, (p, (_, n)) in enumerate(zip(prompts, specs))]
     engine.run()
     for i, ((_, n), req) in enumerate(zip(specs, reqs)):
-        ref = greedy_generate(params, prompts[i][None], CONFIG, max_new_tokens=n)
+        # the reference keeps its cache in the engine's dtype: against the
+        # default bf16 cache, request 2's second token is a 6e-5 near-tie
+        ref = greedy_generate(params, prompts[i][None], CONFIG, max_new_tokens=n,
+                              cache_dtype=jnp.float32)
         assert np.array_equal(np.asarray(ref[0]), req.output_ids()), f"request {i}"

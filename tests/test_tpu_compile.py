@@ -1,0 +1,125 @@
+"""The kernels the TPU dispatch can reach, handed to the chip's own compiler.
+
+Interpret mode runs a kernel's body on the CPU and accepts what Mosaic, the
+TPU's kernel compiler, refuses: a block shape off the (8, 128) tiling, a
+vector read out of SMEM, more scoped VMEM than a kernel may hold. PR 21 found
+three of the four kernel families refused that way while every interpret-mode
+test passed. These tests compile each kernel, at real widths, for a v5e that
+is DESCRIBED (``v5e:2x2``), not attached: nothing runs, so they say nothing
+about results or times — only that the chip's compiler takes the program and
+that a Mosaic kernel (``tpu_custom_call``) is in it.
+
+The topology is described inside a fixture, never at import, in a ``skipif``
+or in a ``parametrize`` argument: only one process may load the TPU's library,
+and under pytest-xdist every worker imports every test file. Keep these tests
+in this ONE file, for the same reason.
+"""
+
+import importlib
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+# the module, not the function ``accelerate_tpu.ops`` re-exports under its name
+fa = importlib.import_module("accelerate_tpu.ops.flash_attention")
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """Sharding on one described v5e chip; JAX's persistent compilation cache
+    is off while the module runs (an entry compiled for a described chip is
+    written but cannot be read back without one: the next run would warn)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+# (B, S, H, Hkv, D, causal, window, segments): the widths of PR 21's step A
+FLASH_CASES = {
+    "dense-mha12-d64-s512": (4, 512, 12, 12, 64, False, None, False),
+    "causal-gqa32x8-d128-s2048": (2, 2048, 32, 8, 128, True, None, False),
+    "window+segments-mha16-d128-s4096": (1, 4096, 16, 16, 128, True, 1024, True),
+}
+
+
+@pytest.mark.parametrize("direction", ["fwd", "fwd+bwd"])
+@pytest.mark.parametrize("case", list(FLASH_CASES))
+def test_flash_attention_compiles_for_v5e(one_chip, case, direction):
+    B, S, H, Hkv, D, causal, window, segments = FLASH_CASES[case]
+
+    def fwd(q, k, v, seg):
+        return fa._flash_kernel(
+            q, k, v, seg if segments else None,
+            causal=causal, sm_scale=D ** -0.5, window=window,
+        )
+
+    def fwd_bwd(q, k, v, seg):
+        loss = lambda q, k, v: fwd(q, k, v, seg).astype(jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    _compile(
+        fwd if direction == "fwd" else fwd_bwd, one_chip,
+        ((B, S, H, D), BF16), ((B, S, Hkv, D), BF16), ((B, S, Hkv, D), BF16),
+        ((B, S), jnp.int32),
+    )
+
+
+# (B, H, Hkv, D, block_size, W)
+@pytest.mark.parametrize(
+    "B,H,Hkv,D,block_size,W",
+    [(8, 16, 8, 64, 16, 32), (8, 32, 8, 128, 16, 64), (4, 16, 8, 64, 128, 8)],
+    ids=["b8-16x8-d64-bs16", "b8-32x8-d128-bs16", "b4-16x8-d64-bs128"],
+)
+def test_paged_decode_compiles_for_v5e(one_chip, B, H, Hkv, D, block_size, W):
+    pool = ((512, block_size, Hkv, D), BF16)
+    _compile(
+        fa.paged_attention_decode, one_chip,
+        ((B, 1, H, D), BF16), pool, pool, ((B, W), jnp.int32), ((B,), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("block_size", [16, 128])
+@pytest.mark.parametrize("chunk", [128, 512])
+def test_paged_prefill_compiles_for_v5e(one_chip, chunk, block_size):
+    B, H, Hkv, D = 1, 16, 8, 64
+    pool = ((512, block_size, Hkv, D), BF16)
+    _compile(
+        fa.paged_attention_prefill, one_chip,
+        ((B, chunk, H, D), BF16), pool, pool,
+        ((B, 1024 // block_size), jnp.int32), ((B, chunk), jnp.int32),
+    )
+
+
+def test_prefill_query_tile_stays_inside_scoped_vmem():
+    """chunk 512 at 16 heads was 27 MB of scoped VMEM in one program against
+    a 16 MB limit: the query tile is what keeps a program inside it."""
+    assert fa._prefill_query_tile(128, 16, 64) == 128
+    assert fa._prefill_query_tile(512, 16, 64) == 128
+    assert fa._prefill_query_tile(512, 32, 128) == 64
+    assert fa._prefill_query_tile(4, 32, 128) == 4  # a verify step's k+1 tokens
+    with pytest.raises(ValueError, match="cannot tile S=4099"):
+        fa._prefill_query_tile(4099, 16, 64)  # prime: no multiple of 8 divides it

@@ -266,3 +266,48 @@ class TestTqdm:
         bar = tqdm(range(2), disable=True)
         assert bar.disable
         list(bar)
+
+
+def test_env_compile_cache_dir_wins_over_jit_config(monkeypatch, tmp_path):
+    """Where JAX_COMPILATION_CACHE_DIR is set JAX's persistent cache lives
+    there and no code moves it: JitConfig(persistent_cache_dir=...) only
+    places the cache when the environment has not."""
+    import jax
+
+    from accelerate_tpu.utils.dataclasses import JitConfig
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "from_env"))
+        JitConfig(persistent_cache_dir=str(tmp_path / "from_code")).apply()
+        assert jax.config.jax_compilation_cache_dir == before  # untouched
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        JitConfig(persistent_cache_dir=str(tmp_path / "from_code")).apply()
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path / "from_code")
+        assert JitConfig().persistent_cache_dir is None  # no env name of our own
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_measurement_scripts_share_one_fixed_cache_dir(monkeypatch, tmp_path):
+    """benchmarks/_common.enable_jax_cache: the environment's directory where
+    it names one, else the fixed in-checkout path — never a temp dir."""
+    import os
+    import sys
+
+    import jax
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, repo)
+    from benchmarks._common import enable_jax_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_jax_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before  # untouched
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_jax_cache() == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == os.path.join(repo, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
