@@ -355,7 +355,9 @@ def _row_spec(width, index_map):
     return pl.BlockSpec((1, 1, width), index)
 
 
-def _flash_pallas_call(kernel, cfg, grid, in_specs, out_specs, out_shape, scratch):
+def _flash_pallas_call(kernel, name, cfg, grid, in_specs, out_specs, out_shape, scratch):
+    """``name`` is the kernel's stable name: it becomes the compiled
+    instruction's name, which is how a device trace tells the kernels apart."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -367,7 +369,8 @@ def _flash_pallas_call(kernel, cfg, grid, in_specs, out_specs, out_shape, scratc
         scratch_shapes=scratch,
     )
     return pl.pallas_call(
-        kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=cfg.interpret
+        kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=cfg.interpret,
+        name=name,
     )
 
 
@@ -427,6 +430,7 @@ def _flash_call_fwd(q3, k3, v3, seg, cfg):
     ]
     out, lse = _flash_pallas_call(
         partial(_flash_fwd_kernel, cfg=cfg),
+        "flash_fwd",
         cfg, (BH, nq, nkv), in_specs, out_specs, out_shape, scratch,
     )(ids, counts, q3, k3, v3, seg[:, None], seg[:, None])
     return out, (q3, k3, v3, seg, lse, out)
@@ -459,6 +463,7 @@ def _flash_call_bwd(cfg, res, do):
     row_spec = _row_spec(bq, lambda g, qi, t, ids, cnt: (g, qi))
     dq = _flash_pallas_call(
         partial(_flash_dq_kernel, cfg=cfg),
+        "flash_dq",
         cfg,
         (BH, nq, nkv),
         [
@@ -503,6 +508,7 @@ def _flash_call_bwd(cfg, res, do):
     kvT_spec = pl.BlockSpec((1, bkv, D), lambda a, j, t, ids, cnt: (a, j, 0))
     dk, dv = _flash_pallas_call(
         partial(_flash_dkdv_kernel, cfg=cfg),
+        "flash_dkdv",
         cfg,
         (B * Hkv, nkv, nq * groups),
         [
@@ -782,6 +788,7 @@ def paged_attention_decode(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=interpret,
+        name="paged_decode",
     )(
         block_tables.astype(jnp.int32),
         jnp.asarray(kv_lens, jnp.int32).reshape(B),
@@ -954,6 +961,7 @@ def paged_attention_prefill(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, S, H, D), q.dtype),
         interpret=interpret,
+        name="paged_prefill",
     )(
         block_tables.astype(jnp.int32),
         jnp.asarray(q_positions, jnp.int32).reshape(B, S, 1),

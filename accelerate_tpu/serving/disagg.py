@@ -343,28 +343,16 @@ class PrefillEngine(ServingEngine):
         out, self._handoffs = self._handoffs, []
         return out
 
-    def step(self, now: Optional[float] = None) -> "list[Request]":
-        now = time.monotonic() if now is None else now
+    def _step(self) -> "list[Request]":
+        # ``ServingEngine.step`` wraps this in the ``atpu.serve.step`` phase;
+        # admission and each prefill record their own phases inside it
         step_t0 = time.monotonic()
         finished: "list[Request]" = []
         prefills = 0
         prefill_tokens_before = self.prefill_tokens
         prefix_cached_before = self.prefix_cached_tokens
-        admitted = self.scheduler.admissions()
-        while self.scheduler.rejected:
-            req = self.scheduler.rejected.pop()
-            req.finish_t = now
-            self._close_trace(req, "rejected")
-            finished.append(req)
-            if _metrics.is_enabled():
-                _metrics.inc("accelerate_engine_requests_total", outcome="rejected")
-            if tel.is_enabled():
-                tel.emit(
-                    "serving_request", rid=req.rid, error=req.error,
-                    new_tokens=0, prompt_tokens=int(req.prompt.size),
-                )
-        for req in admitted:
-            self._prefill_request(req, now)
+        for req in self._admit(finished):
+            self._prefill_request(req)
             prefills += 1
             # chaos point "kv_handoff": the prefill work is DONE but the
             # handoff has not left yet — a crash here is the dropped-handoff
@@ -385,8 +373,9 @@ class PrefillEngine(ServingEngine):
             # complete BEFORE shipping: frees the sequence, parking its
             # registered blocks in this engine's LRU (the tier-local prompt
             # cache); the wire dict snapshotted the content already
-            self.scheduler.complete(req, now)
-            self._close_trace(req, "handoff")
+            t_ns, t = self._clock()
+            self.scheduler.complete(req, t)
+            self._close_trace(req, "handoff", t_ns)
             self.handoffs_packed += 1
             self._handoffs.append((req, wire))
             if _metrics.is_enabled():

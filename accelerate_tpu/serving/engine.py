@@ -30,6 +30,7 @@ the same Megatron decode dataflow as ``generation_shardings``.
 
 from __future__ import annotations
 
+import itertools
 import time
 from functools import partial
 from typing import Optional
@@ -58,6 +59,11 @@ from .kv_pager import NULL_BLOCK, BlockAllocator, init_block_pool
 from .scheduler import Request, Scheduler
 
 __all__ = ["ServingEngine", "paged_forward"]
+
+# the ``engine=`` key of an engine's phases and request records: a sequence
+# number unique in the process (``heartbeat_name`` defaults to one string for
+# every engine, so it cannot tell two engines' records apart)
+_engine_ids = itertools.count()
 
 
 def _chaos_inject(point: str, step: int) -> None:
@@ -340,6 +346,11 @@ class ServingEngine:
         _tracing.maybe_arm_from_env()
         _metrics.maybe_enable_from_env()
 
+        self.engine_id = next(_engine_ids)
+        #: the ``now`` the caller gave the step in progress (a simulated
+        #: clock), else None: every stamp is then read when its event happens
+        self._given_now: Optional[float] = None
+
         # stats for the telemetry records / bench payloads
         self.steps = 0
         self.decode_tokens = 0
@@ -577,8 +588,67 @@ class ServingEngine:
         live slot, complete/free finished sequences. Returns the requests
         that left the engine this step — status FINISHED, or REJECTED (with
         ``Request.error`` set) for requests whose worst case can never fit
-        this engine's pool/lattice."""
-        now = time.monotonic() if now is None else now
+        this engine's pool/lattice.
+
+        The step and its phases are recorded as ``atpu.serve.*`` spans
+        (:func:`accelerate_tpu.telemetry.tracing.phase`): ``step`` round
+        everything, and inside it the disjoint children ``admit``,
+        ``prefill`` (one per admitted request), ``grow``, ``build``,
+        ``dispatch``, ``fetch`` and ``emit``; what is left of ``step`` is the
+        watchdog beat, metrics and event emission. ``now`` stands in for the
+        clock in every ``Request`` stamp of this step (simulated time)."""
+        self._given_now = now
+        with self._phase("step"):
+            return self._step()
+
+    def _phase(self, name: str, **key):
+        return _tracing.phase(
+            "atpu.serve." + name, engine=self.engine_id, step=self.steps, **key
+        )
+
+    def _clock(self) -> "tuple[int, float]":
+        """One read of the clock at an event, as ``(monotonic ns, seconds)``:
+        the ns close the request's trace spans, the seconds are the
+        ``Request`` stamp (the caller's ``now`` where ``step`` was given
+        one), so the two can never disagree."""
+        t_ns = _tracing.now_ns()
+        return t_ns, (t_ns / 1e9 if self._given_now is None else self._given_now)
+
+    def _admit(self, finished: "list[Request]") -> "list[Request]":
+        """The admission phase: place what fits (stamping first admissions
+        and closing their ``queue_wait`` spans at the same read) and hand
+        back what can never run as REJECTED."""
+        with self._phase("admit"):
+            t_ns, t = self._clock()
+            admitted = self.scheduler.admissions(now=t, step=self.steps)
+            for req in admitted:
+                if req._span_queue is not None and "t1_ns" not in req._span_queue:
+                    _tracing.span_close(req._span_queue, t1_ns=t_ns)
+            while self.scheduler.rejected:
+                req = self.scheduler.rejected.pop()
+                req.finish_t = t
+                self._close_trace(req, "rejected", t_ns)
+                finished.append(req)  # returned to the caller, status REJECTED
+                if _metrics.is_enabled():
+                    _metrics.inc("accelerate_engine_requests_total", outcome="rejected")
+                if tel.is_enabled():
+                    tel.emit(
+                        "serving_request", rid=req.rid, error=req.error,
+                        new_tokens=0, prompt_tokens=int(req.prompt.size),
+                    )
+        return admitted
+
+    def _complete_done(self, requests, finished: "list[Request]") -> None:
+        """Complete every request of ``requests`` that is done: free its
+        blocks, stamp ``finish_t`` and write its records."""
+        for req in requests:
+            if req.done:
+                t_ns, t = self._clock()
+                self.scheduler.complete(req, t)
+                self._finish_request(req, t_ns)
+                finished.append(req)
+
+    def _step(self) -> "list[Request]":
         step_t0 = time.monotonic()
         # chaos fault point: a seeded replica kill/hang/slow lands HERE, mid
         # decode loop (resilience/chaos.py, point "serving_decode") — one
@@ -595,28 +665,14 @@ class ServingEngine:
         proposed_before = self.draft_proposed_tokens
         accepted_before = self.draft_accepted_tokens
         hist_before = self.spec_accept_hist.copy()
-        admitted = self.scheduler.admissions()
-        while self.scheduler.rejected:
-            req = self.scheduler.rejected.pop()
-            req.finish_t = now
-            self._close_trace(req, "rejected")
-            finished.append(req)  # returned to the caller, status REJECTED
-            if _metrics.is_enabled():
-                _metrics.inc("accelerate_engine_requests_total", outcome="rejected")
-            if tel.is_enabled():
-                tel.emit(
-                    "serving_request", rid=req.rid, error=req.error,
-                    new_tokens=0, prompt_tokens=int(req.prompt.size),
-                )
-        for req in admitted:
-            self._prefill_request(req, now)
+        for req in self._admit(finished):
+            self._prefill_request(req)
             prefills += 1
-            if req.done:
-                self.scheduler.complete(req, now)
-                self._finish_request(req, now)
-                finished.append(req)
+            if req.done:  # its whole budget was the prefill's one token
+                with self._phase("emit"):
+                    self._complete_done([req], finished)
 
-        running = [r for r in self.scheduler.running()]
+        running = self.scheduler.running()
         if running:
             # reserve the next KV slot(s) for every live sequence FIRST: a
             # grow may preempt the youngest, and the decode batch must be
@@ -626,29 +682,25 @@ class ServingEngine:
             # still covers the peak; leftover reservations from a short
             # accept are reused, so the per-step delta is what LAST step
             # actually emitted.
-            for req in list(running):
-                if req.slot is not None:
-                    if self.spec_tokens > 0:
-                        remaining = req.max_new_tokens - len(req.generated)
-                        target = (req.prefix_len - 1) + min(
-                            self.spec_tokens + 1, remaining
-                        )
-                        self.scheduler.grow(
-                            req, target - self.allocator.tokens(req.rid)
-                        )
-                    else:
-                        self.scheduler.grow(req)
-            running = self.scheduler.running()
+            with self._phase("grow"):
+                for req in running:
+                    if req.slot is not None:
+                        if self.spec_tokens > 0:
+                            remaining = req.max_new_tokens - len(req.generated)
+                            target = (req.prefix_len - 1) + min(
+                                self.spec_tokens + 1, remaining
+                            )
+                            self.scheduler.grow(
+                                req, target - self.allocator.tokens(req.rid)
+                            )
+                        else:
+                            self.scheduler.grow(req)
+                running = self.scheduler.running()
         if running:
             if self.spec_tokens > 0:
-                self._spec_decode_batch(running)
+                self._spec_decode_batch(running, finished)
             else:
-                self._decode_batch(running)
-            for req in running:
-                if req.done:
-                    self.scheduler.complete(req, now)
-                    self._finish_request(req, now)
-                    finished.append(req)
+                self._decode_batch(running, finished)
 
         self.steps += 1
         if self.scheduler.idle():
@@ -768,7 +820,7 @@ class ServingEngine:
             req._key = np.asarray(jax.random.PRNGKey(req.rng_seed), np.uint32)
         return req._key
 
-    def _prefill_request(self, req: Request, now: float) -> None:
+    def _prefill_request(self, req: Request) -> None:
         """Prefill the request's UNCACHED prefix tail in length-bucketed
         CHUNKS: each chunk runs at the smallest covering prefill bucket (the
         largest bucket for all but the tail), so arbitrarily long prefixes —
@@ -781,91 +833,100 @@ class ServingEngine:
         table, so the math is position-exact and bitwise-identical to an
         unshared run). A pending copy-on-write pair is applied to the pool
         FIRST — the one write this request aims below its uncached tail goes
-        into its private copy, never a shared block."""
+        into its private copy, never a shared block.
+
+        The whole of it, up to the sampled token's arrival on the host, is
+        one ``atpu.serve.prefill`` phase; ``first_token_t`` is read inside
+        it, after that sync."""
         prefix = req.output_ids()
-        span_prefill = None
-        if req.trace is not None:
-            if req._span_queue is not None and "t1_ns" not in req._span_queue:
-                _tracing.span_close(req._span_queue)
-            span_prefill = _tracing.span_open(
-                req.trace, "prefill", parent_id=req._span_root["span_id"],
-                component="engine", prefix_tokens=int(prefix.size),
-                cached_tokens=int(req.cached_tokens),
-                cow=req.cow_block is not None,
-                resume=req.preemptions > 0,
-            )
-            req.trace_spans.append(span_prefill)
-        if req.cow_block is not None:
-            src, dst = req.cow_block
-            cow_t0 = _tracing.now_ns() if span_prefill is not None else 0
-            fn = self._aot.get(("cow",), self.cow_fn)
-            self.pool = fn(self.pool, np.int32(src), np.int32(dst))
-            # the copy is issued (ordered before any later pool op): release
-            # the allocator's pin so src can park in the reclaimable pool
-            self.allocator.cow_done(src)
-            req.cow_block = None
-            if span_prefill is not None:
-                req.trace_spans.append(_tracing.make_span(
-                    req.trace, "cow_copy", cow_t0, _tracing.now_ns(),
-                    parent_id=span_prefill["span_id"], component="engine",
-                    src_block=int(src), dst_block=int(dst),
-                ))
-        W = self.lattice.prefill_points()[0][1]
-        table = self.allocator.block_table(req.rid, pad_to=W)[None]
-        chunk_cap = self.lattice.prefill_buckets[-1]
-        key = self._request_key(req)
-        token_idx = np.int32(len(req.generated))
         start = int(req.cached_tokens)
-        self.prefix_cached_tokens += start
-        self.prefill_tokens += int(prefix.size) - start
-        # token-goodput waste attribution: a prefill covering already-produced
-        # work is recomputation. Preempt/resume re-runs carry preemptions>0;
-        # a failover/handoff resume arrives with ``generated`` pre-seeded.
-        if req.preemptions > 0:
-            self.preempt_prefill_tokens += int(prefix.size) - start
-        elif req.generated:
-            self.resume_prefill_tokens += int(prefix.size) - start
-        while start < prefix.size:
-            chunk = prefix[start : start + chunk_cap]
-            Sb = self.lattice.prefill_bucket(chunk.size)
-            ids = np.zeros((1, Sb), np.int32)
-            ids[0, : chunk.size] = chunk
-            chunk_t0 = _tracing.now_ns() if span_prefill is not None else 0
-            fn = self._aot.get(("prefill", Sb, W), self.prefill_fn)
-            self.pool, tok = fn(
-                self.params, self.pool, ids, table, np.int32(start),
-                np.int32(chunk.size - 1), key, token_idx,
-            )
+        with self._phase(
+            "prefill", rid=int(req.rid), tokens=int(prefix.size) - start, cached=start
+        ) as phase_t0_ns:
+            span_prefill = None
+            if req.trace is not None:
+                span_prefill = _tracing.span_open(
+                    req.trace, "prefill", t0_ns=phase_t0_ns,
+                    parent_id=req._span_root["span_id"],
+                    component="engine", prefix_tokens=int(prefix.size),
+                    cached_tokens=start,
+                    cow=req.cow_block is not None,
+                    resume=req.preemptions > 0,
+                )
+                req.trace_spans.append(span_prefill)
+            if req.cow_block is not None:
+                src, dst = req.cow_block
+                cow_t0 = _tracing.now_ns() if span_prefill is not None else 0
+                fn = self._aot.get(("cow",), self.cow_fn)
+                self.pool = fn(self.pool, np.int32(src), np.int32(dst))
+                # the copy is issued (ordered before any later pool op): release
+                # the allocator's pin so src can park in the reclaimable pool
+                self.allocator.cow_done(src)
+                req.cow_block = None
+                if span_prefill is not None:
+                    req.trace_spans.append(_tracing.make_span(
+                        req.trace, "cow_copy", cow_t0, _tracing.now_ns(),
+                        parent_id=span_prefill["span_id"], component="engine",
+                        src_block=int(src), dst_block=int(dst),
+                    ))
+            W = self.lattice.prefill_points()[0][1]
+            table = self.allocator.block_table(req.rid, pad_to=W)[None]
+            chunk_cap = self.lattice.prefill_buckets[-1]
+            key = self._request_key(req)
+            token_idx = np.int32(len(req.generated))
+            self.prefix_cached_tokens += start
+            self.prefill_tokens += int(prefix.size) - start
+            # token-goodput waste attribution: a prefill covering already-produced
+            # work is recomputation. Preempt/resume re-runs carry preemptions>0;
+            # a failover/handoff resume arrives with ``generated`` pre-seeded.
+            if req.preemptions > 0:
+                self.preempt_prefill_tokens += int(prefix.size) - start
+            elif req.generated:
+                self.resume_prefill_tokens += int(prefix.size) - start
+            while start < prefix.size:
+                chunk = prefix[start : start + chunk_cap]
+                Sb = self.lattice.prefill_bucket(chunk.size)
+                ids = np.zeros((1, Sb), np.int32)
+                ids[0, : chunk.size] = chunk
+                chunk_t0 = _tracing.now_ns() if span_prefill is not None else 0
+                fn = self._aot.get(("prefill", Sb, W), self.prefill_fn)
+                self.pool, tok = fn(
+                    self.params, self.pool, ids, table, np.int32(start),
+                    np.int32(chunk.size - 1), key, token_idx,
+                )
+                if span_prefill is not None:
+                    req.trace_spans.append(_tracing.make_span(
+                        req.trace, "prefill_chunk", chunk_t0, _tracing.now_ns(),
+                        parent_id=span_prefill["span_id"], component="engine",
+                        start=int(start), tokens=int(chunk.size), bucket=int(Sb),
+                    ))
+                start += chunk.size
+            tok = int(tok)  # the sync: the sampled token is on the host from here
+            t_ns, t = self._clock()
+            if req.first_token_t is None:
+                req.first_token_t = t
             if span_prefill is not None:
-                req.trace_spans.append(_tracing.make_span(
-                    req.trace, "prefill_chunk", chunk_t0, _tracing.now_ns(),
-                    parent_id=span_prefill["span_id"], component="engine",
-                    start=int(start), tokens=int(chunk.size), bucket=int(Sb),
-                ))
-            start += chunk.size
-        if span_prefill is not None:
-            _tracing.span_close(span_prefill)
-        req.generated.append(int(tok))
-        if req.first_token_t is None:
-            req.first_token_t = now
+                _tracing.span_close(span_prefill, t1_ns=t_ns)
+        req.generated.append(tok)
         self.prefill_calls += 1
 
-    def _decode_batch(self, running: "list[Request]") -> None:
+    def _decode_batch(self, running: "list[Request]", finished: "list[Request]") -> None:
         Bb = self.lattice.slot_bucket(len(running))
         W = self.lattice.block_bucket(
             max(self.allocator.num_seq_blocks(r.rid) for r in running)
         )
-        last = np.zeros((Bb,), np.int32)
-        tables = np.full((Bb, W), NULL_BLOCK, np.int32)
-        positions = np.zeros((Bb,), np.int32)
-        keys = np.zeros((Bb, 2), np.uint32)
-        token_idx = np.zeros((Bb,), np.int32)
-        for i, req in enumerate(running):
-            last[i] = req.generated[-1]
-            tables[i] = self.allocator.block_table(req.rid, pad_to=W)
-            positions[i] = req.prefix_len - 1
-            keys[i] = self._request_key(req)
-            token_idx[i] = len(req.generated)
+        with self._phase("build", batch=len(running), slot_bucket=Bb, block_bucket=W):
+            last = np.zeros((Bb,), np.int32)
+            tables = np.full((Bb, W), NULL_BLOCK, np.int32)
+            positions = np.zeros((Bb,), np.int32)
+            keys = np.zeros((Bb, 2), np.uint32)
+            token_idx = np.zeros((Bb,), np.int32)
+            for i, req in enumerate(running):
+                last[i] = req.generated[-1]
+                tables[i] = self.allocator.block_table(req.rid, pad_to=W)
+                positions[i] = req.prefix_len - 1
+                keys[i] = self._request_key(req)
+                token_idx[i] = len(req.generated)
         # gate on the requests' own contexts, not the local arming state (a
         # ProcessReplica child traces whenever the router propagated a ctx) —
         # and only for SAMPLED traces: per-token decode spans are the bulk of
@@ -878,35 +939,39 @@ class ServingEngine:
             else 0
         )
         fn = self._aot.get(("decode", Bb, W), self.decode_fn)
-        self.pool, toks = fn(
-            self.params, self.pool, last, tables, positions, keys, token_idx
-        )
-        toks = np.asarray(jax.device_get(toks))
-        if decode_t0:
-            decode_t1 = _tracing.now_ns()
-            for req in running:
-                if req.trace is not None and req.trace.get("sampled"):
-                    req.trace_spans.append(_tracing.make_span(
-                        req.trace, "decode_step", decode_t0, decode_t1,
-                        parent_id=req._span_root["span_id"], component="engine",
-                        step=int(self.steps), batch=len(running),
-                        token_idx=len(req.generated),
-                    ))
-        for i, req in enumerate(running):
-            req.generated.append(int(toks[i]))
-            if self.prefix_cache:
-                # this decode wrote position prefix_len-2's token (the last
-                # PREVIOUS token) — when the written count crosses a block
-                # boundary, the just-filled block becomes immutable and
-                # content-indexable for future prefix matches
-                written = req.prefix_len - 1
-                if written > 0 and written % self.block_size == 0:
-                    self.allocator.register_full_blocks(
-                        req.rid, req.output_ids()[:-1]
-                    )
-        self.decode_tokens += len(running)
+        with self._phase("dispatch"):
+            self.pool, toks = fn(
+                self.params, self.pool, last, tables, positions, keys, token_idx
+            )
+        with self._phase("fetch"):  # the host waits for the device here
+            toks = np.asarray(jax.device_get(toks))
+        with self._phase("emit"):
+            if decode_t0:
+                decode_t1 = _tracing.now_ns()
+                for req in running:
+                    if req.trace is not None and req.trace.get("sampled"):
+                        req.trace_spans.append(_tracing.make_span(
+                            req.trace, "decode_step", decode_t0, decode_t1,
+                            parent_id=req._span_root["span_id"], component="engine",
+                            step=int(self.steps), batch=len(running),
+                            token_idx=len(req.generated),
+                        ))
+            for i, req in enumerate(running):
+                req.generated.append(int(toks[i]))
+                if self.prefix_cache:
+                    # this decode wrote position prefix_len-2's token (the last
+                    # PREVIOUS token) — when the written count crosses a block
+                    # boundary, the just-filled block becomes immutable and
+                    # content-indexable for future prefix matches
+                    written = req.prefix_len - 1
+                    if written > 0 and written % self.block_size == 0:
+                        self.allocator.register_full_blocks(
+                            req.rid, req.output_ids()[:-1]
+                        )
+            self.decode_tokens += len(running)
+            self._complete_done(running, finished)
 
-    def _spec_decode_batch(self, running: "list[Request]") -> None:
+    def _spec_decode_batch(self, running: "list[Request]", finished: "list[Request]") -> None:
         """One speculative decode round for every live slot: k sequential S=1
         steps of the truncated self-draft propose candidates, ONE batched
         S=k+1 verify forward (which dispatches to the chunked-prefill paged
@@ -926,42 +991,54 @@ class ServingEngine:
         W = self.lattice.block_bucket(
             max(self.allocator.num_seq_blocks(r.rid) for r in running)
         )
-        last = np.zeros((Bb,), np.int32)
-        tables = np.full((Bb, W), NULL_BLOCK, np.int32)
-        positions = np.zeros((Bb,), np.int32)
-        keys = np.zeros((Bb, 2), np.uint32)
-        token_idx = np.zeros((Bb,), np.int32)
-        rows = np.ones((Bb,), np.int32)
-        for i, req in enumerate(running):
-            last[i] = req.generated[-1]
-            tables[i] = self.allocator.block_table(req.rid, pad_to=W)
-            positions[i] = req.prefix_len - 1
-            keys[i] = self._request_key(req)
-            token_idx[i] = len(req.generated)
-            # emit at most as many rows as the grow phase reserved KV room
-            # for (clamped by the request's remaining new-token budget)
-            rows[i] = self.allocator.tokens(req.rid) - (req.prefix_len - 1)
+        with self._phase("build", batch=len(running), slot_bucket=Bb, block_bucket=W):
+            last = np.zeros((Bb,), np.int32)
+            tables = np.full((Bb, W), NULL_BLOCK, np.int32)
+            positions = np.zeros((Bb,), np.int32)
+            keys = np.zeros((Bb, 2), np.uint32)
+            token_idx = np.zeros((Bb,), np.int32)
+            rows = np.ones((Bb,), np.int32)
+            for i, req in enumerate(running):
+                last[i] = req.generated[-1]
+                tables[i] = self.allocator.block_table(req.rid, pad_to=W)
+                positions[i] = req.prefix_len - 1
+                keys[i] = self._request_key(req)
+                token_idx[i] = len(req.generated)
+                # emit at most as many rows as the grow phase reserved KV room
+                # for (clamped by the request's remaining new-token budget)
+                rows[i] = self.allocator.tokens(req.rid) - (req.prefix_len - 1)
+            cand = np.zeros((Bb, k + 1), np.int32)
+            cand[:, 0] = last
         decode_t0 = (
             _tracing.now_ns()
             if any(r.trace is not None and r.trace.get("sampled") for r in running)
             else 0
         )
-        cand = np.zeros((Bb, k + 1), np.int32)
-        cand[:, 0] = last
         dfn = self._aot.get(("draft", Bb, W), self.draft_fn)
         d_last, d_pos, d_idx = last, positions, token_idx
-        for j in range(k):
-            self.pool, d_tok = dfn(
-                self.draft_params, self.pool, d_last, tables, d_pos, keys, d_idx
-            )
-            d_tok = np.asarray(jax.device_get(d_tok)).astype(np.int32)
+        for j in range(k):  # one dispatch and one fetch per draft round
+            with self._phase("dispatch"):
+                self.pool, d_tok = dfn(
+                    self.draft_params, self.pool, d_last, tables, d_pos, keys, d_idx
+                )
+            with self._phase("fetch"):
+                d_tok = np.asarray(jax.device_get(d_tok)).astype(np.int32)
             cand[:, j + 1] = d_tok
             d_last, d_pos, d_idx = d_tok, d_pos + 1, d_idx + 1
         vfn = self._aot.get(("verify", Bb, W), self.verify_fn)
-        self.pool, sel = vfn(
-            self.params, self.pool, cand, tables, positions, keys, token_idx
-        )
-        sel = np.asarray(jax.device_get(sel))
+        with self._phase("dispatch"):
+            self.pool, sel = vfn(
+                self.params, self.pool, cand, tables, positions, keys, token_idx
+            )
+        with self._phase("fetch"):
+            sel = np.asarray(jax.device_get(sel))
+        with self._phase("emit"):
+            self._spec_emit(running, finished, sel, cand, rows, token_idx, decode_t0)
+
+    def _spec_emit(self, running, finished, sel, cand, rows, token_idx, decode_t0) -> None:
+        """The host half of a speculative round: accept, per request, the
+        longest candidate prefix the verifier's own selections confirm."""
+        k = self.spec_tokens
         emitted = 0
         accepted_by_req: "list[int]" = []
         for i, req in enumerate(running):
@@ -1008,8 +1085,9 @@ class ServingEngine:
                         k_accepted=int(accepted_by_req[i]),
                     ))
         self.decode_tokens += emitted
+        self._complete_done(running, finished)
 
-    def _close_trace(self, req: Request, outcome: str) -> None:
+    def _close_trace(self, req: Request, outcome: str, t1_ns: Optional[int] = None) -> None:
         """Close the request's open spans with the terminal ``outcome``; the
         trace's OWNER emits — this engine when it rooted the trace, the
         router (via the replica event stream) when the context was
@@ -1017,26 +1095,37 @@ class ServingEngine:
         if req.trace is None:
             return
         if req._span_queue is not None and "t1_ns" not in req._span_queue:
-            _tracing.span_close(req._span_queue)
+            _tracing.span_close(req._span_queue, t1_ns=t1_ns)
         if req._span_root is not None and "t1_ns" not in req._span_root:
             _tracing.span_close(
-                req._span_root, outcome=outcome, tokens=len(req.generated),
-                preemptions=int(req.preemptions),
+                req._span_root, t1_ns=t1_ns, outcome=outcome,
+                tokens=len(req.generated), preemptions=int(req.preemptions),
             )
         if req._trace_owner:
             _tracing.finish_trace(
                 req.trace, req.trace_spans, forced=outcome != "finished"
             )
 
-    def _finish_request(self, req: Request, now: float) -> None:
-        self._close_trace(req, "finished")
+    def _finish_request(self, req: Request, t_ns: int) -> None:
+        """The records of a request that has just completed (``finish_t`` is
+        stamped; ``t_ns`` is that same read in ns): the trace's root span,
+        the ``atpu.request`` record of its stamps in the phase ring, the
+        latency histograms and the ``serving_request`` event."""
+        self._close_trace(req, "finished", t_ns)
+        _tracing.record(
+            "atpu.request", t_ns, t_ns, engine=self.engine_id, rid=int(req.rid),
+            arrival_t=req.arrival_t, admit_t=req.admit_t,
+            first_token_t=req.first_token_t, finish_t=req.finish_t,
+            admit_step=req.admit_step, prompt_tokens=int(req.prompt.size),
+            new_tokens=len(req.generated), preemptions=int(req.preemptions),
+        )
         if _metrics.is_enabled():
             _metrics.inc("accelerate_engine_requests_total", outcome="finished")
             if req.first_token_t is not None:
                 _metrics.observe("accelerate_engine_ttft_seconds",
                                  req.first_token_t - req.arrival_t)
             _metrics.observe("accelerate_engine_request_latency_seconds",
-                             (req.finish_t or now) - req.arrival_t)
+                             req.finish_t - req.arrival_t)
         self._emit_completion(req)
 
     def _emit_completion(self, req: Request) -> None:
@@ -1050,6 +1139,9 @@ class ServingEngine:
             latency_s=round((req.finish_t or 0.0) - req.arrival_t, 6),
             ttft_s=round((req.first_token_t or 0.0) - req.arrival_t, 6)
             if req.first_token_t is not None
+            else None,
+            queue_s=round(req.admit_t - req.arrival_t, 6)
+            if req.admit_t is not None
             else None,
             preemptions=req.preemptions,
         )

@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import enum
 import itertools
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Optional
@@ -75,6 +76,13 @@ class Request:
     generated: "list[int]" = field(default_factory=list)
     slot: Optional[int] = None
     preemptions: int = 0
+    # stamps, each read when its event happens (``time.monotonic()``, or the
+    # ``now`` the caller of ``step(now=...)`` gave): FIRST admission (a
+    # resumed request keeps it) and the engine step that made it, the first
+    # token on the host, completion. arrival_t <= admit_t <= first_token_t
+    # <= finish_t; admit_t - arrival_t is the queue wait.
+    admit_t: Optional[float] = None
+    admit_step: Optional[int] = None
     first_token_t: Optional[float] = None
     finish_t: Optional[float] = None
     error: Optional[str] = None  # set when REJECTED
@@ -196,10 +204,16 @@ class Scheduler:
                 return i
         return None
 
-    def admissions(self) -> "list[Request]":
+    def admissions(
+        self, now: Optional[float] = None, step: Optional[int] = None
+    ) -> "list[Request]":
         """Pop and place every request admissible RIGHT NOW (the engine
         prefills each). Continuous mode admits whenever a slot + blocks are
-        available; static mode only gang-admits into an idle engine."""
+        available; static mode only gang-admits into an idle engine.
+
+        A request's FIRST admission stamps ``admit_t`` (``now``, else the
+        monotonic clock) and ``admit_step`` (``step``, the caller's step
+        counter); a resume after preemption leaves both alone."""
         if not self.continuous and self.running():
             return []
         admitted = []
@@ -263,6 +277,9 @@ class Scheduler:
             req.slot = slot
             self.slots[slot] = req
             self._admission_order.append(req)
+            if req.admit_t is None:
+                req.admit_t = time.monotonic() if now is None else now
+                req.admit_step = step
             admitted.append(req)
         return admitted
 

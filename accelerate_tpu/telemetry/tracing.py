@@ -9,11 +9,19 @@ and context-propagation glue that builds it:
 
 - **spans** are plain dicts — ``trace_id`` / ``span_id`` / ``parent_id``,
   ``name``, monotonic-ns ``t0_ns``/``t1_ns`` (the clock
-  :func:`time.monotonic_ns`, the SAME timebase the step profiler and XLA
-  trace windows stamp, so traces join by timestamp), plus free-form
-  attributes. :func:`span_open` / :func:`span_close` / :func:`make_span`
-  build them; holders (the router request, the engine request) accumulate
-  them in a list.
+  :func:`time.monotonic_ns`: the span tree's and the phase ring's clock, and
+  the one ``Request``'s stamps are read from. It is NOT the clock a device
+  trace stamps — the profiler has its own, and nothing converts between the
+  two), plus free-form attributes. :func:`span_open` / :func:`span_close` /
+  :func:`make_span` build them; holders (the router request, the engine
+  request) accumulate them in a list.
+- **phases** — :func:`phase` brackets a stretch of host code (the serving
+  engine's ``atpu.serve.*`` step phases). It enters a
+  ``jax.profiler.TraceAnnotation`` of the same name, which is the ONE join
+  with a device trace (the profiler stamps the annotation on its own clock,
+  beside the device's operations), and keeps ``(name, t0_ns, t1_ns, key)``
+  in a bounded in-memory ring that :func:`recorded` reads back. Always on:
+  a phase costs two clock reads and one tuple.
 - **context propagation** — a :class:`TraceContext` is a 3-field JSON-able
   dict (``trace_id``, ``parent_id``, ``sampled``) that rides the existing
   transports verbatim: the router puts it in the submit payload, the
@@ -32,14 +40,16 @@ and context-propagation glue that builds it:
 - **export** — emitted spans are ``span`` telemetry records (they carry
   ``trace_id``, unlike the :meth:`EventLog.span <accelerate_tpu.telemetry.
   events.EventLog.span>` timing records); :func:`chrome_trace` converts a
-  span list to a Chrome ``trace.json`` (the xplane chrome conventions —
-  load it in ``chrome://tracing``/Perfetto next to an XLA window), and
+  span list to a Chrome ``trace.json`` (load it in ``chrome://tracing`` or
+  Perfetto; its timestamps are monotonic ns, not a device trace's), and
   :func:`validate_span_tree` is the gap-free-tree oracle the tests and
   ``make doctor`` check 16 assert.
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import os
 import threading
 import time
@@ -156,6 +166,49 @@ def new_trace(sampled: Optional[bool] = None) -> TraceContext:
 
 def now_ns() -> int:
     return time.monotonic_ns()
+
+
+# ---------------------------------------------------------------------------
+# phases: host spans in the profiler's trace and in an in-memory ring
+
+#: records the ring keeps (a serve step writes 7 plus one per prefill; 2^16
+#: hold some 8000 steps, a quarter of an hour at 100 ms a step)
+RING_MAXLEN = 1 << 16
+_RING: "collections.deque[tuple]" = collections.deque(maxlen=RING_MAXLEN)
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation, bound by the first phase
+
+
+def record(name: str, t0_ns: int, t1_ns: int, **key: Any) -> None:
+    """Append ``(name, t0_ns, t1_ns, key)`` to the process-wide ring (the
+    oldest record falls out when it is full; ``deque.append`` is atomic)."""
+    _RING.append((name, t0_ns, t1_ns, key))
+
+
+@contextlib.contextmanager
+def phase(name: str, **key: Any):
+    """Bracket a stretch of host code: a ``jax.profiler.TraceAnnotation``
+    (free while no profiler is attached; the span a device trace's idle gaps
+    are attributed to) and, on exit, one :func:`record` stamped by
+    :func:`now_ns`. Yields the start's ``t0_ns``, for a caller that opens a
+    request span at the same read. The record is written on an exception too."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:  # deferred: the rest of the module is stdlib-only;
+        from jax.profiler import TraceAnnotation  # once: the import costs 9 us a time
+
+        _TraceAnnotation = TraceAnnotation
+    with _TraceAnnotation(name, **key):
+        t0_ns = now_ns()
+        try:
+            yield t0_ns
+        finally:
+            _RING.append((name, t0_ns, now_ns(), key))
+
+
+def recorded(name_prefix: str = "") -> "list[tuple]":
+    """The ring's records whose name starts with ``name_prefix``, oldest
+    first: what a benchmark reads when its window is over, and what an
+    operator reads from a live process."""
+    return [r for r in tuple(_RING) if r[0].startswith(name_prefix)]
 
 
 def span_open(
@@ -293,9 +346,11 @@ def span_children(spans: "list[dict]") -> "dict[Optional[str], list[dict]]":
 
 def chrome_trace(spans: Iterable[dict]) -> dict:
     """Spans → Chrome ``trace.json``: complete ("ph": "X") events in
-    microseconds on the shared monotonic timebase, one pid/tid lane per
-    emitting component (the ``component`` attr; default the span name's
-    prefix), so the export drops straight next to an XLA trace window."""
+    microseconds of the monotonic clock, one pid/tid lane per emitting
+    component (the ``component`` attr; default the span name's prefix). A
+    device trace is on the profiler's clock, so the two files do not line up
+    by timestamp: the ``atpu.*`` annotations of :func:`phase` are what a
+    device trace holds of the host."""
     trace_events = []
     tids: "dict[str, int]" = {}
     for s in spans:

@@ -17,6 +17,7 @@ The acceptance lines these tests hold:
 
 import dataclasses
 import json
+import re
 import threading
 import time
 import urllib.request
@@ -976,3 +977,120 @@ class TestReportIntegration:
         assert report["slo"] is None and report["traces"] == 0
         text = format_report(report)
         assert "SLO:" not in text and "traces:" not in text
+
+
+# ---------------------------------------------------------------------------
+# PR 25: the request's span tree and its stamps are one set of clock reads;
+# the five Pallas kernels carry the names a device trace tells them apart by
+
+
+class TestStampsAndSpansAgree:
+    def test_armed_span_tree_is_valid_and_its_edges_are_the_request_stamps(self, params):
+        """``queue_wait`` ends at ``admit_t``, the first ``prefill`` ends at
+        ``first_token_t`` (after the token's sync, so the chunks lie inside
+        it) and the root ends at ``finish_t``: the spans are closed with the
+        very reads the stamps come from, not with reads of their own."""
+        tracing.arm(1.0)
+        engine = ServingEngine(
+            params, CONFIG, num_blocks=10, block_size=8, max_slots=4, max_blocks_per_seq=8,
+            lattice=BucketLattice(slot_buckets=(1, 2, 4), block_buckets=(4, 8),
+                                  prefill_buckets=(16, 32)),
+        )
+        engine.warmup()
+        rng = np.random.default_rng(2)
+        prompts = [rng.integers(0, CONFIG.vocab_size, (n,)).astype(np.int32)
+                   for n in (16, 14, 15, 40)]
+        reqs = [engine.submit(p, 16, rng_seed=i) for i, p in enumerate(prompts)]
+        engine.run()
+        assert engine.scheduler.preemption_count >= 1  # a resumed request among them
+        for req in reqs:
+            assert tracing.validate_span_tree(req.trace_spans) == []
+            by_name = {}
+            for span in req.trace_spans:
+                by_name.setdefault(span["name"], []).append(span)
+            (root,), (queue,) = by_name["engine_request"], by_name["queue_wait"]
+            prefill = by_name["prefill"][0]
+            assert queue["t1_ns"] / 1e9 == req.admit_t
+            assert prefill["t1_ns"] / 1e9 == req.first_token_t
+            assert root["t1_ns"] / 1e9 == req.finish_t
+            assert queue["t1_ns"] <= prefill["t0_ns"]
+            assert len(by_name["prefill"]) == 1 + req.preemptions
+            assert by_name["prefill"][-1]["attrs"]["resume"] == (req.preemptions > 0)
+            # the span opens at the read its atpu.serve.prefill phase opens at
+            phases = [r for r in tracing.recorded("atpu.serve.prefill")
+                      if r[3]["engine"] == engine.engine_id and r[3]["rid"] == req.rid]
+            assert [p[1] for p in phases] == [s["t0_ns"] for s in by_name["prefill"]]
+            for chunk in by_name["prefill_chunk"]:
+                assert any(p["t0_ns"] <= chunk["t0_ns"] and chunk["t1_ns"] <= p["t1_ns"]
+                           for p in by_name["prefill"])
+
+    def test_ttft_histogram_and_request_event_read_the_sound_stamp(self, params, tmp_path):
+        """``accelerate_engine_ttft_seconds`` and ``serving_request.ttft_s`` hold
+        the request's own prefill (made slow here), and the event carries the
+        queue wait beside it."""
+        import time as _time
+
+        tel.enable(out_dir=str(tmp_path), run_id="ttft")
+        metrics.enable()
+        engine = ServingEngine(
+            params, CONFIG, num_blocks=33, block_size=8, max_slots=2,
+            lattice=BucketLattice(slot_buckets=(2,), block_buckets=(8,), prefill_buckets=(16,)),
+        )
+        engine.warmup()
+        fast = engine.prefill_fn
+
+        def slow_prefill(*args):
+            _time.sleep(0.05)
+            return fast(*args)
+
+        engine.prefill_fn = slow_prefill
+        req = engine.submit(np.arange(1, 13, dtype=np.int32), 3)
+        engine.run()
+        tel.disable()
+        recs = [json.loads(l) for l in open(tmp_path / "events-rank0.jsonl")]
+        (done,) = [r for r in recs if r["kind"] == "serving_request"]
+        assert done["ttft_s"] >= 0.05 and done["ttft_s"] == round(
+            req.first_token_t - req.arrival_t, 6)
+        assert done["queue_s"] == round(req.admit_t - req.arrival_t, 6)
+        assert done["queue_s"] <= done["ttft_s"] - 0.05 + 1e-5  # the wait holds no prefill
+        hist = metrics.get_registry().get("accelerate_engine_ttft_seconds")
+        assert hist.count == 1 and hist.sum == req.first_token_t - req.arrival_t >= 0.05
+
+
+def _kernel_jaxprs():
+    import importlib
+
+    fa = importlib.import_module("accelerate_tpu.ops.flash_attention")
+    q, kv = jnp.zeros((1, 128, 2, 32)), jnp.zeros((1, 128, 1, 32))
+
+    def flash(q, k, v):
+        return fa._flash_kernel(q, k, v, None, causal=True, sm_scale=1.0, window=None,
+                                interpret=True)
+
+    def flash_grads(q, k, v):
+        return jax.grad(lambda *a: flash(*a).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    pool = jnp.zeros((8, 8, 1, 32))
+    tables = jnp.zeros((2, 4), jnp.int32)
+    return {
+        "flash_fwd": lambda: jax.make_jaxpr(flash)(q, kv, kv),
+        "flash_dq": lambda: jax.make_jaxpr(flash_grads)(q, kv, kv),
+        "flash_dkdv": lambda: jax.make_jaxpr(flash_grads)(q, kv, kv),
+        "paged_decode": lambda: jax.make_jaxpr(
+            lambda x: fa.paged_attention_decode(x, pool, pool, tables, jnp.ones((2,), jnp.int32),
+                                                interpret=True))(jnp.zeros((2, 1, 2, 32))),
+        "paged_prefill": lambda: jax.make_jaxpr(
+            lambda x: fa.paged_attention_prefill(x, pool, pool, tables[:1],
+                                                 jnp.zeros((1, 8), jnp.int32),
+                                                 interpret=True))(jnp.zeros((1, 8, 2, 32))),
+    }
+
+
+@pytest.mark.parametrize(
+    "kernel", ["flash_fwd", "flash_dq", "flash_dkdv", "paged_decode", "paged_prefill"])
+def test_every_pallas_kernel_carries_its_name(kernel):
+    """The ``name=`` of each ``pallas_call`` is what the compiled instruction
+    is called (``%paged_decode.3``), which is how a device trace's reader
+    tells the kernels apart; an unnamed kernel is a ``closed_call`` there."""
+    text = str(_kernel_jaxprs()[kernel]())
+    assert "pallas_call[" in text and re.search(rf"^\s+name={kernel}$", text, re.M)

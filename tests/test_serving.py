@@ -10,6 +10,10 @@ The two acceptance lines these tests hold:
   jit caches (the telemetry recompile detector is the oracle).
 """
 
+import json
+import os
+import time
+
 import numpy as np
 import pytest
 
@@ -31,6 +35,7 @@ from accelerate_tpu.serving import (
     ServingEngine,
     paged_attention,
 )
+from accelerate_tpu.telemetry import tracing
 
 CONFIG = LlamaConfig.tiny()
 SMALL_LATTICE = BucketLattice(slot_buckets=(2, 4), block_buckets=(4,), prefill_buckets=(32,))
@@ -756,3 +761,197 @@ def test_zero_recompiles_through_churn_on_multidevice_mesh():
                               max_new_tokens=r.max_new_tokens,
                               cache_dtype=jnp.float32)
         assert np.array_equal(np.asarray(ref[0]), r.output_ids()), f"request {i}"
+
+
+# ---------------------------------------------------------------------------
+# the engine's own measurement (PR 25): step phases, request stamps, the ring
+
+PHASE = "atpu.serve."
+CHILDREN = {"admit", "prefill", "grow", "build", "dispatch", "fetch", "emit"}
+
+
+def _engine_records(engine, prefix=PHASE):
+    return [r for r in tracing.recorded(prefix) if r[3]["engine"] == engine.engine_id]
+
+
+def _mixed_engine(params, **kwargs):
+    engine = ServingEngine(
+        params, CONFIG, num_blocks=10, block_size=8, max_slots=4, max_blocks_per_seq=8,
+        lattice=BucketLattice(slot_buckets=(1, 2, 4), block_buckets=(4, 8),
+                              prefill_buckets=(16, 32)),
+        **kwargs,
+    )
+    engine.warmup()
+    return engine
+
+
+def _run_staggered(engine, prompts, max_new):
+    """Submit two requests up front and one more after each of the next steps
+    (so some requests wait in the queue), then run the engine dry."""
+    reqs = [engine.submit(p, max_new, rng_seed=i) for i, p in enumerate(prompts[:2])]
+    for i, p in enumerate(prompts[2:], start=2):
+        engine.step()
+        reqs.append(engine.submit(p, max_new, rng_seed=i))
+    engine.run()
+    return reqs
+
+
+def test_request_stamps_are_ordered_and_first_token_lies_in_its_own_prefill(params):
+    """arrival <= admit <= first token <= finish for every request of a mixed
+    workload (chunked prefills, queueing, a preemption), ``first_token_t`` is
+    read inside the request's own ``atpu.serve.prefill`` span, and each
+    finished request leaves one ``atpu.request`` record of the same stamps."""
+    engine = _mixed_engine(params)
+    reqs = _run_staggered(engine, _prompts(7, (16, 40, 14, 15, 9)), 16)
+    assert engine.scheduler.preemption_count >= 1
+    prefills = {}
+    for name, t0_ns, t1_ns, key in _engine_records(engine, PHASE + "prefill"):
+        prefills.setdefault(key["rid"], []).append((t0_ns, t1_ns, key))
+    finished = {r[3]["rid"]: r[3] for r in _engine_records(engine, "atpu.request")}
+    for req in reqs:
+        assert req.arrival_t <= req.admit_t <= req.first_token_t <= req.finish_t
+        t0_ns, t1_ns, key = prefills[req.rid][0]  # the first: a resume re-prefills
+        assert t0_ns / 1e9 <= req.first_token_t <= t1_ns / 1e9
+        assert key["step"] == req.admit_step and key["cached"] == 0
+        assert key["tokens"] == req.prompt.size
+        assert len(prefills[req.rid]) == 1 + req.preemptions
+        stamps = finished[req.rid]
+        assert stamps == dict(
+            engine=engine.engine_id, rid=req.rid, arrival_t=req.arrival_t,
+            admit_t=req.admit_t, first_token_t=req.first_token_t, finish_t=req.finish_t,
+            admit_step=req.admit_step, prompt_tokens=int(req.prompt.size),
+            new_tokens=len(req.generated), preemptions=req.preemptions)
+    assert any(r.admit_step > 0 and r.admit_t > r.arrival_t for r in reqs)  # one queued
+
+
+def test_first_token_stamp_follows_the_tokens_sync_not_the_steps_start(params):
+    """With a prefill function the test makes slow, ``first_token_t - admit_t``
+    is at least that slow: the stamp is read after the token's sync. It was 0
+    while the stamp was the clock read at the top of ``step()``."""
+    engine = _mixed_engine(params)
+    fast, slow_s = engine.prefill_fn, 0.05
+
+    def slow_prefill(*args):
+        time.sleep(slow_s)
+        return fast(*args)
+
+    engine.prefill_fn = slow_prefill
+    reqs = [engine.submit(p, 3, rng_seed=i) for i, p in enumerate(_prompts(8, (12, 40)))]
+    engine.run()
+    assert reqs[0].first_token_t - reqs[0].admit_t >= slow_s
+    # the second was admitted at the same read, waited for the first's prefill, and
+    # its own ran in two chunks (40 tokens over a 32 bucket)
+    assert reqs[1].admit_t == reqs[0].admit_t
+    assert reqs[1].first_token_t - reqs[0].first_token_t >= 2 * slow_s
+    for req in reqs:
+        assert req.first_token_t <= req.finish_t
+
+
+def test_preempted_request_keeps_its_first_stamps_and_a_given_now_is_every_stamp(params):
+    """A resume after preemption leaves ``admit_t``, ``admit_step`` and
+    ``first_token_t`` alone; under ``step(now=...)`` every stamp is the
+    caller's clock, so a simulated clock stays deterministic."""
+    engine = _mixed_engine(params)
+    reqs = [engine.submit(p, 16, rng_seed=i, arrival_t=100.0)
+            for i, p in enumerate(_prompts(2, (16, 14, 15)))]
+    now, first = 100.0, {}
+    while not engine.scheduler.idle():
+        now += 1.0
+        engine.step(now=now)
+        for req in reqs:
+            if req.admit_t is not None:
+                first.setdefault(req.rid, (req.admit_t, req.admit_step, req.first_token_t))
+    assert engine.scheduler.preemption_count >= 1
+    for req in reqs:
+        assert (req.admit_t, req.admit_step, req.first_token_t) == first[req.rid]
+        assert req.admit_t == req.first_token_t == 101.0 + req.admit_step
+        assert req.finish_t == float(int(req.finish_t)) and req.finish_t > req.first_token_t
+
+
+@pytest.mark.parametrize("spec_tokens", [0, 2], ids=["decode", "spec-decode"])
+def test_step_phases_are_disjoint_children_of_their_step(params, spec_tokens):
+    """Every ``atpu.serve.step`` holds its children: each carries the step's
+    number, lies inside it, and overlaps no sibling; a step that decoded has
+    one ``build`` and as many ``fetch`` as ``dispatch`` phases."""
+    kwargs = dict(spec_tokens=spec_tokens, draft_layers=1) if spec_tokens else {}
+    engine = _mixed_engine(params, **kwargs)
+    _run_staggered(engine, _prompts(9, (16, 40, 14, 9)), 10)
+    by_step = {}
+    for name, t0_ns, t1_ns, key in _engine_records(engine):
+        assert t0_ns <= t1_ns
+        by_step.setdefault(key["step"], []).append((name[len(PHASE):], t0_ns, t1_ns, key))
+    assert sorted(by_step) == list(range(engine.steps))
+    for step, spans in by_step.items():
+        (whole,) = [s for s in spans if s[0] == "step"]
+        children = sorted((s for s in spans if s[0] != "step"), key=lambda s: s[1])
+        assert {c[0] for c in children} <= CHILDREN and children[0][0] == "admit"
+        assert whole[1] <= children[0][1] and children[-1][2] <= whole[2]
+        for a, b in zip(children, children[1:]):
+            assert a[2] <= b[1], (step, a[0], b[0])
+        names = [c[0] for c in children]
+        if "dispatch" in names:
+            assert names.count("build") == 1 and names.count("grow") == 1
+            assert names.count("fetch") == names.count("dispatch") == 1 + spec_tokens
+            (build,) = [c for c in children if c[0] == "build"]
+            assert build[3]["slot_bucket"] >= build[3]["batch"] >= 1
+            assert build[3]["block_bucket"] in (4, 8)
+            assert names[-1] == "emit"
+
+
+def test_phase_ring_is_bounded_and_two_engines_keep_apart(params, monkeypatch):
+    import collections
+
+    monkeypatch.setattr(tracing, "_RING", collections.deque(maxlen=64))
+    a, b = _mixed_engine(params), _mixed_engine(params)
+    assert a.engine_id != b.engine_id and a.heartbeat_name == b.heartbeat_name
+    for engine in (a, b):
+        for i, p in enumerate(_prompts(4, (9, 12))):
+            engine.submit(p, 12, rng_seed=i)
+        engine.run()
+    assert len(tracing.recorded()) == 64  # the oldest fell out, the newest are there
+    assert tracing._RING.maxlen == 64 and tracing.RING_MAXLEN == 1 << 16
+    assert not _engine_records(a, PHASE + "admit")[:1] or _engine_records(a)[0][3]["step"] > 0
+    assert _engine_records(b)[-1][0] == PHASE + "step"
+    assert _engine_records(b)[-1][3]["step"] == b.steps - 1
+    with pytest.raises(RuntimeError, match="boom"):
+        with tracing.phase("atpu.test.raises", engine=-1):
+            raise RuntimeError("boom")
+    assert tracing.recorded("atpu.test.")[-1][0] == "atpu.test.raises"  # recorded all the same
+
+
+GOLDEN_GREEDY = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "golden", "serving_greedy_pr23.json")
+
+
+def fixed_greedy_workload(kernel_mode: str) -> "list[list[int]]":
+    """The fixed workload behind ``golden/serving_greedy_pr23.json``: float32
+    weights and cache, six staggered greedy requests through chunked prefill,
+    queueing and a preemption. Returns each request's prompt + output."""
+    os.environ["ACCELERATE_PAGED_KERNEL"] = kernel_mode
+    try:
+        params = init_llama(CONFIG, jax.random.PRNGKey(0))
+        engine = ServingEngine(
+            params, CONFIG, num_blocks=12, block_size=8, max_slots=4, max_blocks_per_seq=8,
+            cache_dtype=jnp.float32,
+            lattice=BucketLattice(slot_buckets=(1, 2, 4), block_buckets=(4, 8),
+                                  prefill_buckets=(16, 32)),
+        )
+        prompts = _prompts(11, (5, 17, 40, 9, 33, 12))
+        reqs = [engine.submit(p, 14, rng_seed=i) for i, p in enumerate(prompts[:3])]
+        for i, p in enumerate(prompts[3:], start=3):
+            engine.step()
+            reqs.append(engine.submit(p, 14, rng_seed=i))
+        engine.run()
+        assert engine.scheduler.preemption_count >= 1
+        return [r.output_ids().tolist() for r in reqs]
+    finally:
+        del os.environ["ACCELERATE_PAGED_KERNEL"]
+
+
+@pytest.mark.parametrize("kernel_mode", ["0", "interpret"])
+def test_greedy_outputs_are_bitwise_the_parents(kernel_mode):
+    """Naming the kernels and timing the step changed no arithmetic: the
+    fixed workload's tokens equal those the parent commit (PR 23) produced,
+    through the XLA path and through both paged kernels in interpret mode."""
+    golden = json.load(open(GOLDEN_GREEDY))
+    assert fixed_greedy_workload(kernel_mode) == golden[kernel_mode]
