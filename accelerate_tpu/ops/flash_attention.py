@@ -664,71 +664,99 @@ def paged_kernel_mode() -> str:
     return "on"
 
 
+# VMEM the decode kernel plans for a grid step: its N blocks of K and of V,
+# double buffered, and their f32 working set. On the v5e, at the serve cells'
+# shapes, N 2 and 4 were the fastest and 8 was 7-14 % slower (my chip runs, PR
+# 26): a grid step is cheap, and a row's last step computes on what it masks.
+# This gives those shapes N = 4.
+_DECODE_VMEM_BYTES = 3 * 1024 * 1024
+
+
+def _decode_group_blocks(block_size, Hkv, D, dtype, groups, W) -> int:
+    """Blocks fetched and folded per grid step of the decode walk (``N``),
+    read from shapes: how many fit :data:`_DECODE_VMEM_BYTES`, a block costing
+    its four pool-dtype copies (K and V, double buffered) and its f32 working
+    set (K and V upcast, and the ``groups`` score and value products), every
+    ``[Hkv, D]`` tile padded to 8 sublanes and 128 lanes. Clamped to ``[1,
+    W]``."""
+    tile = block_size * -(-Hkv // 8) * 8 * -(-D // 128) * 128  # elements
+    per_block = 4 * tile * jnp.dtype(dtype).itemsize + (2 + 2 * groups) * tile * 4
+    return max(1, min(W, _DECODE_VMEM_BYTES // per_block))
+
+
 def _paged_decode_kernel(
-    tables_ref,  # [B, W] int32 scalar-prefetch (drives the k/v index maps)
-    lens_ref,    # [B]    int32 scalar-prefetch: per-row live kv length
-    q_ref,       # [1, H, D]            this row's query
-    k_ref,       # [1, block_size, Hkv, D]  the block the index map selected
-    v_ref,       # [1, block_size, Hkv, D]
-    o_ref,       # [1, H, D]
-    acc_ref,     # VMEM [H, D] f32      online-softmax accumulators,
-    m_ref,       # VMEM [H, 1] f32      carried across the W grid steps
-    l_ref,       # VMEM [H, 1] f32
-    *,
+    walk_ref,    # [B, steps*N] int32 scalar-prefetch: the block tables, every
+                 # entry past a row's last live one replaced by that one
+    lens_ref,    # [B] int32 scalar-prefetch: per-row live kv length
+    row_ref,     # [B*steps] int32 scalar-prefetch: the row of grid step i
+    group_ref,   # [B*steps] int32 scalar-prefetch: its group within the row
+    q_ref,       # [1, G, Hkv, D]  the row's query, query-group major
+    *refs,       # N K blocks, N V blocks [1, block_size, Hkv, D]; then
+                 # o_ref [1, G, Hkv, D] and the online-softmax carries in
+                 # VMEM: acc [G, Hkv, D], m [G, Hkv, 1], l [G, Hkv, 1], f32
     block_size: int,
-    groups: int,
+    group: int,
     scale: float,
 ):
-    """One (row, logical-block) grid step of paged flash decode.
+    """One grid step of paged flash decode: ``N`` consecutive blocks of one
+    row, folded into its online softmax in one pass.
 
-    The grid is ``(B, W)`` with the block axis innermost; the BlockSpec index
-    maps already DMA'd physical block ``tables[b, w]`` of each pool into VMEM
-    — the kernel never sees the pool, only one streamed block — so the body
-    is plain online softmax: rescale the running (max, sum, acc) by the new
-    block's contribution and normalize on the last block. Padded table
-    entries point at the null block and their positions exceed the row's
-    live length, so the same position mask that makes the gather reference
-    exact silences them here. All math is f32 on the VPU: decode attention
-    is bandwidth-bound (one query row per block), so streaming, not the MXU,
-    is what this kernel buys."""
+    The grid is one-dimensional and as long as the batch's live blocks need:
+    row ``b`` takes ``ceil(live_b / N)`` steps, ``live_b = ceil(kv_len_b /
+    block_size)``, one row after the other (``row_ref``/``group_ref`` say
+    which step is whose). The pool comes in through ``N`` BlockSpecs a side
+    whose index maps fetch physical blocks ``walk[b, g*N : g*N+N]``; the
+    pipeline fetches the next step's blocks, the next row's first ones too,
+    while this step computes. So a row costs its live blocks, rounded up to
+    ``N``, whatever the width of the bucketed table: the table's padding is
+    neither fetched nor computed on. Where a row's last step reaches past its
+    last live block, ``walk`` repeats that block (already there, the row's
+    own, never the null block's or another row's values), and those positions
+    are masked like the tail of the last live block (``pos < kv_len``, the mask
+    of the gather reference). A padded slot (``kv_len`` 1, all-null table) is
+    one step on one fetched block.
+
+    The arithmetic is f32 on the VPU, per KV head: ``q`` arrives as ``[G,
+    Hkv, D]`` (``G`` query heads to a KV head), so a token's ``[Hkv, D]`` tile
+    of K meets each of the ``G`` query tiles as it lies, and no copy of K at
+    the query heads' width exists. Scores are lane reductions kept as ``[..,
+    Hkv, 1]`` columns, the shape the value product broadcasts from. The ``G``
+    heads are unrolled: their chains interleave, and rolled into a loop the
+    kernel ran 2.4 times longer (my chip runs, PR 26)."""
     from jax.experimental import pallas as pl  # deferred with pallas_call's
 
-    w = pl.program_id(1)
+    k_refs, v_refs = refs[:group], refs[group : 2 * group]
+    o_ref, acc_ref, m_ref, l_ref = refs[2 * group :]
+    i = pl.program_id(0)
+    g = group_ref[i]
+    kv_len = lens_ref[row_ref[i]]
 
-    @pl.when(w == 0)
+    @pl.when(g == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0].astype(jnp.float32) * scale           # [H, D]
-    k = k_ref[0].astype(jnp.float32)                   # [bs, Hkv, D]
-    v = v_ref[0].astype(jnp.float32)
-    if groups > 1:  # GQA: every q head in a group reads its kv head's block
-        bs, hkv, d = k.shape
-        k = jnp.broadcast_to(k[:, :, None, :], (bs, hkv, groups, d)).reshape(bs, -1, d)
-        v = jnp.broadcast_to(v[:, :, None, :], (bs, hkv, groups, d)).reshape(bs, -1, d)
-    # s[h, j] = q[h] . k[j, h] — broadcast-multiply-reduce on the VPU (one
-    # query row per head: an MXU matmul would be all padding)
-    s = jnp.sum(q[:, None, :] * k.transpose(1, 0, 2), axis=-1)  # [H, bs]
-    pos = w * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(pos < lens_ref[pl.program_id(0)], s, -jnp.inf)
-
-    m_prev, l_prev = m_ref[...], l_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))  # [H, 1]
-    # a fully-masked prefix of blocks keeps m at -inf: exp(-inf - -inf) would
-    # be NaN, so clamp the shift (everything is 0-weighted anyway)
-    shift = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-    alpha = jnp.exp(m_prev - shift)                    # [H, 1]
-    p = jnp.exp(s - shift)                             # [H, bs], masked -> 0
-    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.sum(
-        p[:, :, None] * v.transpose(1, 0, 2), axis=1
+    q = q_ref[0].astype(jnp.float32) * scale             # [G, Hkv, D]
+    k = jnp.concatenate([r[0] for r in k_refs]).astype(jnp.float32)  # [N*bs, Hkv, D]
+    v = jnp.concatenate([r[0] for r in v_refs]).astype(jnp.float32)
+    pos = g * group * block_size + jax.lax.broadcasted_iota(
+        jnp.int32, (k.shape[0], k.shape[1], 1), 0
     )
-    m_ref[...] = m_new
-    l_ref[...] = l_new
+    live = pos < kv_len  # true at the step's first position: `m_new` is finite
+    for h in range(q.shape[0]):  # one query head of every KV head
+        # s[t, kv] = q[h, kv] . k[t, kv]: multiply-reduce over the lanes
+        s = jnp.sum(q[h] * k, axis=-1, keepdims=True)    # [N*bs, Hkv, 1]
+        s = jnp.where(live, s, -jnp.inf)
+        m_prev = m_ref[h]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))  # [Hkv, 1]
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)                           # masked -> 0
+        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=0)
+        acc_ref[h] = acc_ref[h] * alpha + jnp.sum(p * v, axis=0)
+        m_ref[h] = m_new
 
-    @pl.when(w == pl.num_programs(1) - 1)
+    @pl.when((g + 1) * group * block_size >= kv_len)  # the row's last step
     def _finalize():
         o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
@@ -739,9 +767,17 @@ def paged_attention_decode(
     """Pallas paged flash-attention decode: q ``[B, 1, H, D]`` against
     per-layer pools ``[num_blocks, block_size, Hkv, D]`` through
     ``block_tables [B, W]``, with ragged per-row live lengths ``kv_lens
-    [B]``. Walks each row's block table and streams the referenced KV blocks
-    through VMEM with online softmax — the gathered ``[B, W*block_size]``
-    cache the XLA reference materializes per layer never exists.
+    [B]``. Each row walks the ``ceil(kv_len / block_size)`` live entries of
+    its table, ``N`` blocks a grid step, the next step's blocks in flight
+    while this step's are computed (:func:`_paged_decode_kernel`); ``N`` comes
+    from the shapes (:func:`_decode_group_blocks`) and the length of the grid
+    from ``kv_lens``. The gathered ``[B, W*block_size]`` cache the XLA
+    reference materializes per layer never exists, and the padding of a
+    bucketed table is neither fetched nor computed on. On one v5e at 32/8
+    heads of 128, blocks of 16, bf16 (my chip runs, PR 26): 1.2-1.3 ms a call
+    for 64 rows holding 3000 live blocks of a 64 x 144 table (8.1-8.2 ms on
+    the ``(B, W)`` one-block grid this replaced), 3.5 ms with the table full,
+    whose 604 MB need 0.74 ms: 0.38 us a block is VPU arithmetic.
     ``interpret=True`` runs the identical kernel through the Pallas
     interpreter (the CPU parity path in tier-1 CI)."""
     from jax.experimental import pallas as pl_  # deferred: CPU-only installs
@@ -754,49 +790,67 @@ def paged_attention_decode(
     W = block_tables.shape[1]
     if H % Hkv:
         raise ValueError(f"q heads {H} not a multiple of kv heads {Hkv}")
+    G = H // Hkv
     sm_scale = (1.0 / math.sqrt(D)) if scale is None else float(scale)
+    N = _decode_group_blocks(block_size, Hkv, D, k_pool.dtype, G, W)
+    steps = pl_.cdiv(W, N)  # of a row whose table is full
 
+    # The walk, from the lengths (the same small integer arrays for every
+    # layer of a step). A longer kv_len than the table holds reads the table.
+    kv_lens = jnp.minimum(jnp.asarray(kv_lens, jnp.int32).reshape(B), W * block_size)
+    live = jnp.maximum(pl_.cdiv(kv_lens, block_size), 1)  # table entries, >= 1
+    walk = jnp.take_along_axis(
+        block_tables.astype(jnp.int32),
+        jnp.minimum(jnp.arange(steps * N, dtype=jnp.int32), live[:, None] - 1),
+        axis=1,
+    )
+    ends = jnp.cumsum(pl_.cdiv(live, N))  # grid steps up to and with row b
+    step = jnp.arange(B * steps, dtype=jnp.int32)
+    done = step[:, None] >= ends  # [B*steps, B]: row b ends before this step
+    # (steps past ends[-1] never run; their entries only have to stay in range)
+    step_row = jnp.minimum(jnp.sum(done, axis=1, dtype=jnp.int32), B - 1)
+    step_group = step - jnp.max(jnp.where(done, ends, 0), axis=1)
+
+    def pool_block(j):
+        return pl_.BlockSpec(
+            (1, block_size, Hkv, D),
+            lambda i, walk, lens, row, group: (walk[row[i], group[i] * N + j], 0, 0, 0),
+        )
+
+    row = pl_.BlockSpec(
+        (1, G, Hkv, D), lambda i, walk, lens, row, group: (row[i], 0, 0, 0)
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block tables + lengths
-        grid=(B, W),
-        in_specs=[
-            pl_.BlockSpec((1, H, D), lambda b, w, tables, lens: (b, 0, 0)),
-            pl_.BlockSpec(
-                (1, block_size, Hkv, D),
-                lambda b, w, tables, lens: (tables[b, w], 0, 0, 0),
-            ),
-            pl_.BlockSpec(
-                (1, block_size, Hkv, D),
-                lambda b, w, tables, lens: (tables[b, w], 0, 0, 0),
-            ),
-        ],
-        out_specs=pl_.BlockSpec((1, H, D), lambda b, w, tables, lens: (b, 0, 0)),
+        num_scalar_prefetch=4,
+        grid=(ends[-1],),
+        in_specs=[row] + 2 * [pool_block(j) for j in range(N)],
+        out_specs=row,
         scratch_shapes=[
-            pltpu.VMEM((H, D), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((G, Hkv, D), jnp.float32),
+            pltpu.VMEM((G, Hkv, 1), jnp.float32),
+            pltpu.VMEM((G, Hkv, 1), jnp.float32),
         ],
     )
     kernel = partial(
-        _paged_decode_kernel,
-        block_size=block_size,
-        groups=H // Hkv,
-        scale=sm_scale,
+        _paged_decode_kernel, block_size=block_size, group=N, scale=sm_scale
     )
     out = pl_.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, G, Hkv, D), q.dtype),
         interpret=interpret,
         name="paged_decode",
     )(
-        block_tables.astype(jnp.int32),
-        jnp.asarray(kv_lens, jnp.int32).reshape(B),
-        q[:, 0],
-        k_pool,
-        v_pool,
+        walk,
+        kv_lens,
+        step_row,
+        step_group,
+        # query head h = kv_head * G + g: [B, Hkv, G, D] -> group major
+        q.reshape(B, Hkv, G, D).transpose(0, 2, 1, 3),
+        *(N * [k_pool] + N * [v_pool]),
     )
-    return out[:, None]  # [B, 1, H, D], the caller's BSHD contract
+    # back to [B, 1, H, D], the caller's BSHD contract
+    return out.transpose(0, 2, 1, 3).reshape(B, 1, H, D)
 
 
 def _paged_prefill_kernel(

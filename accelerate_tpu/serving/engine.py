@@ -367,6 +367,10 @@ class ServingEngine:
         self.max_running = 0
         self._occupancy_sum = 0.0
         self._occupancy_steps = 0
+        #: block-table entries the decode batches' rows held (the paged decode
+        #: kernel's work) and entries of the bucketed tables they were handed
+        self.decode_blocks_live = 0
+        self.decode_blocks_walked = 0
         #: speculative decoding: draft tokens proposed / accepted, and the
         #: accepted-per-step histogram (index = draft tokens accepted that
         #: slot-step, 0..k) the report's serving section renders
@@ -910,12 +914,25 @@ class ServingEngine:
         req.generated.append(tok)
         self.prefill_calls += 1
 
-    def _decode_batch(self, running: "list[Request]", finished: "list[Request]") -> None:
+    def _decode_bucket(self, running: "list[Request]") -> "tuple[int, int, int]":
+        """The lattice point of a decode batch and the blocks its rows hold:
+        ``(slot_bucket, block_bucket, live_blocks)``. The paged decode kernel's
+        work follows ``live_blocks``; ``slot_bucket * block_bucket`` is the
+        table it is handed (``decode_blocks_live`` / ``decode_blocks_walked``
+        in :meth:`stats`)."""
+        blocks = [self.allocator.num_seq_blocks(r.rid) for r in running]
         Bb = self.lattice.slot_bucket(len(running))
-        W = self.lattice.block_bucket(
-            max(self.allocator.num_seq_blocks(r.rid) for r in running)
-        )
-        with self._phase("build", batch=len(running), slot_bucket=Bb, block_bucket=W):
+        W = self.lattice.block_bucket(max(blocks))
+        live = sum(blocks)
+        self.decode_blocks_live += live
+        self.decode_blocks_walked += Bb * W
+        return Bb, W, live
+
+    def _decode_batch(self, running: "list[Request]", finished: "list[Request]") -> None:
+        Bb, W, live = self._decode_bucket(running)
+        with self._phase(
+            "build", batch=len(running), slot_bucket=Bb, block_bucket=W, live_blocks=live
+        ):
             last = np.zeros((Bb,), np.int32)
             tables = np.full((Bb, W), NULL_BLOCK, np.int32)
             positions = np.zeros((Bb,), np.int32)
@@ -987,11 +1004,10 @@ class ServingEngine:
         prefix and are position-masked out of every read until the next
         step's scatter overwrites them."""
         k = self.spec_tokens
-        Bb = self.lattice.slot_bucket(len(running))
-        W = self.lattice.block_bucket(
-            max(self.allocator.num_seq_blocks(r.rid) for r in running)
-        )
-        with self._phase("build", batch=len(running), slot_bucket=Bb, block_bucket=W):
+        Bb, W, live = self._decode_bucket(running)
+        with self._phase(
+            "build", batch=len(running), slot_bucket=Bb, block_bucket=W, live_blocks=live
+        ):
             last = np.zeros((Bb,), np.int32)
             tables = np.full((Bb, W), NULL_BLOCK, np.int32)
             positions = np.zeros((Bb,), np.int32)
@@ -1159,6 +1175,8 @@ class ServingEngine:
             "mean_occupancy": round(
                 self._occupancy_sum / max(self._occupancy_steps, 1), 6
             ),
+            "decode_blocks_live": self.decode_blocks_live,
+            "decode_blocks_walked": self.decode_blocks_walked,
             **self.jit_cache_sizes(),
             **self.allocator.stats(),
         }

@@ -1,9 +1,10 @@
 """Pallas paged-attention decode kernel (ISSUE 14): interpret-mode parity.
 
-The kernel (``ops.flash_attention.paged_attention_decode``) walks block
-tables and streams KV blocks through VMEM with online softmax; the XLA
-gather path (``serving.kv_pager.paged_attention``) is the reference
-semantics. These tests drive the SAME kernel through the Pallas interpreter
+The kernel (``ops.flash_attention.paged_attention_decode``) walks each row's
+LIVE block-table entries, ``N`` blocks a grid step (ISSUE 26), and streams
+them through VMEM with online softmax; the XLA gather path
+(``serving.kv_pager.paged_attention``) is the reference semantics. These
+tests drive the SAME kernel through the Pallas interpreter
 on CPU — identical dataflow, no TPU required — and hold the line the
 acceptance criteria name: parity across scrambled non-contiguous block
 tables, GQA head ratios, ragged per-slot lengths, null-block rows, and
@@ -17,6 +18,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import importlib
+
 from accelerate_tpu.generation import greedy_generate
 from accelerate_tpu.models import LlamaConfig, init_llama
 from accelerate_tpu.ops.flash_attention import (
@@ -28,6 +31,8 @@ from accelerate_tpu.serving import BucketLattice, ServingEngine
 from accelerate_tpu.serving.kv_pager import NULL_BLOCK, paged_attention as gather_ref
 
 CONFIG = LlamaConfig.tiny()
+# the module, not the function ``accelerate_tpu.ops`` re-exports under its name
+fa = importlib.import_module("accelerate_tpu.ops.flash_attention")
 
 
 def _random_paged_case(seed, *, B, H, Hkv, D, bs, nb, W, lens):
@@ -122,6 +127,100 @@ def test_kernel_parity_bf16_pools_within_one_ulp():
 
 
 # ---------------------------------------------------------------------------
+# the walk: a row's live blocks, N a grid step
+
+
+@pytest.fixture
+def group_of(monkeypatch):
+    """Pin the walk's ``N`` (clamped to ``[1, W]`` like the derived one): tiny
+    pools would otherwise all fit one group."""
+    def pin(n):
+        monkeypatch.setattr(
+            fa, "_decode_group_blocks", lambda bs, Hkv, D, dtype, G, W: max(1, min(W, n)))
+    return pin
+
+
+# (N, W, lens) at block_size 4
+WALK_CASES = {
+    "ends-inside-a-group": (3, 8, [17, 26, 5]),            # 5, 7, 2 blocks of groups of 3
+    "ends-on-a-group-boundary": (3, 8, [24, 12, 21]),      # 6, 3 blocks: whole groups
+    "one-token-on-an-all-null-row": (3, 8, [29, 1, 1]),
+    "full-table": (3, 6, [24, 24]),
+    "full-table-of-an-odd-width": (4, 7, [28, 27]),
+    "W-under-N-1": (8, 1, [3, 4]),
+    "W-under-N-2": (8, 2, [8, 5, 1]),
+    "W-under-N-4": (8, 4, [16, 9, 1]),
+    "W-not-a-multiple-of-N-5": (3, 5, [20, 13, 4]),
+    "W-not-a-multiple-of-N-7": (4, 7, [25, 17, 1]),
+    "N-1": (1, 4, [13, 16, 1]),
+}
+
+
+@pytest.mark.parametrize("case", list(WALK_CASES))
+def test_walk_parity(group_of, case):
+    N, W, lens = WALK_CASES[case]
+    group_of(N)
+    q, k_pool, v_pool, tables, lens = _random_paged_case(
+        10, B=len(lens), H=8, Hkv=2, D=16, bs=4, nb=40, W=W, lens=lens)
+    for b, n in enumerate(lens):
+        if n == 1:  # a padded slot: all-null table, one token
+            tables[b, :] = NULL_BLOCK
+    _assert_parity(q, k_pool, v_pool, tables, lens)
+
+
+@pytest.mark.parametrize(
+    "bs,G,Hkv,D,dtype,W,lens,N",
+    [(128, 2, 8, 64, jnp.bfloat16, 3, [300, 128, 1], 1),   # the big-block compile shape
+     (16, 4, 8, 128, jnp.float32, 6, [90, 17, 64], 3)],
+    ids=["bs128-bf16", "bs16-f32"],
+)
+def test_walk_parity_at_the_derived_group(bs, G, Hkv, D, dtype, W, lens, N):
+    """No pinning: ``N`` is what the shapes give, and it is under ``W``."""
+    assert fa._decode_group_blocks(bs, Hkv, D, dtype, G, W) == N
+    q, k_pool, v_pool, tables, lens = _random_paged_case(
+        11, B=len(lens), H=G * Hkv, Hkv=Hkv, D=D, bs=bs, nb=14, W=W, lens=lens)
+    bf16 = dtype == jnp.bfloat16
+    _assert_parity(q.astype(dtype), k_pool.astype(dtype), v_pool.astype(dtype), tables, lens,
+                   tol=2e-2 if bf16 else 1e-6)
+
+
+def test_group_comes_from_shapes():
+    """``N`` at the serve cells' shapes, at a big block, and its clamps."""
+    bf16 = jnp.bfloat16
+    assert fa._decode_group_blocks(16, 8, 128, bf16, 4, 144) == 4
+    assert fa._decode_group_blocks(16, 8, 128, bf16, 4, 48) == 4
+    assert fa._decode_group_blocks(16, 8, 128, bf16, 4, 2) == 2      # never over W
+    assert fa._decode_group_blocks(128, 8, 64, bf16, 2, 8) == 1      # never under 1
+    assert fa._decode_group_blocks(16, 8, 128, jnp.float32, 4, 144) == 3
+    assert fa._decode_group_blocks(16, 8, 64, bf16, 2, 32) == \
+        fa._decode_group_blocks(16, 8, 128, bf16, 2, 32)              # D pads to a lane tile
+
+
+@pytest.mark.parametrize("N", [2, 3, 8])
+def test_dead_table_entries_are_skipped_not_masked(group_of, N):
+    """Masking multiplies a dead block's values by zero, and 0 * NaN is NaN;
+    skipping never reads them. With every pool block a row does not own set to
+    NaN (the null block too, but for the padded slot that owns it), the row's
+    output is finite and what the clean pool gives."""
+    group_of(N)
+    lens = [22, 5, 1, 32]  # 6, 2, 1 (padded slot) and all 8 of 8 entries live
+    q, k_pool, v_pool, tables, lens = _random_paged_case(
+        12, B=4, H=4, Hkv=2, D=16, bs=4, nb=24, W=8, lens=lens)
+    tables[2, :] = NULL_BLOCK
+    args = (jnp.asarray(q), jnp.asarray(tables), jnp.asarray(lens))
+    ref = gather_ref(args[0], jnp.asarray(k_pool), jnp.asarray(v_pool), args[1],
+                     jnp.asarray(lens - 1)[:, None])
+    for b, n in enumerate(lens):
+        own = tables[b, : -(-int(n) // 4)]
+        k_nan, v_nan = np.full_like(k_pool, np.nan), np.full_like(v_pool, np.nan)
+        k_nan[own], v_nan[own] = k_pool[own], v_pool[own]
+        out = paged_attention_decode(
+            args[0], jnp.asarray(k_nan), jnp.asarray(v_nan), args[1], args[2], interpret=True)
+        assert bool(jnp.all(jnp.isfinite(out[b]))), f"row {b} read a block it does not own"
+        assert float(jnp.max(jnp.abs(out[b] - ref[b]))) <= 1e-6
+
+
+# ---------------------------------------------------------------------------
 # dispatch + kill switch
 
 
@@ -160,9 +259,6 @@ def test_prefill_shapes_dispatch_to_the_prefill_kernel(monkeypatch):
     v_pool = jnp.asarray(rng.standard_normal((8, 4, 2, 16)), jnp.float32)
     tables = jnp.asarray([[3, 5, 1]], jnp.int32)
     qpos = jnp.asarray([[8, 9, 10]], jnp.int32)
-    import importlib
-
-    fa = importlib.import_module("accelerate_tpu.ops.flash_attention")
     calls = []
     real_prefill = fa.paged_attention_prefill
 
@@ -181,12 +277,6 @@ def test_tpu_backend_dispatches_the_kernel(monkeypatch):
     """On a TPU backend with the default mode, S=1 decode must route to the
     Pallas kernel (compiled, not interpreted) — asserted by stubbing the
     kernel entry point, since CI has no TPU to compile for."""
-    import importlib
-
-    # `ops.__init__` re-exports the `flash_attention` FUNCTION under the
-    # submodule's name, so attribute-style import resolves to the function —
-    # fetch the module itself
-    fa = importlib.import_module("accelerate_tpu.ops.flash_attention")
     calls = []
 
     def fake_decode(q, k_pool, v_pool, tables, lens, scale=None, *, interpret=False):

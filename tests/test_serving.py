@@ -898,6 +898,35 @@ def test_step_phases_are_disjoint_children_of_their_step(params, spec_tokens):
             assert names[-1] == "emit"
 
 
+@pytest.mark.parametrize("spec_tokens", [0, 2], ids=["decode", "spec-decode"])
+def test_build_phase_counts_the_live_blocks_of_its_rows(params, spec_tokens):
+    """Every ``atpu.serve.build`` carries ``live_blocks``, the sum of its
+    running rows' block counts (what the paged decode kernel walks), beside the
+    bucketed table it is handed; ``stats()`` keeps both totals."""
+    kwargs = dict(spec_tokens=spec_tokens, draft_layers=1) if spec_tokens else {}
+    engine = _mixed_engine(params, **kwargs)
+    held = {}  # step -> blocks the decode batch's rows held, counted from outside
+    batch_name = "_spec_decode_batch" if spec_tokens else "_decode_batch"
+    real_batch = getattr(engine, batch_name)
+
+    def counting(running, finished):
+        held[engine.steps] = sum(engine.allocator.num_seq_blocks(r.rid) for r in running)
+        return real_batch(running, finished)
+
+    setattr(engine, batch_name, counting)
+    _run_staggered(engine, _prompts(11, (16, 40, 14, 9)), 10)
+    builds = {key["step"]: key for _, _, _, key in _engine_records(engine, PHASE + "build")}
+    assert builds and sorted(builds) == sorted(held)
+    for step, key in builds.items():
+        assert key["live_blocks"] == held[step] >= key["batch"]
+        assert key["live_blocks"] <= key["slot_bucket"] * key["block_bucket"]
+    stats = engine.stats()
+    assert stats["decode_blocks_live"] == sum(held.values())
+    assert stats["decode_blocks_walked"] == sum(
+        key["slot_bucket"] * key["block_bucket"] for key in builds.values())
+    assert 0 < stats["decode_blocks_live"] <= stats["decode_blocks_walked"]
+
+
 def test_phase_ring_is_bounded_and_two_engines_keep_apart(params, monkeypatch):
     import collections
 

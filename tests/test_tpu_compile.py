@@ -91,8 +91,11 @@ def test_flash_attention_compiles_for_v5e(one_chip, case, direction):
 # (B, H, Hkv, D, block_size, W)
 @pytest.mark.parametrize(
     "B,H,Hkv,D,block_size,W",
-    [(8, 16, 8, 64, 16, 32), (8, 32, 8, 128, 16, 64), (4, 16, 8, 64, 128, 8)],
-    ids=["b8-16x8-d64-bs16", "b8-32x8-d128-bs16", "b4-16x8-d64-bs128"],
+    [(8, 16, 8, 64, 16, 32), (8, 32, 8, 128, 16, 64), (4, 16, 8, 64, 128, 8),
+     # the serve cells' own decode shapes: chat-sat's and chat-r80's buckets
+     (64, 32, 8, 128, 16, 144), (32, 32, 8, 128, 16, 48)],
+    ids=["b8-16x8-d64-bs16", "b8-32x8-d128-bs16", "b4-16x8-d64-bs128",
+         "b64-32x8-d128-bs16-w144", "b32-32x8-d128-bs16-w48"],
 )
 def test_paged_decode_compiles_for_v5e(one_chip, B, H, Hkv, D, block_size, W):
     pool = ((512, block_size, Hkv, D), BF16)
