@@ -3,9 +3,9 @@ device record, JAX's compile cache, the compile counter, peak memory, the
 profiler slice, and the result line.
 
 Nothing here knows a model, a traffic mix or a metric by name: those sit in
-files of their own (``configs/``, ``traffic/``, ``workloads/``, ``runners/``,
-``layer_metrics/``), so a later PR adds files and manifest entries and edits
-nothing that is here."""
+files of their own (``configs/``, ``traffic/``, ``workloads/``, ``kinds/``,
+``runners/``, ``layer_metrics/``), so a later PR adds files and manifest
+entries and edits nothing that is here."""
 
 from __future__ import annotations
 
@@ -23,8 +23,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(os.path.dirname(HERE))
 MANIFEST = os.path.join(REPO, "BENCHMARK.json")
 
-# the result line has exactly these keys (plus `breakdown` in a traced run)
-RESULT_KEYS = ("correct", "attempted", "failed", "metrics", "device")
+# the result line has exactly these keys (plus `breakdown` in a traced run), `compared` last
+RESULT_KEYS = ("correct", "attempted", "failed", "metrics", "device", "compared")
 
 
 @dataclasses.dataclass
@@ -38,6 +38,7 @@ class Cell:
     traffic: dict       # traffic/<traffic>.json: the mix one general generator reads
     end_to_end: list    # the manifest's end-to-end metric entries this cell reports
     per_layer: list     # the manifest's per-layer metric entries this cell reports
+    root: str = HERE    # where its files were found: kinds/ and layer_metrics/ are looked up there
 
     @property
     def runner(self) -> str:
@@ -48,7 +49,10 @@ class Cell:
 class Record:
     """What a runner hands back. ``clocks`` holds the runner's host clocks and
     the program's counters, ``trace`` the reduced profiler slice of a traced
-    run; the per-layer readers take their numbers from these two."""
+    run; the per-layer readers take their numbers from these two, and the
+    cell's published keys and engine settings from ``cell``, which
+    ``result_line`` puts there. ``compared`` is what decided ``correct``:
+    ``{name: [number, limit]}``."""
 
     correct: bool
     attempted: int
@@ -57,6 +61,8 @@ class Record:
     clocks: dict
     facts: dict
     trace: Optional[dict] = None
+    compared: dict = dataclasses.field(default_factory=dict)
+    cell: Optional[Cell] = None
 
 
 def _read_json(*parts) -> dict:
@@ -69,7 +75,13 @@ def _reported_by(metric: dict, cell_name: str) -> bool:
 
 
 def load_cell(name: str, manifest_path: str = MANIFEST) -> Cell:
+    """A cell with its files read. They are found from the manifest's place:
+    beside another ``BENCHMARK.json`` lies another ``benchmarks/chip/`` with
+    its own ``configs/``, ``traffic/``, ``workloads/``, ``kinds/`` and
+    ``layer_metrics/`` (the tests build one under a temporary directory)."""
     manifest = _read_json(manifest_path)
+    top = os.path.dirname(os.path.abspath(manifest_path))
+    root = os.path.join(top, os.path.relpath(HERE, REPO))
     entries = {w["name"]: w for w in manifest["workloads"]}
     if name not in entries:
         raise SystemExit(f"no cell {name!r} in BENCHMARK.json; it has {sorted(entries)}")
@@ -78,11 +90,12 @@ def load_cell(name: str, manifest_path: str = MANIFEST) -> Cell:
     return Cell(
         name=name,
         chips=int(entry["chips"]),
-        spec=_read_json(HERE, "workloads", name + ".json"),
-        config=_read_json(os.path.dirname(manifest_path), config_file),
-        traffic=_read_json(HERE, "traffic", entry["traffic"] + ".json"),
+        spec=_read_json(root, "workloads", name + ".json"),
+        config=_read_json(top, config_file),
+        traffic=_read_json(root, "traffic", entry["traffic"] + ".json"),
         end_to_end=[m for m in manifest["end_to_end"] if _reported_by(m, name)],
         per_layer=[m for m in manifest["per_layer"] if _reported_by(m, name)],
+        root=root,
     )
 
 
@@ -90,14 +103,19 @@ def runner_of(cell: Cell):
     return importlib.import_module(f"benchmarks.chip.runners.{cell.runner}")
 
 
-def layer_metric_reader(name: str):
-    """The reader of one per-layer metric: ``layer_metrics/<name>.py``, loaded
-    by path because a metric's name may hold dots."""
-    path = os.path.join(HERE, "layer_metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location("layer_metric_" + name.replace(".", "_"), path)
+def module_from_path(path: str, prefix: str):
+    """A data-named file as a module: loaded by path, because the name of a
+    metric or a kind may hold dots and the file may lie under another root."""
+    stem = os.path.basename(path)[:-3].replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(prefix + stem, path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
+
+
+def layer_metric_reader(name: str, root: str = HERE):
+    """The reader of one per-layer metric: ``<root>/layer_metrics/<name>.py``."""
+    return module_from_path(os.path.join(root, "layer_metrics", name + ".py"), "layer_metric_").read
 
 
 # ---------------------------------------------------------------- the device
@@ -234,11 +252,14 @@ def nearest_rank(values, q: float) -> float:
 def result_line(cell: Cell, record: Record, *, traced: bool) -> dict:
     """The one JSON object the driver reads: the cell's end-to-end metrics in
     a plain run, its per-layer metrics in a traced run. A reader that finds
-    nothing to read returns None and its metric is left out."""
+    nothing to read returns None and its metric is left out. The readers get
+    the record with the cell on it. Last in the line comes ``compared``: every
+    number that decided ``correct``, beside its limit."""
+    record = dataclasses.replace(record, cell=cell)
     metrics: dict[str, Any] = {}
     if traced:
         for entry in cell.per_layer:
-            value = layer_metric_reader(entry["name"])(record)
+            value = layer_metric_reader(entry["name"], cell.root)(record)
             if value is not None:
                 metrics[entry["name"]] = {"value": float(value), "unit": entry["unit"]}
     else:
@@ -253,4 +274,6 @@ def result_line(cell: Cell, record: Record, *, traced: bool) -> dict:
         device["window_s"] = record.trace["window_s"]
         line["breakdown"] = {"device_ops": record.trace["device_ops"][:10],
                              "idle_gaps": record.trace["idle_gaps"][:10]}
+    line["compared"] = {name: {"value": value, "limit": limit}
+                        for name, (value, limit) in record.compared.items()}
     return line

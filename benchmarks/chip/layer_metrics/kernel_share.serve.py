@@ -1,6 +1,7 @@
 """Device time of the Pallas custom calls (``tpu_custom_call``: in the serve
-programs these are the paged decode and paged prefill kernels, which carry no
-name of their own and so cannot be told apart) over the device's busy time."""
+programs these are the paged decode and paged prefill kernels, which
+``paged_decode_share.serve`` and ``paged_prefill_share.serve`` tell apart by
+name) over the device's busy time."""
 
 
 def read(record):

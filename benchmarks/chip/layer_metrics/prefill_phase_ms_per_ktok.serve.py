@@ -11,5 +11,5 @@ def read(record):
     if not steps:
         return None
     seconds = sum(program_spans.total(p, "prefill") for p in steps.values())
-    tokens = sum(sum(p.get("prefill_tokens", ())) for p in steps.values())
+    tokens = sum(key["tokens"] for key in program_spans.attributes(steps, "prefill"))
     return 1e3 * seconds / (tokens / 1e3) if tokens else None

@@ -1,80 +1,52 @@
-"""The small registry behind a configuration's ``kind``: how to turn the
-published keys into the program's config, and where its init, loss, sharding
-rules, operation count and plain reference are. A configuration of a kind that
-is here is a data file; a new kind is a new entry."""
+"""What a configuration's ``kind`` brings, found by name like everything else:
+``kinds/<kind>.py`` beside the configurations, loaded by path. A kind file
+says how to turn the published keys into the program's config and where its
+init, loss, sharding rules, operation count and plain reference are, as
+module-level names (the ``HOOKS``). A new architecture is a new kind file with
+a reference and counts of its own; nothing here knows one."""
 
 from __future__ import annotations
 
-import functools
+import glob
+import os
 
-from benchmarks.chip import flops, reference
+from benchmarks.chip import harness
 
-
-def _bert_config(c: dict, *, n_layers: int, max_seq_len: int):
-    from accelerate_tpu.models import BertConfig
-
-    return BertConfig(
-        vocab_size=c["vocab_size"], dim=c["hidden_size"], n_layers=n_layers,
-        n_heads=c["num_attention_heads"], ffn_dim=c["intermediate_size"],
-        max_seq_len=max_seq_len, type_vocab_size=c["type_vocab_size"],
-        num_labels=c["assumed"]["num_labels"], norm_eps=c["layer_norm_eps"],
-    )
-
-
-def _llama_config(c: dict, *, n_layers: int, max_seq_len: int):
-    from accelerate_tpu.models import LlamaConfig
-
-    if c["hidden_size"] != c["num_attention_heads"] * c["head_dim"]:
-        raise ValueError("the program's LlamaConfig derives head_dim as dim / n_heads")
-    if c.get("sliding_window") is not None:
-        raise ValueError("the paged kernels cannot express a sliding window")
-    return LlamaConfig(
-        vocab_size=c["vocab_size"], dim=c["hidden_size"], n_layers=n_layers,
-        n_heads=c["num_attention_heads"], n_kv_heads=c["num_key_value_heads"],
-        ffn_dim=c["intermediate_size"], max_seq_len=max_seq_len,
-        rope_theta=c["rope_theta"], norm_eps=c["rms_norm_eps"],
-        tie_embeddings=c["tie_word_embeddings"],
-    )
+# what the runners ask a kind for; a kind that only trains or only serves
+# leaves the other runner's hooks out
+HOOKS = {
+    "program_config": "both runners: (published keys, n_layers=, max_seq_len=) -> the program's config",
+    "init": "both runners: (program config, key) -> the parameter tree",
+    "loss": "train: (program config, **loss_kwargs) -> fn(params, batch)",
+    "shard_rules": "train: () -> the sharding rules `Accelerator.prepare` takes",
+    "forward_flops_per_token": "both runners: (published keys, seq_len, n_layers) -> matmul operations",
+    "reference_loss": "train: (published keys) -> fn(params, batch), the plain float32 loss",
+    "reference_logits": "serve: (published keys) -> fn(params, ids [T]) -> float32 logits [T, vocab]",
+}
 
 
-def _bert_kind():
-    from accelerate_tpu import models as m
+class Kind(dict):
+    """The hooks one kind file exports. Asking for one it lacks says which
+    file lacks what, and what the hook is."""
 
-    return {
-        "program_config": _bert_config,
-        "init": m.init_bert,
-        "loss": lambda cfg, **kw: (lambda p, b: m.bert_loss(p, b, cfg)),
-        "shard_rules": m.bert_shard_rules,
-        "forward_flops_per_token": flops.bert_forward_flops_per_token,
-        "reference_loss": lambda c: functools.partial(
-            reference.bert_loss, n_heads=c["num_attention_heads"], eps=c["layer_norm_eps"]),
-    }
+    def __init__(self, path: str, module):
+        super().__init__({h: getattr(module, h) for h in HOOKS if hasattr(module, h)})
+        self.path, self.module = path, module
 
-
-def _llama_kind():
-    from accelerate_tpu import models as m
-
-    return {
-        "program_config": _llama_config,
-        "init": m.init_llama,
-        # remat as a cell asks for it: at long sequences the activations of
-        # every layer do not fit beside 16 bytes a parameter
-        "loss": lambda cfg, remat=False: (lambda p, b: m.llama_loss(p, b, cfg, remat=remat)),
-        "shard_rules": m.llama_shard_rules,
-        "forward_flops_per_token": flops.llama_forward_flops_per_token,
-        "reference_loss": lambda c: functools.partial(
-            reference.llama_loss, n_heads=c["num_attention_heads"],
-            n_kv_heads=c["num_key_value_heads"], eps=c["rms_norm_eps"], theta=c["rope_theta"]),
-    }
+    def __missing__(self, hook):
+        raise KeyError(f"{self.path} has no `{hook}` ({HOOKS.get(hook, 'not a hook')}); "
+                       f"it has {sorted(self)}")
 
 
-_KINDS = {"bert": _bert_kind, "llama": _llama_kind}
-
-
-def kind_of(config: dict) -> dict:
-    if config["kind"] not in _KINDS:
-        raise KeyError(f"no model kind {config['kind']!r}; the registry has {sorted(_KINDS)}")
-    return _KINDS[config["kind"]]()
+def kind_of(config: dict, root: str = harness.HERE) -> Kind:
+    """The kind file of a configuration, from ``<root>/kinds/``: ``root`` is
+    the directory the cell's other files were found in (``Cell.root``)."""
+    name = config["kind"]
+    path = os.path.join(root, "kinds", name + ".py")
+    if not os.path.exists(path):
+        have = sorted(os.path.basename(p)[:-3] for p in glob.glob(os.path.join(root, "kinds", "*.py")))
+        raise KeyError(f"no model kind {name!r}: no {path}; the kinds there are {have}")
+    return Kind(path, harness.module_from_path(path, "chip_benchmark_kind_"))
 
 
 def depth(cell) -> int:

@@ -4,9 +4,10 @@
 
 Everything is found by name from ``BENCHMARK.json``: the cell's file under
 ``workloads/``, its configuration under ``configs/``, its traffic mix under
-``traffic/``, its runner under ``runners/`` and one reader per per-layer metric
-under ``layer_metrics/`` (see ``README.md`` beside this file). Earlier lines of
-the output are free text; the LAST line is the result the driver reads."""
+``traffic/``, its model kind under ``kinds/``, its runner under ``runners/`` and
+one reader per per-layer metric under ``layer_metrics/`` (see ``README.md``
+beside this file). Earlier lines of the output are free text; the LAST line is
+the result the driver reads."""
 
 import time
 
@@ -41,7 +42,10 @@ def main(argv=None) -> int:
         process_t0=_PROCESS_T0,
     )
     print(json.dumps({"facts": record.facts}), flush=True)
-    print(json.dumps(harness.result_line(cell, record, traced=bool(args.trace))), flush=True)
+    line = harness.result_line(cell, record, traced=bool(args.trace))
+    for name, c in line["compared"].items():  # the last lines of standard error
+        print(f"compared {name}: {c['value']} limit {c['limit']}", file=sys.stderr, flush=True)
+    print(json.dumps(line), flush=True)
     return 0
 
 

@@ -49,7 +49,7 @@ def run(cell, *, seed: int, seconds: float, trace: bool, process_t0: float,
     from accelerate_tpu import Accelerator, DataLoader, DeepSpeedPlugin, ParallelismConfig
 
     harness.require_device(cell.chips, allow_cpu=allow_cpu)
-    spec, mix, kind = cell.spec, cell.traffic, models.kind_of(cell.config)
+    spec, mix, kind = cell.spec, cell.traffic, models.kind_of(cell.config, cell.root)
     n_layers = models.depth(cell)
     cfg = kind["program_config"](cell.config, n_layers=n_layers, max_seq_len=mix["seq_len"])
     key_seed = int(seed) % (2**31 - 1)
@@ -182,4 +182,10 @@ def run(cell, *, seed: int, seconds: float, trace: bool, process_t0: float,
             "mesh": {k: int(v) for k, v in accelerator.mesh.shape.items() if v > 1},
         },
         trace=out["trace"],
+        compared={
+            "loss_rel": (check["loss_rel"], tol["loss"]),
+            "grad_norm_gap": (abs(norm - ref_norm), tol["grad_norm"] * abs(ref_norm) + tol["grad_norm_abs"]),
+            "nonfinite_losses": (int(np.sum(~np.isfinite(losses))), 0),
+            "late_compiles": (late_compiles, 0), "step_cache_grew": (int(cache_grew), 0),
+        },
     )

@@ -1,7 +1,7 @@
 """From a profiler trace (``.xplane.pb``) to what the per-layer metrics read:
 the device's busy time as the union of its operations' intervals, the
-operations that took most time by short name, and the device's idle gaps named
-by the host annotation (``cb.*``) that covers them.
+operations that took most time by short name with how often each ran, and the
+device's idle gaps named by the host annotation (``cb.*``) that covers them.
 
 Only the part of the trace inside the runner's ``cb.window`` annotation is
 reduced: the profiler's own start and stop leave the device idle, and that is
@@ -71,13 +71,15 @@ def reduce(devices, annotations) -> dict | None:
         return None
     lo, hi = windows[0][1], windows[0][2]
     spans = [(n, *_clip(s, e, lo, hi)) for n, s, e in annotations if n != WINDOW and e > lo and s < hi]
-    busy_ns, op_ns, kernel_ns, gap_ns = 0.0, {}, 0.0, {}
+    busy_ns, op_ns, op_calls, kernel_ns, gap_ns = 0.0, {}, {}, 0.0, {}
     for ops in devices:
         inside = [(n, *_clip(s, e, lo, hi)) for n, s, e in ops if e > lo and s < hi]
         merged = _union((s, e) for _, s, e in inside)
         busy_ns += sum(e - s for s, e in merged)
         for name, s, e in inside:
-            op_ns[short_name(name)] = op_ns.get(short_name(name), 0.0) + (e - s)
+            short = short_name(name)
+            op_ns[short] = op_ns.get(short, 0.0) + (e - s)
+            op_calls[short] = op_calls.get(short, 0) + 1
             if KERNEL_MARK in name:
                 kernel_ns += e - s
         edges = [lo] + [t for pair in merged for t in pair] + [hi]
@@ -104,6 +106,7 @@ def reduce(devices, annotations) -> dict | None:
         "kernel_s": kernel_ns / n / 1e9,
         "n_devices": n,
         "device_ops": ranked(op_ns),
+        "device_op_calls": {k: v / n for k, v in op_calls.items()},  # a device, like the seconds
         "idle_gaps": ranked(gap_ns),
     }
 

@@ -125,7 +125,10 @@ def test_program_span_reader_finds_nothing_rather_than_something_wrong(monkeypat
 def test_window_is_the_last_engines_steps_below_the_runners_count(monkeypatch):
     monkeypatch.setattr(tracing, "_RING", _hand_built_ring())
     steps = program_spans.window_steps(_record(steps=3))
-    assert sorted(steps) == [0, 1, 2] and steps[0]["prefill_tokens"] == [600, 400]
+    assert sorted(steps) == [0, 1, 2]
+    assert [(k["rid"], k["tokens"]) for k in program_spans.attributes(steps, "prefill")] == [
+        (1, 600), (2, 400)]
+    assert program_spans.attributes(steps, "build")[0]["block_bucket"] == 48
     assert program_spans.total(steps[0], "prefill") == pytest.approx(0.1)
     assert sorted(program_spans.window_steps(_record(steps=4))) == [0, 1, 2, 3]
     assert [r["rid"] for r in program_spans.window_requests(_record(steps=3))] == [1, 2, 3]
