@@ -47,7 +47,14 @@ __all__ = [
 
 
 def init_kv_cache(config: LlamaConfig, batch_size: int, max_len: int, dtype=jnp.bfloat16):
-    """Stacked cache: {"k","v"}: [L, B, max_len, Hkv, D]."""
+    """Stacked cache: {"k","v"}: [L, B, max_len, Hkv, D]. The single-stream
+    decode of this module restates the llama layer (``_layer_step``): another
+    model is refused here, where every generate path starts, and is served
+    through ``serving.ServingEngine``."""
+    if not isinstance(config, LlamaConfig):
+        raise TypeError(
+            f"generation.py decodes a LlamaConfig; serve a {type(config).__name__} "
+            "through serving.ServingEngine")
     shape = (config.n_layers, batch_size, max_len, config.n_kv_heads, config.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 

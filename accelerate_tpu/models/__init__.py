@@ -12,6 +12,11 @@ from .transformer import (
     llama_loss,
     llama_shard_rules,
 )
+from .cohere2_moe import (
+    Cohere2MoeConfig,
+    cohere2_moe_forward,
+    init_cohere2_moe,
+)
 from .resnet import (
     ResNetConfig,
     init_resnet,

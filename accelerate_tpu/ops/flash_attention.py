@@ -690,13 +690,16 @@ def _paged_decode_kernel(
     lens_ref,    # [B] int32 scalar-prefetch: per-row live kv length
     row_ref,     # [B*steps] int32 scalar-prefetch: the row of grid step i
     group_ref,   # [B*steps] int32 scalar-prefetch: its group within the row
-    q_ref,       # [1, G, Hkv, D]  the row's query, query-group major
-    *refs,       # N K blocks, N V blocks [1, block_size, Hkv, D]; then
-                 # o_ref [1, G, Hkv, D] and the online-softmax carries in
-                 # VMEM: acc [G, Hkv, D], m [G, Hkv, 1], l [G, Hkv, 1], f32
+    *refs,       # with a window first_ref [B] int32 scalar-prefetch, the block
+                 # a row's walk starts at; then q_ref [1, G, Hkv, D], the row's
+                 # query, query-group major; N K blocks, N V blocks [1,
+                 # block_size, Hkv, D]; o_ref [1, G, Hkv, D] and the
+                 # online-softmax carries in VMEM: acc [G, Hkv, D], m [G, Hkv,
+                 # 1], l [G, Hkv, 1], f32
     block_size: int,
     group: int,
     scale: float,
+    window: Optional[int] = None,
 ):
     """One grid step of paged flash decode: ``N`` consecutive blocks of one
     row, folded into its online softmax in one pass.
@@ -722,12 +725,21 @@ def _paged_decode_kernel(
     the query heads' width exists. Scores are lane reductions kept as ``[..,
     Hkv, 1]`` columns, the shape the value product broadcasts from. The ``G``
     heads are unrolled: their chains interleave, and rolled into a loop the
-    kernel ran 2.4 times longer (my chip runs, PR 26)."""
+    kernel ran 2.4 times longer (my chip runs, PR 26).
+
+    With a ``window`` a query sees the last ``window`` positions only, itself
+    among them (``kv_len - window <= pos < kv_len``): the walk starts at block
+    ``first = max(0, kv_len - window) // block_size`` (the wrapper's ``walk``
+    begins there), so the blocks wholly behind the window are never fetched,
+    and the head of block ``first`` is masked like the tail of the last."""
     from jax.experimental import pallas as pl  # deferred with pallas_call's
 
+    i = pl.program_id(0)
+    if window is not None:
+        first_ref, *refs = refs
+    q_ref, *refs = refs
     k_refs, v_refs = refs[:group], refs[group : 2 * group]
     o_ref, acc_ref, m_ref, l_ref = refs[2 * group :]
-    i = pl.program_id(0)
     g = group_ref[i]
     kv_len = lens_ref[row_ref[i]]
 
@@ -740,10 +752,19 @@ def _paged_decode_kernel(
     q = q_ref[0].astype(jnp.float32) * scale             # [G, Hkv, D]
     k = jnp.concatenate([r[0] for r in k_refs]).astype(jnp.float32)  # [N*bs, Hkv, D]
     v = jnp.concatenate([r[0] for r in v_refs]).astype(jnp.float32)
-    pos = g * group * block_size + jax.lax.broadcasted_iota(
+    # the walk begins at position 0, with a window at the row's block `first`
+    walk_start = 0 if window is None else first_ref[row_ref[i]] * block_size
+    start = g * group * block_size
+    if window is not None:
+        start = walk_start + start
+    pos = start + jax.lax.broadcasted_iota(
         jnp.int32, (k.shape[0], k.shape[1], 1), 0
     )
-    live = pos < kv_len  # true at the step's first position: `m_new` is finite
+    # true somewhere in a row's first step (at its first position without a
+    # window, at `kv_len - window` with one): `m_new` is finite
+    live = pos < kv_len
+    if window is not None:
+        live = live & (pos >= kv_len - window)
     for h in range(q.shape[0]):  # one query head of every KV head
         # s[t, kv] = q[h, kv] . k[t, kv]: multiply-reduce over the lanes
         s = jnp.sum(q[h] * k, axis=-1, keepdims=True)    # [N*bs, Hkv, 1]
@@ -756,13 +777,17 @@ def _paged_decode_kernel(
         acc_ref[h] = acc_ref[h] * alpha + jnp.sum(p * v, axis=0)
         m_ref[h] = m_new
 
-    @pl.when((g + 1) * group * block_size >= kv_len)  # the row's last step
+    end = (g + 1) * group * block_size
+    if window is not None:
+        end = walk_start + end
+
+    @pl.when(end >= kv_len)  # the row's last step
     def _finalize():
         o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
 def paged_attention_decode(
-    q, k_pool, v_pool, block_tables, kv_lens, scale=None, *, interpret=False
+    q, k_pool, v_pool, block_tables, kv_lens, scale=None, *, window=None, interpret=False
 ):
     """Pallas paged flash-attention decode: q ``[B, 1, H, D]`` against
     per-layer pools ``[num_blocks, block_size, Hkv, D]`` through
@@ -778,6 +803,10 @@ def paged_attention_decode(
     for 64 rows holding 3000 live blocks of a 64 x 144 table (8.1-8.2 ms on
     the ``(B, W)`` one-block grid this replaced), 3.5 ms with the table full,
     whose 604 MB need 0.74 ms: 0.38 us a block is VPU arithmetic.
+    A static ``window`` (None: the program as it was, named ``paged_decode``)
+    makes a row see its last ``window`` positions only and walk only the blocks
+    that hold them, under the name ``paged_decode_win``, so that a trace tells
+    a model's window layers from its full ones.
     ``interpret=True`` runs the identical kernel through the Pallas
     interpreter (the CPU parity path in tier-1 CI)."""
     from jax.experimental import pallas as pl_  # deferred: CPU-only installs
@@ -799,11 +828,15 @@ def paged_attention_decode(
     # layer of a step). A longer kv_len than the table holds reads the table.
     kv_lens = jnp.minimum(jnp.asarray(kv_lens, jnp.int32).reshape(B), W * block_size)
     live = jnp.maximum(pl_.cdiv(kv_lens, block_size), 1)  # table entries, >= 1
-    walk = jnp.take_along_axis(
-        block_tables.astype(jnp.int32),
-        jnp.minimum(jnp.arange(steps * N, dtype=jnp.int32), live[:, None] - 1),
-        axis=1,
-    )
+    entry = jnp.minimum(jnp.arange(steps * N, dtype=jnp.int32), live[:, None] - 1)
+    first = ()
+    if window is not None:  # the walk starts at the block that holds `kv_len - window`
+        first_block = jnp.maximum(kv_lens - int(window), 0) // block_size
+        live = live - first_block
+        entry = first_block[:, None] + jnp.minimum(
+            jnp.arange(steps * N, dtype=jnp.int32), live[:, None] - 1)
+        first = (first_block,)
+    walk = jnp.take_along_axis(block_tables.astype(jnp.int32), entry, axis=1)
     ends = jnp.cumsum(pl_.cdiv(live, N))  # grid steps up to and with row b
     step = jnp.arange(B * steps, dtype=jnp.int32)
     done = step[:, None] >= ends  # [B*steps, B]: row b ends before this step
@@ -814,14 +847,14 @@ def paged_attention_decode(
     def pool_block(j):
         return pl_.BlockSpec(
             (1, block_size, Hkv, D),
-            lambda i, walk, lens, row, group: (walk[row[i], group[i] * N + j], 0, 0, 0),
+            lambda i, walk, lens, row, group, *_: (walk[row[i], group[i] * N + j], 0, 0, 0),
         )
 
     row = pl_.BlockSpec(
-        (1, G, Hkv, D), lambda i, walk, lens, row, group: (row[i], 0, 0, 0)
+        (1, G, Hkv, D), lambda i, walk, lens, row, group, *_: (row[i], 0, 0, 0)
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=4 + len(first),
         grid=(ends[-1],),
         in_specs=[row] + 2 * [pool_block(j) for j in range(N)],
         out_specs=row,
@@ -832,19 +865,21 @@ def paged_attention_decode(
         ],
     )
     kernel = partial(
-        _paged_decode_kernel, block_size=block_size, group=N, scale=sm_scale
+        _paged_decode_kernel, block_size=block_size, group=N, scale=sm_scale,
+        window=None if window is None else int(window),
     )
     out = pl_.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, G, Hkv, D), q.dtype),
         interpret=interpret,
-        name="paged_decode",
+        name="paged_decode" if window is None else "paged_decode_win",
     )(
         walk,
         kv_lens,
         step_row,
         step_group,
+        *first,
         # query head h = kv_head * G + g: [B, Hkv, G, D] -> group major
         q.reshape(B, Hkv, G, D).transpose(0, 2, 1, 3),
         *(N * [k_pool] + N * [v_pool]),
@@ -855,19 +890,22 @@ def paged_attention_decode(
 
 def _paged_prefill_kernel(
     tables_ref,  # [B, W] int32 scalar-prefetch (drives the k/v index maps)
-    qpos_ref,    # [1, Sq, 1] int32 VMEM: absolute position of each query (a
-                 # column: SMEM scalar-prefetch operands only yield scalars)
-    q_ref,       # [1, Sq, H, D]            this row's tile of the query chunk
-    k_ref,       # [1, block_size, Hkv, D]  the block the index map selected
-    v_ref,       # [1, block_size, Hkv, D]
-    o_ref,       # [1, Sq, H, D]
-    acc_ref,     # VMEM [H, Sq, D] f32  online-softmax accumulators,
-    m_ref,       # VMEM [H, Sq, 1] f32  carried across the W grid steps
-    l_ref,       # VMEM [H, Sq, 1] f32
-    *,
+    *refs,       # with a window first_ref [B, S/Sq] int32 scalar-prefetch, the
+                 # block a query tile's walk starts at; then:
+                 # qpos_ref [1, Sq, 1] int32 VMEM: absolute position of each
+                 #   query (a column: SMEM scalar-prefetch operands only yield
+                 #   scalars)
+                 # q_ref [1, Sq, H, D]            this row's tile of the query chunk
+                 # k_ref [1, block_size, Hkv, D]  the block the index map selected
+                 # v_ref [1, block_size, Hkv, D]
+                 # o_ref [1, Sq, H, D]
+                 # acc_ref VMEM [H, Sq, D] f32  online-softmax accumulators,
+                 # m_ref   VMEM [H, Sq, 1] f32  carried across the W grid steps
+                 # l_ref   VMEM [H, Sq, 1] f32
     block_size: int,
     groups: int,
     scale: float,
+    window: Optional[int] = None,
 ):
     """One (row, query-tile, logical-block) grid step of paged chunked-prefill
     attention.
@@ -883,9 +921,18 @@ def _paged_prefill_kernel(
     position — old blocks and the chunk's own tokens alike — is live in the
     walked blocks, and masking ``kv_pos <= q_position`` per query reproduces
     the gather reference exactly (null-padded table entries sit at positions
-    past every query and are silenced by the same predicate)."""
+    past every query and are silenced by the same predicate).
+
+    With a ``window`` a query at position ``p`` sees ``p - window < kv_pos <=
+    p``. The blocks wholly behind the window of the tile's first query are
+    not walked: for them the index maps repeat block ``first`` (a repeated
+    index elides the DMA) and the fold is skipped; the head of block
+    ``first`` and every later query's own edge are masked."""
     from jax.experimental import pallas as pl  # deferred with pallas_call's
 
+    if window is not None:
+        first_ref, *refs = refs
+    qpos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
     w = pl.program_id(2)
 
     @pl.when(w == 0)
@@ -894,6 +941,21 @@ def _paged_prefill_kernel(
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
+    if window is None:
+        _paged_prefill_fold(w, *refs, block_size=block_size, groups=groups, scale=scale)
+    else:
+        pl.when(w >= first_ref[pl.program_id(0), pl.program_id(1)])(partial(
+            _paged_prefill_fold, w, *refs, block_size=block_size, groups=groups,
+            scale=scale, window=window))
+
+    @pl.when(w == pl.num_programs(2) - 1)
+    def _finalize():
+        o_ref[0] = (acc_ref[...] / l_ref[...]).transpose(1, 0, 2).astype(o_ref.dtype)
+
+
+def _paged_prefill_fold(w, qpos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
+                        *, block_size, groups, scale, window=None):
+    """Fold logical block ``w`` into a query tile's online softmax."""
     q = q_ref[0].astype(jnp.float32) * scale           # [S, H, D]
     k = k_ref[0].astype(jnp.float32)                   # [bs, Hkv, D]
     v = v_ref[0].astype(jnp.float32)
@@ -910,7 +972,10 @@ def _paged_prefill_kernel(
         qh, kh, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
     )                                                  # [H, S, bs]
     pos = w * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    s = jnp.where(pos <= qpos_ref[...], s, -jnp.inf)
+    seen = pos <= qpos_ref[...]
+    if window is not None:
+        seen = seen & (pos > qpos_ref[...] - window)
+    s = jnp.where(seen, s, -jnp.inf)
 
     m_prev, l_prev = m_ref[...], l_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))  # [H, S, 1]
@@ -925,10 +990,6 @@ def _paged_prefill_kernel(
     )
     m_ref[...] = m_new
     l_ref[...] = l_new
-
-    @pl.when(w == pl.num_programs(2) - 1)
-    def _finalize():
-        o_ref[0] = (acc_ref[...] / l_ref[...]).transpose(1, 0, 2).astype(o_ref.dtype)
 
 
 # One prefill program holds its query tile for ALL heads: q/o tiles (double
@@ -956,7 +1017,7 @@ def _prefill_query_tile(S: int, H: int, D: int) -> int:
 
 
 def paged_attention_prefill(
-    q, k_pool, v_pool, block_tables, q_positions, scale=None, *, interpret=False
+    q, k_pool, v_pool, block_tables, q_positions, scale=None, *, window=None, interpret=False
 ):
     """Pallas paged chunked-prefill attention: q ``[B, S, H, D]`` (``S > 1``)
     against per-layer pools ``[num_blocks, block_size, Hkv, D]`` through
@@ -967,7 +1028,11 @@ def paged_attention_prefill(
     per-query position mask is what makes the online softmax match the
     gather reference's causal masking bit for bit. The gathered
     ``[B, W*block_size]`` cache the XLA reference materializes per layer
-    never exists. ``interpret=True`` runs the identical kernel through the
+    never exists. A static ``window`` (None: the program as it was, named
+    ``paged_prefill``) makes a query see its last ``window`` positions only,
+    itself among them; a query tile then skips the blocks wholly behind its
+    first query's window, under the name ``paged_prefill_win``.
+    ``interpret=True`` runs the identical kernel through the
     Pallas interpreter (the CPU parity path in tier-1 CI)."""
     from jax.experimental import pallas as pl_  # deferred: CPU-only installs
     from jax.experimental.pallas import tpu as pltpu
@@ -981,23 +1046,30 @@ def paged_attention_prefill(
         raise ValueError(f"q heads {H} not a multiple of kv heads {Hkv}")
     sm_scale = (1.0 / math.sqrt(D)) if scale is None else float(scale)
     Sq = _prefill_query_tile(S, H, D)
+    q_positions = jnp.asarray(q_positions, jnp.int32)
+
+    if window is None:
+        first = ()
+
+        def pool_block(b, i, w, tables):
+            return (tables[b, w], 0, 0, 0)
+    else:  # a tile's walk starts at the block its first query's window starts in
+        tile_first = jnp.min(q_positions.reshape(B, S // Sq, Sq), axis=2)
+        first = (jnp.clip(tile_first - int(window) + 1, 0, W * block_size - 1) // block_size,)
+
+        def pool_block(b, i, w, tables, first):
+            return (tables[b, jnp.maximum(w, first[b, i])], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,  # block tables
+        num_scalar_prefetch=1 + len(first),  # block tables, a window's first blocks
         grid=(B, S // Sq, W),
         in_specs=[
-            pl_.BlockSpec((1, Sq, 1), lambda b, i, w, tables: (b, i, 0)),
-            pl_.BlockSpec((1, Sq, H, D), lambda b, i, w, tables: (b, i, 0, 0)),
-            pl_.BlockSpec(
-                (1, block_size, Hkv, D),
-                lambda b, i, w, tables: (tables[b, w], 0, 0, 0),
-            ),
-            pl_.BlockSpec(
-                (1, block_size, Hkv, D),
-                lambda b, i, w, tables: (tables[b, w], 0, 0, 0),
-            ),
+            pl_.BlockSpec((1, Sq, 1), lambda b, i, w, *_: (b, i, 0)),
+            pl_.BlockSpec((1, Sq, H, D), lambda b, i, w, *_: (b, i, 0, 0)),
+            pl_.BlockSpec((1, block_size, Hkv, D), pool_block),
+            pl_.BlockSpec((1, block_size, Hkv, D), pool_block),
         ],
-        out_specs=pl_.BlockSpec((1, Sq, H, D), lambda b, i, w, tables: (b, i, 0, 0)),
+        out_specs=pl_.BlockSpec((1, Sq, H, D), lambda b, i, w, *_: (b, i, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((H, Sq, D), jnp.float32),
             pltpu.VMEM((H, Sq, 1), jnp.float32),
@@ -1009,23 +1081,25 @@ def paged_attention_prefill(
         block_size=block_size,
         groups=H // Hkv,
         scale=sm_scale,
+        window=None if window is None else int(window),
     )
     return pl_.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, S, H, D), q.dtype),
         interpret=interpret,
-        name="paged_prefill",
+        name="paged_prefill" if window is None else "paged_prefill_win",
     )(
         block_tables.astype(jnp.int32),
-        jnp.asarray(q_positions, jnp.int32).reshape(B, S, 1),
+        *first,
+        q_positions.reshape(B, S, 1),
         q,
         k_pool,
         v_pool,
     )
 
 
-def paged_attention(q, k_pool, v_pool, block_tables, q_positions, scale=None):
+def paged_attention(q, k_pool, v_pool, block_tables, q_positions, scale=None, window=None):
     """Paged attention for the serving engine (kernel dispatch point).
 
     q ``[B, S, H, D]``; per-layer pools ``[num_blocks, block_size, Hkv, D]``;
@@ -1042,20 +1116,23 @@ def paged_attention(q, k_pool, v_pool, block_tables, q_positions, scale=None):
     :func:`flash_attention`'s pallas-vs-xla split.
     ``ACCELERATE_PAGED_KERNEL=interpret`` forces the kernels (interpreter
     mode) on any backend so CPU CI can drive the kernel dataflow through
-    the full engine."""
+    the full engine. A static ``window`` (a sliding-window layer: a query at
+    position ``p`` sees ``p - window < kv_pos <= p``) is the same predicate on
+    all three paths; None is full causal attention, every program as it was."""
     mode = paged_kernel_mode()
     if mode != "off":
         interpret = mode == "interpret"
         if interpret or jax.default_backend() == "tpu":
+            windowed = {} if window is None else {"window": window}  # None: the call as it was
             if q.shape[1] == 1:
                 return paged_attention_decode(
                     q, k_pool, v_pool, block_tables, q_positions[:, 0] + 1,
-                    scale, interpret=interpret,
+                    scale, interpret=interpret, **windowed,
                 )
             return paged_attention_prefill(
                 q, k_pool, v_pool, block_tables, q_positions,
-                scale, interpret=interpret,
+                scale, interpret=interpret, **windowed,
             )
     from ..serving.kv_pager import paged_attention as _xla_paged
 
-    return _xla_paged(q, k_pool, v_pool, block_tables, q_positions, scale)
+    return _xla_paged(q, k_pool, v_pool, block_tables, q_positions, scale, window)

@@ -1,5 +1,12 @@
 from .long_context import make_context_parallel_attention, sequence_parallel_attention
-from .moe import init_moe_ffn, moe_ffn, moe_shard_rules
+from .moe import (
+    held_expert_ffn,
+    init_held_experts,
+    init_moe_ffn,
+    moe_ffn,
+    moe_shard_rules,
+    route_top_k,
+)
 from .pipeline import (
     make_pipeline_forward,
     make_pipeline_train_step_1f1b,

@@ -145,3 +145,88 @@ def moe_ffn(
     aux_loss = E * jnp.sum(fraction * mean_prob)
 
     return y_grp.reshape(B, S, D).astype(x.dtype), aux_loss
+
+
+# ---------------------------------------------------------------------------
+# Routing without dropped tokens over the experts held here
+
+
+def init_held_experts(key, d_model: int, d_ff: int, num_experts: int, held: int):
+    """Params of :func:`held_expert_ffn` (float32): a router over all
+    ``num_experts`` and the gated MLP stacks of the ``held`` experts that live
+    here."""
+    import jax
+
+    k_r, k_g, k_u, k_d = jax.random.split(key, 4)
+
+    def normal(k, shape, fan_in):
+        return jax.random.normal(k, shape) / np.sqrt(fan_in)
+
+    return {
+        "router": {"kernel": normal(k_r, (d_model, num_experts), d_model)},
+        "w_gate": {"kernel": normal(k_g, (held, d_model, d_ff), d_model)},
+        "w_up": {"kernel": normal(k_u, (held, d_model, d_ff), d_model)},
+        "w_down": {"kernel": normal(k_d, (held, d_ff, d_model), d_ff)},
+    }
+
+
+def route_top_k(router_kernel, x, top_k: int):
+    """Sigmoid scores over every expert, the ``top_k`` largest, their weights
+    normalised to sum to 1: ``(expert ids [N, k], weights [N, k])``. The
+    arithmetic is float32 (a TPU's default matmul precision is not): the gap
+    between the k-th and the next score is small against bf16 rounding."""
+    import jax
+    import jax.numpy as jnp
+
+    logits = jnp.dot(x.astype(jnp.float32), router_kernel.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores, experts = jax.lax.top_k(jax.nn.sigmoid(logits), top_k)
+    return experts, scores / jnp.sum(scores, axis=-1, keepdims=True)
+
+
+def held_expert_ffn(params, x, *, top_k: int, first_expert: int = 0, valid=None):
+    """The routed experts' part of an expert layer that this chip computes:
+    ``x [..., D] -> (y [..., D], counts [3])``.
+
+    The router scores all ``E = router.shape[-1]`` experts and picks ``top_k``
+    a token (:func:`route_top_k`); the ``held = w_gate.shape[0]`` experts
+    ``first_expert .. first_expert + held`` live here. The (token, expert)
+    pairs that land on them are sorted by expert and go through one grouped
+    matmul a weight stack (:func:`accelerate_tpu.ops.grouped_matmul.
+    grouped_matmul`): ``E_i(x) = (silu(x Wg_i) * (x Wu_i)) Wd_i``, and ``y =
+    sum of w_i E_i(x)`` over the chosen experts held here. Nothing is dropped
+    and nothing stands in for the experts held elsewhere: with every expert
+    held (``first_expert`` 0, ``held == E``) ``y`` is the whole layer, else
+    its share, and the shares of all the chips add up to the whole.
+
+    ``valid [...]`` (bool) marks the real tokens of a padded batch: the others
+    are routed nowhere. ``counts`` is int32 ``(local_pairs, experts_hit,
+    max_expert_load)``: the pairs that landed here, the held experts that got
+    at least one, the most any of them got."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops.grouped_matmul import grouped_matmul
+
+    lead, D = x.shape[:-1], x.shape[-1]
+    x2 = x.reshape(-1, D)
+    held = params["w_gate"]["kernel"].shape[0]
+    experts, weights = route_top_k(params["router"]["kernel"], x2, top_k)
+    local = (experts >= first_expert) & (experts < first_expert + held)
+    if valid is not None:
+        local = local & valid.reshape(-1, 1)
+    # pairs by expert, those of experts held elsewhere behind them all
+    key = jnp.where(local, experts - first_expert, held).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    token_of = order // top_k
+    group_sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+    rows = x2[token_of]
+    hidden = jax.nn.silu(grouped_matmul(rows, params["w_gate"]["kernel"], group_sizes)) * (
+        grouped_matmul(rows, params["w_up"]["kernel"], group_sizes))
+    out = grouped_matmul(hidden, params["w_down"]["kernel"], group_sizes)
+    # the rows behind the groups hold nothing defined: they take weight 0, and are masked
+    weight = jnp.where(local, weights, 0.0).reshape(-1)[order]
+    out = jnp.where(weight[:, None] > 0, out.astype(jnp.float32) * weight[:, None], 0.0)
+    y = jnp.zeros((x2.shape[0], D), jnp.float32).at[token_of].add(out)
+    counts = jnp.stack([jnp.sum(group_sizes), jnp.sum(group_sizes > 0), jnp.max(group_sizes)])
+    return y.astype(x.dtype).reshape(*lead, D), counts.astype(jnp.int32)

@@ -107,6 +107,30 @@ def _paged_layer_step(layer_params, h, k_pool, v_pool, block_tables, positions,
     return h + y, k_pool, v_pool
 
 
+def model_paged_forward(config, block_size: int):
+    """The paged forward of the model ``config`` describes, as the engine's
+    step programs call it: ``fn(params, ids [B, S], pool, block_tables,
+    positions [B, S], valid [B, S]) -> (logits, new pool, counts)``. The
+    config's type selects: a ``LlamaConfig`` gets :func:`paged_forward` (it
+    takes no notice of ``valid`` and counts nothing: None); any other model
+    brings its own as ``config.paged_forward`` (``models/cohere2_moe.py``),
+    whose ``counts`` is a small integer array the engine fetches with the
+    step's tokens and records (:meth:`ServingEngine._record_counts`)."""
+    if isinstance(config, LlamaConfig):
+        def forward(params, ids, pool, block_tables, positions, valid):
+            logits, pool = paged_forward(
+                params, ids, pool, block_tables, positions, config, block_size)
+            return logits, pool, None
+
+        return forward
+    if not callable(getattr(config, "paged_forward", None)):
+        raise TypeError(
+            f"ServingEngine cannot serve a {type(config).__name__}: it is no LlamaConfig "
+            "and has no paged_forward(params, ids, pool, block_tables, positions, valid, "
+            "block_size)")
+    return partial(config.paged_forward, block_size=block_size)
+
+
 def paged_forward(params, ids, pool, block_tables, positions, config: LlamaConfig,
                   block_size: int):
     """Forward ``ids [B, S]`` at per-row ``positions [B, S]`` against the
@@ -157,12 +181,19 @@ class ServingEngine:
     stays bitwise-identical to non-speculative decode while a good draft
     collapses up to k+1 tokens into one model step (see
     ``docs/serving.md``).
+
+    ``config`` describes the model: the engine reads its ``n_layers``,
+    ``n_kv_heads``, ``head_dim`` (the pool's shape), ``max_seq_len`` and, where
+    it has one, ``sliding_window``, and runs its paged forward
+    (:func:`model_paged_forward`: the config's type selects). Speculative
+    decoding drafts with a ``LlamaConfig``'s own first layers and refuses
+    another model.
     """
 
     def __init__(
         self,
         params,
-        config: LlamaConfig,
+        config,
         *,
         num_blocks: int = 64,
         block_size: int = 16,
@@ -185,6 +216,9 @@ class ServingEngine:
     ):
         self.params = params
         self.config = config
+        forward = model_paged_forward(config, block_size)  # the config's type selects
+        #: a window layer of the model reads the last `window` positions only
+        self.window = getattr(config, "sliding_window", None)
         self.block_size = block_size
         self.max_slots = max_slots
         self.mesh = mesh
@@ -198,6 +232,10 @@ class ServingEngine:
             raise ValueError(f"spec_tokens must be >= 0, got {spec_tokens}")
         if self.spec_tokens > 0 and draft_layers is None:
             raise ValueError("spec_tokens > 0 requires draft_layers (the self-draft depth)")
+        if self.spec_tokens > 0 and not isinstance(config, LlamaConfig):
+            raise TypeError(
+                f"speculative decoding drafts with a LlamaConfig's first layers; "
+                f"a {type(config).__name__} has no draft")
         # watchdog heartbeat source for the decode loop: a hang inside a
         # batched decode produces a stall dump naming this engine (replicas
         # suffix their name so a stuck replica is attributable)
@@ -251,21 +289,21 @@ class ServingEngine:
             # meaningful only for the final chunk (last_idx = last real row)
             B, Sb = ids.shape
             positions = start + jnp.broadcast_to(jnp.arange(Sb)[None], (B, Sb))
-            logits, pool = paged_forward(
-                params, ids, pool, table, positions, config, block_size
-            )
+            # the chunk's real tokens; the bucket's padding lies behind `last_idx`
+            valid = jnp.broadcast_to(jnp.arange(Sb)[None] <= last_idx, (B, Sb))
+            logits, pool, counts = forward(params, ids, pool, table, positions, valid)
             last = jax.lax.dynamic_index_in_dim(logits, last_idx, axis=1, keepdims=False)
             tok = select_one(last[0], jax.random.fold_in(key, token_idx))
-            return pool, tok.astype(jnp.int32)
+            return pool, (tok.astype(jnp.int32), counts)
 
         def _decode(params, pool, last_tok, tables, positions, keys, token_idx):
-            logits, pool = paged_forward(
-                params, last_tok[:, None], pool, tables, positions[:, None],
-                config, block_size,
-            )
+            # an idle slot's table is all null; a live row's first block never is
+            valid = tables[:, :1] != NULL_BLOCK
+            logits, pool, counts = forward(
+                params, last_tok[:, None], pool, tables, positions[:, None], valid)
             folded = jax.vmap(jax.random.fold_in)(keys, token_idx)
             tok = jax.vmap(select_one)(logits[:, -1], folded)
-            return pool, tok.astype(jnp.int32)
+            return pool, (tok.astype(jnp.int32), counts)
 
         def _cow(pool, src, dst):
             # copy-on-write for the aligned prefix-cache edge case: duplicate
@@ -371,6 +409,13 @@ class ServingEngine:
         #: kernel's work) and entries of the bucketed tables they were handed
         self.decode_blocks_live = 0
         self.decode_blocks_walked = 0
+        #: of the live ones, the blocks a window layer walked (a model with a window)
+        self.decode_blocks_window = 0
+        #: a routed model's own counts, summed over its calls and layers
+        #: (:meth:`_record_counts`; `moe_*` in :meth:`stats`): real tokens
+        #: through the model, (token, expert) pairs on the experts held here,
+        #: held experts hit, layer calls, the most pairs one expert got in a call
+        self.moe = dict(tokens=0, local_pairs=0, experts_hit=0, calls=0, max_expert_load=0)
         #: speculative decoding: draft tokens proposed / accepted, and the
         #: accepted-per-step histogram (index = draft tokens accepted that
         #: slot-step, 0..k) the report's serving section renders
@@ -473,7 +518,7 @@ class ServingEngine:
                 if executable is not None:
                     self._aot[("prefill", Sb, W)] = executable
                     continue
-            self.pool, tok = self.prefill_fn(*args)
+            self.pool, _ = self.prefill_fn(*args)
         for Bb, W in self.lattice.decode_points():
             last = np.zeros((Bb,), np.int32)
             tables = np.full((Bb, W), NULL_BLOCK, np.int32)
@@ -490,7 +535,7 @@ class ServingEngine:
                 if executable is not None:
                     self._aot[("decode", Bb, W)] = executable
                     continue
-            self.pool, tok = self.decode_fn(*args)
+            self.pool, _ = self.decode_fn(*args)
         if self.spec_tokens > 0:
             # the draft + k-verify families: one point per decode point each
             # (verify's S=k+1 width is static, so it is one extra warmed
@@ -887,6 +932,7 @@ class ServingEngine:
                 self.preempt_prefill_tokens += int(prefix.size) - start
             elif req.generated:
                 self.resume_prefill_tokens += int(prefix.size) - start
+            chunk_counts = []  # a model's own counts, one a chunk: [(tokens, counts)]
             while start < prefix.size:
                 chunk = prefix[start : start + chunk_cap]
                 Sb = self.lattice.prefill_bucket(chunk.size)
@@ -894,10 +940,11 @@ class ServingEngine:
                 ids[0, : chunk.size] = chunk
                 chunk_t0 = _tracing.now_ns() if span_prefill is not None else 0
                 fn = self._aot.get(("prefill", Sb, W), self.prefill_fn)
-                self.pool, tok = fn(
+                self.pool, (tok, counts) = fn(
                     self.params, self.pool, ids, table, np.int32(start),
                     np.int32(chunk.size - 1), key, token_idx,
                 )
+                chunk_counts.append((int(chunk.size), counts))
                 if span_prefill is not None:
                     req.trace_spans.append(_tracing.make_span(
                         req.trace, "prefill_chunk", chunk_t0, _tracing.now_ns(),
@@ -907,6 +954,8 @@ class ServingEngine:
                 start += chunk.size
             tok = int(tok)  # the sync: the sampled token is on the host from here
             t_ns, t = self._clock()
+            for tokens, counts in chunk_counts:  # computed by now: no wait
+                self._record_counts("prefill", tokens, counts, t_ns, rid=int(req.rid))
             if req.first_token_t is None:
                 req.first_token_t = t
             if span_prefill is not None:
@@ -914,24 +963,52 @@ class ServingEngine:
         req.generated.append(tok)
         self.prefill_calls += 1
 
-    def _decode_bucket(self, running: "list[Request]") -> "tuple[int, int, int]":
+    def _decode_bucket(self, running: "list[Request]") -> "tuple[int, int, dict]":
         """The lattice point of a decode batch and the blocks its rows hold:
-        ``(slot_bucket, block_bucket, live_blocks)``. The paged decode kernel's
-        work follows ``live_blocks``; ``slot_bucket * block_bucket`` is the
-        table it is handed (``decode_blocks_live`` / ``decode_blocks_walked``
-        in :meth:`stats`)."""
+        ``(slot_bucket, block_bucket, {"live_blocks": ...})``. The paged decode
+        kernel's work follows ``live_blocks``; ``slot_bucket * block_bucket``
+        is the table it is handed (``decode_blocks_live`` /
+        ``decode_blocks_walked`` in :meth:`stats`). For a model with window
+        layers the dict also holds ``window_blocks``: the blocks such a layer
+        walks, a row's from the one that holds position ``kv_len - window``
+        (``decode_blocks_window`` in :meth:`stats`)."""
         blocks = [self.allocator.num_seq_blocks(r.rid) for r in running]
         Bb = self.lattice.slot_bucket(len(running))
         W = self.lattice.block_bucket(max(blocks))
-        live = sum(blocks)
-        self.decode_blocks_live += live
+        held = {"live_blocks": sum(blocks)}
+        self.decode_blocks_live += held["live_blocks"]
         self.decode_blocks_walked += Bb * W
-        return Bb, W, live
+        if self.window is not None:
+            held["window_blocks"] = sum(
+                n - max(r.prefix_len - self.window, 0) // self.block_size
+                for n, r in zip(blocks, running))
+            self.decode_blocks_window += held["window_blocks"]
+        return Bb, W, held
+
+    def _record_counts(self, kind: str, tokens: int, counts, t_ns: int, **key) -> None:
+        """One ``atpu.serve.moe`` record for one call of a model whose paged
+        forward counts its routing (``counts [n_layers, 3]``, fetched with the
+        call's tokens): per layer the (token, expert) pairs that landed on the
+        experts held here, the held experts hit, and the most one of them
+        got, for the ``tokens`` real tokens of a ``decode`` batch or a
+        ``prefill`` chunk. Nothing for a model that counts nothing."""
+        if counts is None:
+            return
+        counts = np.asarray(counts)
+        pairs, hit, load = (counts[:, i].tolist() for i in range(3))
+        _tracing.record(
+            "atpu.serve.moe", t_ns, t_ns, engine=self.engine_id, step=self.steps, kind=kind,
+            tokens=tokens, local_pairs=pairs, experts_hit=hit, max_expert_load=load, **key)
+        self.moe["tokens"] += tokens
+        self.moe["local_pairs"] += sum(pairs)
+        self.moe["experts_hit"] += sum(hit)
+        self.moe["calls"] += len(pairs)
+        self.moe["max_expert_load"] = max(self.moe["max_expert_load"], *load)
 
     def _decode_batch(self, running: "list[Request]", finished: "list[Request]") -> None:
-        Bb, W, live = self._decode_bucket(running)
+        Bb, W, held = self._decode_bucket(running)
         with self._phase(
-            "build", batch=len(running), slot_bucket=Bb, block_bucket=W, live_blocks=live
+            "build", batch=len(running), slot_bucket=Bb, block_bucket=W, **held
         ):
             last = np.zeros((Bb,), np.int32)
             tables = np.full((Bb, W), NULL_BLOCK, np.int32)
@@ -957,12 +1034,14 @@ class ServingEngine:
         )
         fn = self._aot.get(("decode", Bb, W), self.decode_fn)
         with self._phase("dispatch"):
-            self.pool, toks = fn(
+            self.pool, out = fn(
                 self.params, self.pool, last, tables, positions, keys, token_idx
             )
         with self._phase("fetch"):  # the host waits for the device here
-            toks = np.asarray(jax.device_get(toks))
+            toks, counts = jax.device_get(out)  # the step's one fetch
+            toks = np.asarray(toks)
         with self._phase("emit"):
+            self._record_counts("decode", len(running), counts, _tracing.now_ns())
             if decode_t0:
                 decode_t1 = _tracing.now_ns()
                 for req in running:
@@ -1004,9 +1083,9 @@ class ServingEngine:
         prefix and are position-masked out of every read until the next
         step's scatter overwrites them."""
         k = self.spec_tokens
-        Bb, W, live = self._decode_bucket(running)
+        Bb, W, held = self._decode_bucket(running)
         with self._phase(
-            "build", batch=len(running), slot_bucket=Bb, block_bucket=W, live_blocks=live
+            "build", batch=len(running), slot_bucket=Bb, block_bucket=W, **held
         ):
             last = np.zeros((Bb,), np.int32)
             tables = np.full((Bb, W), NULL_BLOCK, np.int32)
@@ -1180,6 +1259,10 @@ class ServingEngine:
             **self.jit_cache_sizes(),
             **self.allocator.stats(),
         }
+        if self.window is not None:
+            out["decode_blocks_window"] = self.decode_blocks_window
+        if self.moe["calls"]:
+            out.update({"moe_" + name: total for name, total in self.moe.items()})
         if self.spec_tokens > 0:
             out.update(
                 spec_tokens=self.spec_tokens,

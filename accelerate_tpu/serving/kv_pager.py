@@ -59,7 +59,6 @@ import numpy as np
 import jax.numpy as jnp
 
 from ..generation import _masked_attention
-from ..models.transformer import LlamaConfig
 from ..telemetry import metrics as _metrics
 
 __all__ = [
@@ -85,11 +84,12 @@ class BlockPoolExhausted(RuntimeError):
     """No free block available — the scheduler should preempt or defer."""
 
 
-def init_block_pool(
-    config: LlamaConfig, num_blocks: int, block_size: int, dtype=jnp.bfloat16
-) -> dict:
+def init_block_pool(config, num_blocks: int, block_size: int, dtype=jnp.bfloat16) -> dict:
     """Device pool ``{"k","v"}: [L, num_blocks, block_size, Hkv, D]``
-    (``num_blocks`` INCLUDES the reserved null block 0)."""
+    (``num_blocks`` INCLUDES the reserved null block 0). ``config`` is any
+    model description with ``n_layers``, ``n_kv_heads`` and ``head_dim``; every
+    layer gets the same blocks, whatever its kind (a window layer keeps what
+    lies behind its window: an allocator by layer kind is ROADMAP B-m2's)."""
     shape = (config.n_layers, num_blocks, block_size, config.n_kv_heads, config.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
@@ -571,7 +571,7 @@ class BlockAllocator:
         return out
 
 
-def paged_attention(q, k_pool, v_pool, block_tables, q_positions, scale=None):
+def paged_attention(q, k_pool, v_pool, block_tables, q_positions, scale=None, window=None):
     """Paged variant of ``generation._cached_attention``.
 
     q ``[B, S, H, D]``; per-layer pools ``[num_blocks, block_size, Hkv, D]``;
@@ -581,10 +581,14 @@ def paged_attention(q, k_pool, v_pool, block_tables, q_positions, scale=None):
     shared masked-attention core: a slot at gathered position ``t`` holds
     logical token ``t`` of that sequence, and only slots with ``t <=
     q_position`` are attended, so null/stale slots are masked to an exact
-    0 contribution (bitwise parity with the contiguous path)."""
+    0 contribution (bitwise parity with the contiguous path). With a static
+    ``window`` a query also sees nothing at or before ``q_position - window``
+    (a sliding-window layer; the CPU twin of the paged kernels' predicate)."""
     B = q.shape[0]
     k_cache = k_pool[block_tables].reshape(B, -1, k_pool.shape[2], k_pool.shape[3])
     v_cache = v_pool[block_tables].reshape(B, -1, v_pool.shape[2], v_pool.shape[3])
     kv_pos = jnp.arange(k_cache.shape[1])
     allow = kv_pos[None, None, :] <= q_positions[:, :, None]  # [B, S, T]
+    if window is not None:
+        allow = allow & (kv_pos[None, None, :] > q_positions[:, :, None] - window)
     return _masked_attention(q, k_cache, v_cache, allow[:, None], scale)

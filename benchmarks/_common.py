@@ -47,18 +47,25 @@ def detect_backend() -> bool:
     )
 
 
-def enable_jax_cache() -> str:
+def enable_jax_cache() -> "str | None":
     """Place JAX's persistent compilation cache and return its directory.
     Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX has taken it from the
-    environment and nothing is changed. Otherwise it is the one fixed,
-    git-ignored directory of this checkout: the path is part of the cache's
-    key, so a directory that moves (a temp dir, a pid, a time) never hits."""
+    environment and nothing is changed. Otherwise, on a chip, it is the one
+    fixed, git-ignored directory of this checkout: the path is part of the
+    cache's key, so a directory that moves (a temp dir, a pid, a time) never
+    hits. On the CPU no cache is placed and None comes back: a rehearsal
+    compiles in seconds, and entries an earlier process left there made the
+    serving benchmark's own compile counts (its ``zero_recompiles``) depend
+    on what had run before it."""
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if not path:
-        import jax
+    if path:
+        return path
+    import jax
 
-        path = os.path.join(REPO, ".jax_cache")
-        jax.config.update("jax_compilation_cache_dir", path)
+    if jax.default_backend() == "cpu":
+        return None
+    path = os.path.join(REPO, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
     return path
 
 

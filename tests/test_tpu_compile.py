@@ -126,3 +126,37 @@ def test_prefill_query_tile_stays_inside_scoped_vmem():
     assert fa._prefill_query_tile(4, 32, 128) == 4  # a verify step's k+1 tokens
     with pytest.raises(ValueError, match="cannot tile S=4099"):
         fa._prefill_query_tile(4099, 16, 64)  # prime: no multiple of 8 divides it
+
+
+# ---- the window kernels and the grouped matmul, at the widths of the routed
+# decoder's cell (128 query heads on 8 key heads of 128, blocks of 16, a table
+# of 400; experts of 4096 x 4096): its decode batch and its prefill chunks
+
+
+@pytest.mark.parametrize("window", [None, 4096], ids=["full", "window4096"])
+def test_paged_kernels_compile_at_128_query_heads_with_and_without_a_window(one_chip, window):
+    H, Hkv, D, bs, W = 128, 8, 128, 16, 400
+    pool = ((6401, bs, Hkv, D), BF16)
+    name = "paged_decode" + ("_win" if window else "")
+    text = _compile(
+        lambda q, k, v, t, n: fa.paged_attention_decode(q, k, v, t, n, window=window), one_chip,
+        ((32, 1, H, D), BF16), pool, pool, ((32, W), jnp.int32), ((32,), jnp.int32),
+    )
+    assert name in text and (window is not None or "paged_decode_win" not in text)
+    for chunk in (256, 512):
+        text = _compile(
+            lambda q, k, v, t, p: fa.paged_attention_prefill(q, k, v, t, p, window=window),
+            one_chip, ((1, chunk, H, D), BF16), pool, pool, ((1, W), jnp.int32),
+            ((1, chunk), jnp.int32),
+        )
+        assert "paged_prefill" + ("_win" if window else "") in text
+
+
+@pytest.mark.parametrize("rows", [256, 2048, 4096], ids=["decode32x8", "chunk256x8", "chunk512x8"])
+def test_grouped_matmul_compiles_for_v5e(one_chip, rows):
+    gm = importlib.import_module("accelerate_tpu.ops.grouped_matmul")
+    text = _compile(
+        gm.grouped_matmul_kernel, one_chip,
+        ((rows, 4096), BF16), ((16, 4096, 4096), BF16), ((16,), jnp.int32),
+    )
+    assert "moe_gmm" in text

@@ -291,7 +291,9 @@ def test_env_compile_cache_dir_wins_over_jit_config(monkeypatch, tmp_path):
 
 def test_measurement_scripts_share_one_fixed_cache_dir(monkeypatch, tmp_path):
     """benchmarks/_common.enable_jax_cache: the environment's directory where
-    it names one, else the fixed in-checkout path — never a temp dir."""
+    it names one, else on a chip the fixed in-checkout path — never a temp
+    dir — and on the CPU no cache at all (entries of an earlier test process
+    made the serving benchmark's compile counts depend on what ran before)."""
     import os
     import sys
 
@@ -307,6 +309,9 @@ def test_measurement_scripts_share_one_fixed_cache_dir(monkeypatch, tmp_path):
         assert enable_jax_cache() == str(tmp_path)
         assert jax.config.jax_compilation_cache_dir == before  # untouched
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_jax_cache() is None  # the tests run on the CPU
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         assert enable_jax_cache() == os.path.join(repo, ".jax_cache")
         assert jax.config.jax_compilation_cache_dir == os.path.join(repo, ".jax_cache")
     finally:
