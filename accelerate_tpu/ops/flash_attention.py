@@ -35,6 +35,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def flash_kernel_mode() -> str:
@@ -888,122 +889,39 @@ def paged_attention_decode(
     return out.transpose(0, 2, 1, 3).reshape(B, 1, H, D)
 
 
-def _paged_prefill_kernel(
-    tables_ref,  # [B, W] int32 scalar-prefetch (drives the k/v index maps)
-    *refs,       # with a window first_ref [B, S/Sq] int32 scalar-prefetch, the
-                 # block a query tile's walk starts at; then:
-                 # qpos_ref [1, Sq, 1] int32 VMEM: absolute position of each
-                 #   query (a column: SMEM scalar-prefetch operands only yield
-                 #   scalars)
-                 # q_ref [1, Sq, H, D]            this row's tile of the query chunk
-                 # k_ref [1, block_size, Hkv, D]  the block the index map selected
-                 # v_ref [1, block_size, Hkv, D]
-                 # o_ref [1, Sq, H, D]
-                 # acc_ref VMEM [H, Sq, D] f32  online-softmax accumulators,
-                 # m_ref   VMEM [H, Sq, 1] f32  carried across the W grid steps
-                 # l_ref   VMEM [H, Sq, 1] f32
-    block_size: int,
-    groups: int,
-    scale: float,
-    window: Optional[int] = None,
-):
-    """One (row, query-tile, logical-block) grid step of paged chunked-prefill
-    attention.
-
-    Same shape of walk as :func:`_paged_decode_kernel` — grid ``(B, S/Sq, W)``,
-    block axis innermost, BlockSpec index maps DMA physical block
-    ``tables[b, w]`` into VMEM — but with ``Sq > 1`` queries per tile, so the
-    score/PV contractions are real ``[H, S, d] x [H, d, bs]`` matmuls on the
-    MXU (``dot_general`` batched over heads) instead of the decode kernel's
-    VPU broadcast-reduce. Causality inside the chunk and raggedness against
-    previously-landed KV collapse into ONE predicate: the engine scatter-
-    writes the chunk's own KV into the pool *before* attention, so every KV
-    position — old blocks and the chunk's own tokens alike — is live in the
-    walked blocks, and masking ``kv_pos <= q_position`` per query reproduces
-    the gather reference exactly (null-padded table entries sit at positions
-    past every query and are silenced by the same predicate).
-
-    With a ``window`` a query at position ``p`` sees ``p - window < kv_pos <=
-    p``. The blocks wholly behind the window of the tile's first query are
-    not walked: for them the index maps repeat block ``first`` (a repeated
-    index elides the DMA) and the fold is skipped; the head of block
-    ``first`` and every later query's own edge are masked."""
-    from jax.experimental import pallas as pl  # deferred with pallas_call's
-
-    if window is not None:
-        first_ref, *refs = refs
-    qpos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    w = pl.program_id(2)
-
-    @pl.when(w == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    if window is None:
-        _paged_prefill_fold(w, *refs, block_size=block_size, groups=groups, scale=scale)
-    else:
-        pl.when(w >= first_ref[pl.program_id(0), pl.program_id(1)])(partial(
-            _paged_prefill_fold, w, *refs, block_size=block_size, groups=groups,
-            scale=scale, window=window))
-
-    @pl.when(w == pl.num_programs(2) - 1)
-    def _finalize():
-        o_ref[0] = (acc_ref[...] / l_ref[...]).transpose(1, 0, 2).astype(o_ref.dtype)
+# VMEM the prefill kernel plans for a grid step's keys and values: its N blocks
+# of K and of V, double buffered, and their head-major working copies. At the
+# serve cells' shapes (blocks of 16, 8 key heads of 128, bf16) this gives N = 8:
+# 128 keys a step, one lane tile of scores a query row.
+_PREFILL_VMEM_BYTES = 5 * 512 * 1024
 
 
-def _paged_prefill_fold(w, qpos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
-                        *, block_size, groups, scale, window=None):
-    """Fold logical block ``w`` into a query tile's online softmax."""
-    q = q_ref[0].astype(jnp.float32) * scale           # [S, H, D]
-    k = k_ref[0].astype(jnp.float32)                   # [bs, Hkv, D]
-    v = v_ref[0].astype(jnp.float32)
-    if groups > 1:  # GQA: every q head in a group reads its kv head's block
-        bs, hkv, d = k.shape
-        k = jnp.broadcast_to(k[:, :, None, :], (bs, hkv, groups, d)).reshape(bs, -1, d)
-        v = jnp.broadcast_to(v[:, :, None, :], (bs, hkv, groups, d)).reshape(bs, -1, d)
-    qh = q.transpose(1, 0, 2)                          # [H, S, D]
-    kh = k.transpose(1, 0, 2)                          # [H, bs, D]
-    vh = v.transpose(1, 0, 2)                          # [H, bs, D]
-    # s[h, i, j] = q[i, h] . k[j, h] — an MXU matmul batched over heads (the
-    # chunk gives the systolic array S real rows, unlike decode's single one)
-    s = jax.lax.dot_general(
-        qh, kh, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
-    )                                                  # [H, S, bs]
-    pos = w * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    seen = pos <= qpos_ref[...]
-    if window is not None:
-        seen = seen & (pos > qpos_ref[...] - window)
-    s = jnp.where(seen, s, -jnp.inf)
-
-    m_prev, l_prev = m_ref[...], l_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))  # [H, S, 1]
-    # a fully-masked prefix of blocks keeps m at -inf: exp(-inf - -inf) would
-    # be NaN, so clamp the shift (everything is 0-weighted anyway)
-    shift = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-    alpha = jnp.exp(m_prev - shift)                    # [H, S, 1]
-    p = jnp.exp(s - shift)                             # [H, S, bs], masked -> 0
-    l_new = l_prev * alpha + jnp.sum(p, axis=2, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, vh, (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
-    l_ref[...] = l_new
+def _prefill_group_blocks(block_size, Hkv, D, dtype, W) -> int:
+    """Blocks fetched and folded per grid step of the prefill walk (``N``),
+    read from shapes as :func:`_decode_group_blocks` reads the decode
+    kernel's: how many fit :data:`_PREFILL_VMEM_BYTES`, a block costing its
+    four pool-dtype copies (K and V, double buffered), the f32 upcast its
+    relayout to head-major goes through and the head-major copies the MXU
+    reads, every ``[Hkv, D]`` tile padded to 8 sublanes and 128 lanes.
+    Clamped to ``[1, W]``."""
+    tile = block_size * -(-Hkv // 8) * 8 * -(-D // 128) * 128  # elements
+    per_block = 6 * tile * jnp.dtype(dtype).itemsize + 2 * tile * 4
+    return max(1, min(W, _PREFILL_VMEM_BYTES // per_block))
 
 
-# One prefill program holds its query tile for ALL heads: q/o tiles (double
-# buffered), the f32 upcast and its head-major copy, acc/m/l and the score
-# temporaries, every one padded to 128 lanes. The v5e compiler reported 6.8 MB
-# of scoped VMEM at H*Sq = 2048 rows, D <= 128, against its 16 MB limit; twice
-# that does not fit.
-_PREFILL_TILE_ROWS = 2048
+# Query rows one prefill program holds, over all heads (``H * Sq``): the q and
+# o tiles (double buffered, pool dtype) and acc in f32, 10 MB at 4096 rows and
+# D = 128, beside the 2.5 MB of keys and values and a key head's score tiles.
+# On the v5e a 512-token chunk of 128 query heads behind 1024-5632 tokens took
+# 1.16-4.25 ms a call at 2048 rows and 0.83-3.18 ms at 4096 (my chip runs, PR
+# 30): a tile twice as tall reads the keys half as often and pays half the
+# steps' overhead. Twice that again does not fit the 16 MB of scoped VMEM.
+_PREFILL_TILE_ROWS = 4096
 
 
 def _prefill_query_tile(S: int, H: int, D: int) -> int:
     """Queries per program: all of S when ``H*S`` rows fit the budget above,
-    else the largest divisor of S that does and is a multiple of 8 (the
-    sublane tiling of the ``[Sq, 1]`` position column)."""
+    else the largest divisor of S that does and is a multiple of 8."""
     budget = max(1, _PREFILL_TILE_ROWS * 128 // (H * max(D, 128)))
     if S <= budget:
         return S
@@ -1016,6 +934,167 @@ def _prefill_query_tile(S: int, H: int, D: int) -> int:
     )
 
 
+def prefill_tiling(S: int, H: int, Hkv: int, D: int, block_size: int, dtype, W: int):
+    """``(Sq, N)`` of a :func:`paged_attention_prefill` call, from its shapes:
+    the queries a tile (:func:`_prefill_query_tile`) and the blocks a grid
+    step (:func:`_prefill_group_blocks`)."""
+    return _prefill_query_tile(S, H, D), _prefill_group_blocks(block_size, Hkv, D, dtype, W)
+
+
+def _prefill_walk(lo, hi, block_size: int, W: int, N: int, window: Optional[int]):
+    """``(first, last, steps)`` of the walk of a query tile whose positions
+    span ``lo..hi`` (integers, or arrays of them, numpy's or traced): the
+    table entries ``first..last`` hold every key some query of the tile sees,
+    ``last`` the block of position ``hi`` (the table's last entry where a
+    padded tail runs past it), ``first`` 0 or, with a window, the block of
+    position ``lo - window + 1``; ``steps = ceil((last - first + 1) / N)``."""
+    xp = jnp if isinstance(lo, jax.Array) else np
+    last = xp.minimum(hi // block_size, W - 1)
+    first = 0 * last
+    if window is not None:
+        first = xp.minimum(xp.maximum(lo - (window - 1), 0) // block_size, last)
+    return first, last, -(-(last - first + 1) // N)
+
+
+def prefill_walk_blocks(start: int, S: int, Sq: int, N: int, W: int, block_size: int,
+                        window: Optional[int] = None) -> int:
+    """Blocks the grid of one :func:`paged_attention_prefill` call visits for
+    a row of ``S`` queries at positions ``start .. start + S - 1`` cut into
+    tiles of ``Sq``: ``N`` a grid step, every tile's walk rounded up to whole
+    steps (:func:`_prefill_walk`, the wrapper's own arithmetic) and never more
+    than the table's ``W`` entries. Against ``S // Sq * W``, the entries of the
+    bucketed table the tiles are handed, this is the share of the table the
+    kernel fetches and computes on."""
+    lo = start + Sq * np.arange(S // Sq)
+    steps = _prefill_walk(lo, lo + Sq - 1, block_size, W, N, window)[2]
+    return int(np.minimum(N * steps, W).sum())
+
+
+def _paged_prefill_kernel(
+    walk_ref,    # [steps*N] int32 scalar-prefetch: the physical block of grid
+                 #   step i's j-th K/V operand, at i*N + j (drives their index maps)
+    tile_ref,    # [steps] int32 scalar-prefetch: the query tile of grid step i
+                 #   (the q, position and output index maps read it)
+    group_ref,   # [steps] int32 scalar-prefetch: its step within the tile's walk
+    entry_ref,   # [steps] int32 scalar-prefetch: the step's first table entry
+    last_ref,    # [steps] int32 scalar-prefetch: the last entry of its tile's walk
+    qpos_ref,    # [1, 1, G*Sq] int32 VMEM: absolute position of each query
+                 #   row (SMEM operands only yield scalars)
+    q_ref,       # [1, Hkv, G*Sq, D]: the tile's queries, key-head major, a
+                 #   key head's G query heads one after the other
+    *refs,       # N K blocks, N V blocks [1, block_size, Hkv, D]; o_ref
+                 # [1, Hkv, D, G*Sq]; the online-softmax carries in VMEM, f32,
+                 # queries along the lanes: acc [Hkv, D, G*Sq], m and l
+                 # [Hkv, 1, G*Sq]; the step's keys and values head-major in
+                 # VMEM, pool dtype: k and v [Hkv, N*block_size, D]
+    block_size: int,
+    group: int,
+    scale: float,
+    window: Optional[int] = None,
+):
+    """One grid step of paged chunked-prefill attention: ``N`` consecutive
+    blocks of one query tile's walk, folded into its online softmax.
+
+    The grid is one-dimensional and as long as the tiles' live blocks need
+    (:func:`_paged_decode_kernel`'s walk, a query tile where that has a row):
+    tile ``t`` of ``Sq`` queries takes ``ceil((last_t - first_t + 1) / N)``
+    steps over the table entries ``first_t..last_t`` that hold the keys its
+    queries can see (:func:`_prefill_walk`), one tile after the other. The pool
+    comes in through ``N`` BlockSpecs a side whose index maps fetch physical
+    blocks ``walk[i*N + j] = tables[b, min(first_t + g*N + j, last_t)]``: what
+    lies past a tile's last live block is neither fetched nor computed on, and
+    where its last step reaches past ``last_t`` the walk repeats that block
+    (already there) and its positions are masked. Everything a step needs is
+    laid out by grid step, one SMEM read an index map: lowering those reads
+    costs a process's start more than anything else in the kernel (at five a
+    map they were 1.0 s of ``command-a-plus.rag-sat``'s 18 s of warm set-up,
+    my chip runs, PR 30).
+
+    A step is two matmuls a key head in the pool's dtype, f32 accumulation:
+    the ``G`` query heads of a key head are the ``G*Sq`` columns of one
+    ``[N*block_size, D] x [D, G*Sq]`` score product and one ``[D,
+    N*block_size] x [N*block_size, G*Sq]`` value product, so K and V are never
+    copied out to the query heads' width, and the probabilities are rounded to
+    the pool's dtype for the value product (what
+    ``generation._masked_attention`` computes). Scores are held keys by
+    queries: the running max ``m``, the sum ``l`` and the rescaling of ``acc``
+    are lane-dense rows over the queries and their reductions run down the
+    sublanes, where the other way round every ``[G*Sq, 1]`` column cost as
+    much as the score tile itself (a 512-token chunk of 128 heads behind 2560
+    tokens: 2.19 ms a call against 3.47, my chip runs, PR 30). ``m``, ``l``
+    and ``acc`` stay f32; the output leaves as ``[Hkv, D, G*Sq]`` and the
+    wrapper's transpose puts it back.
+
+    Causality inside the chunk and raggedness against previously-landed KV
+    collapse into ONE predicate: the engine scatter-writes the chunk's own KV
+    into the pool *before* attention, so every KV position — old blocks and
+    the chunk's own tokens alike — is live in the walked blocks, and masking
+    ``kv_pos <= q_position`` per query reproduces the gather reference
+    (null-padded table entries sit at positions past every query; a padded
+    tail's queries past the table see the table and no more). With a
+    ``window`` a query at position ``p`` sees ``p - window < kv_pos <= p``:
+    the walk starts at the block the tile's first query's window starts in,
+    and the head of that block and every later query's own edge are masked.
+    The mask is built once a step, as 0 / -inf added to the scores, and shared
+    by the key heads, which are a ``fori_loop`` over the head-major copy of
+    the step's K and V that Mosaic unrolls: the body is traced once, not
+    ``Hkv`` times (unrolled in Python, the four layers and two chunk buckets
+    of ``command-a-plus.rag-sat`` paid 1.6 s more of tracing at every process
+    start, compile cache warm or not: my chip runs, PR 30)."""
+    from jax.experimental import pallas as pl  # deferred with pallas_call's
+
+    i = pl.program_id(0)
+    k_refs, v_refs = refs[:group], refs[group : 2 * group]
+    o_ref, acc_ref, m_ref, l_ref, k_ref, v_ref = refs[2 * group :]
+    entry, last = entry_ref[i], last_ref[i]
+
+    @pl.when(group_ref[i] == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    qpos = qpos_ref[0]                                   # [1, G*Sq]
+    pos = entry * block_size + jax.lax.broadcasted_iota(
+        jnp.int32, (group * block_size, 1), 0)
+    # a step past the tile's last block repeats it: those keys are no one's
+    pos = jnp.where(pos < (last + 1) * block_size, pos, jnp.iinfo(jnp.int32).max)
+    seen = pos <= qpos                                   # [N*bs, G*Sq]
+    if window is not None:
+        seen = seen & (pos > qpos - window)
+    unseen = jnp.where(seen, 0.0, -jnp.inf)              # added to the scores
+
+    def head_major(refs):  # N blocks [bs, Hkv, D] -> [Hkv, N*bs, D]
+        x = jnp.concatenate([r[0] for r in refs])
+        # (the relayout goes through f32: Mosaic moves 32-bit sublanes)
+        return x.astype(jnp.float32).transpose(1, 0, 2).astype(x.dtype)
+
+    k_ref[...] = head_major(k_refs)
+    v_ref[...] = head_major(v_refs)
+
+    def fold(h, carry):  # one key head and its G query heads
+        s = _dot_nt2(k_ref[h], q_ref[0, h]) * scale + unseen  # [N*bs, G*Sq] f32
+        m_prev = m_ref[h]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))  # [1, G*Sq]
+        # a query whose window starts after this step keeps m at -inf:
+        # exp(-inf - -inf) would be NaN, so clamp the shift (all is 0-weighted)
+        shift = jnp.where(m_new > -jnp.inf, m_new, 0.0)
+        alpha = jnp.exp(m_prev - shift)
+        p = jnp.exp(s - shift)                           # masked -> 0
+        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=0, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + _dot_tn2(v_ref[h], p.astype(v_ref.dtype))  # [D, G*Sq]
+        m_ref[h] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, k_ref.shape[0], fold, 0, unroll=True)
+
+    @pl.when(entry + group > last)  # the tile's last step
+    def _finalize():
+        # (a padded query whose whole window lies past the table saw nothing: 0, not 0 / 0)
+        l = l_ref[...]
+        o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
 def paged_attention_prefill(
     q, k_pool, v_pool, block_tables, q_positions, scale=None, *, window=None, interpret=False
 ):
@@ -1023,80 +1102,111 @@ def paged_attention_prefill(
     against per-layer pools ``[num_blocks, block_size, Hkv, D]`` through
     ``block_tables [B, W]``, with per-query absolute positions
     ``q_positions [B, S]``. The engine has already scatter-written the
-    chunk's own KV into the pool, so one walk over each row's block table
-    covers both the previously-landed KV and the in-chunk causal part; the
-    per-query position mask is what makes the online softmax match the
-    gather reference's causal masking bit for bit. The gathered
-    ``[B, W*block_size]`` cache the XLA reference materializes per layer
-    never exists. A static ``window`` (None: the program as it was, named
-    ``paged_prefill``) makes a query see its last ``window`` positions only,
-    itself among them; a query tile then skips the blocks wholly behind its
-    first query's window, under the name ``paged_prefill_win``.
-    ``interpret=True`` runs the identical kernel through the
-    Pallas interpreter (the CPU parity path in tier-1 CI)."""
+    chunk's own KV into the pool, so one walk over a row's block table covers
+    both the previously-landed KV and the in-chunk causal part; the per-query
+    position mask is what makes the online softmax match the gather
+    reference's causal masking. The gathered ``[B, W*block_size]`` cache the
+    XLA reference materializes per layer never exists.
+
+    The queries are cut into tiles of ``Sq`` (:func:`_prefill_query_tile`) and
+    laid out key-head major, ``[B*S/Sq, Hkv, G*Sq, D]`` (one XLA transpose in,
+    one out), so the ``G`` query heads of a key head are one matmul operand.
+    Each tile walks the table entries that hold keys its queries can see,
+    ``N`` blocks a grid step (:func:`_prefill_group_blocks`), the next step's
+    blocks in flight while this step's are computed
+    (:func:`_paged_prefill_kernel`); the length of the grid comes from the
+    positions at run time, so the padding of a bucketed table and the blocks
+    ahead of a tile are neither fetched nor computed on
+    (:func:`prefill_walk_blocks` counts the walk on the host).
+    A static ``window`` (None: full causal attention, named ``paged_prefill``)
+    makes a query see its last ``window`` positions only, itself among them;
+    a tile's walk then starts at the block its first query's window starts
+    in, under the name ``paged_prefill_win``. ``interpret=True`` runs the
+    identical kernel through the Pallas interpreter (the CPU parity path in
+    tier-1 CI)."""
     from jax.experimental import pallas as pl_  # deferred: CPU-only installs
     from jax.experimental.pallas import tpu as pltpu
 
     B, S, H, D = q.shape
     if S < 2:
         raise ValueError(f"prefill kernel wants S>1 queries, got S={S}")
-    num_blocks, block_size, Hkv, _ = k_pool.shape
-    W = block_tables.shape[1]
+    block_size, Hkv = k_pool.shape[1:3]
     if H % Hkv:
         raise ValueError(f"q heads {H} not a multiple of kv heads {Hkv}")
-    sm_scale = (1.0 / math.sqrt(D)) if scale is None else float(scale)
-    Sq = _prefill_query_tile(S, H, D)
+    W = block_tables.shape[1]
+    G = H // Hkv
+    scale = (1.0 / math.sqrt(D)) if scale is None else float(scale)
+    window = None if window is None else int(window)
+    Sq, N = prefill_tiling(S, H, Hkv, D, block_size, k_pool.dtype, W)
+    T = S // Sq  # query tiles a row
+    steps = pl_.cdiv(W, N)  # of a tile that walks the whole table
+
+    # The walk, from the positions (the same small integer arrays for every
+    # layer of a step): tile t = b*T + i takes grid steps ends[t-1]..ends[t]-1.
     q_positions = jnp.asarray(q_positions, jnp.int32)
+    tiled = q_positions.reshape(B * T, Sq)
+    first, last, count = _prefill_walk(
+        jnp.min(tiled, axis=1), jnp.max(tiled, axis=1), block_size, W, N, window)
+    ends = jnp.cumsum(count)
+    step = jnp.arange(B * T * steps, dtype=jnp.int32)
+    # (steps past ends[-1] never run; their entries only have to stay in range)
+    done = step[:, None] >= ends  # [B*T*steps, B*T]: tile t ends before this step
+    step_tile = jnp.minimum(jnp.sum(done, axis=1, dtype=jnp.int32), B * T - 1)
+    step_group = step - jnp.max(jnp.where(done, ends, 0), axis=1)
 
-    if window is None:
-        first = ()
+    step_entry = first[step_tile] + step_group * N
+    step_last = last[step_tile]
+    # the physical block of step i's j-th operand: its table entry, or the
+    # tile's last one where the step reaches past it
+    walk = block_tables.astype(jnp.int32)[
+        (step_tile // T)[:, None],
+        jnp.minimum(step_entry[:, None] + jnp.arange(N, dtype=jnp.int32), step_last[:, None]),
+    ].reshape(-1)
 
-        def pool_block(b, i, w, tables):
-            return (tables[b, w], 0, 0, 0)
-    else:  # a tile's walk starts at the block its first query's window starts in
-        tile_first = jnp.min(q_positions.reshape(B, S // Sq, Sq), axis=2)
-        first = (jnp.clip(tile_first - int(window) + 1, 0, W * block_size - 1) // block_size,)
+    def pool_block(j):
+        return pl_.BlockSpec(
+            (1, block_size, Hkv, D), lambda i, walk, *_: (walk[i * N + j], 0, 0, 0))
 
-        def pool_block(b, i, w, tables, first):
-            return (tables[b, jnp.maximum(w, first[b, i])], 0, 0, 0)
+    def of_tile(*block):
+        return pl_.BlockSpec(
+            (1,) + block, lambda i, walk, tile, *_: (tile[i],) + (0,) * len(block))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1 + len(first),  # block tables, a window's first blocks
-        grid=(B, S // Sq, W),
-        in_specs=[
-            pl_.BlockSpec((1, Sq, 1), lambda b, i, w, *_: (b, i, 0)),
-            pl_.BlockSpec((1, Sq, H, D), lambda b, i, w, *_: (b, i, 0, 0)),
-            pl_.BlockSpec((1, block_size, Hkv, D), pool_block),
-            pl_.BlockSpec((1, block_size, Hkv, D), pool_block),
-        ],
-        out_specs=pl_.BlockSpec((1, Sq, H, D), lambda b, i, w, *_: (b, i, 0, 0)),
+        num_scalar_prefetch=5,
+        grid=(ends[-1],),
+        in_specs=[of_tile(1, G * Sq), of_tile(Hkv, G * Sq, D)]
+        + 2 * [pool_block(j) for j in range(N)],
+        out_specs=of_tile(Hkv, D, G * Sq),
         scratch_shapes=[
-            pltpu.VMEM((H, Sq, D), jnp.float32),
-            pltpu.VMEM((H, Sq, 1), jnp.float32),
-            pltpu.VMEM((H, Sq, 1), jnp.float32),
+            pltpu.VMEM((Hkv, D, G * Sq), jnp.float32),
+            pltpu.VMEM((Hkv, 1, G * Sq), jnp.float32),
+            pltpu.VMEM((Hkv, 1, G * Sq), jnp.float32),
+            pltpu.VMEM((Hkv, N * block_size, D), k_pool.dtype),
+            pltpu.VMEM((Hkv, N * block_size, D), v_pool.dtype),
         ],
     )
     kernel = partial(
-        _paged_prefill_kernel,
-        block_size=block_size,
-        groups=H // Hkv,
-        scale=sm_scale,
-        window=None if window is None else int(window),
-    )
-    return pl_.pallas_call(
+        _paged_prefill_kernel, block_size=block_size, group=N, scale=scale, window=window)
+    out = pl_.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, S, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B * T, Hkv, D, G * Sq), q.dtype),
         interpret=interpret,
         name="paged_prefill" if window is None else "paged_prefill_win",
     )(
-        block_tables.astype(jnp.int32),
-        *first,
-        q_positions.reshape(B, S, 1),
-        q,
-        k_pool,
-        v_pool,
+        walk,
+        step_tile,
+        step_group,
+        step_entry,
+        step_last,
+        # a query row of a key head is (query head g, query s): g*Sq + s
+        jnp.broadcast_to(tiled[:, None, :], (B * T, G, Sq)).reshape(B * T, 1, G * Sq),
+        # query head h = kv_head * G + g: [B, T, Sq, Hkv, G, D] -> key-head major
+        q.reshape(B, T, Sq, Hkv, G, D).transpose(0, 1, 3, 4, 2, 5).reshape(B * T, Hkv, G * Sq, D),
+        *(N * [k_pool] + N * [v_pool]),
     )
+    # back to [B, S, H, D], the caller's BSHD contract
+    return out.reshape(B, T, Hkv, D, G, Sq).transpose(0, 1, 5, 2, 4, 3).reshape(B, S, H, D)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, q_positions, scale=None, window=None):

@@ -48,7 +48,7 @@ from ..models.transformer import (
     rms_norm,
     rope_frequencies,
 )
-from ..ops.flash_attention import paged_attention
+from ..ops.flash_attention import paged_attention, prefill_tiling, prefill_walk_blocks
 from ..telemetry import events as tel
 from ..telemetry import goodput as _goodput
 from ..telemetry import metrics as _metrics
@@ -411,6 +411,12 @@ class ServingEngine:
         self.decode_blocks_walked = 0
         #: of the live ones, the blocks a window layer walked (a model with a window)
         self.decode_blocks_window = 0
+        #: blocks the paged prefill kernel's grid visits for one full layer of
+        #: the prefill chunks, and entries of the tables its query tiles were
+        #: handed (:meth:`_prefill_plan`); for a window layer where the model has one
+        self.prefill_blocks_walked = 0
+        self.prefill_blocks_table = 0
+        self.prefill_blocks_window = 0
         #: a routed model's own counts, summed over its calls and layers
         #: (:meth:`_record_counts`; `moe_*` in :meth:`stats`): real tokens
         #: through the model, (token, expert) pairs on the experts held here,
@@ -889,8 +895,10 @@ class ServingEngine:
         it, after that sync."""
         prefix = req.output_ids()
         start = int(req.cached_tokens)
+        W = self.lattice.prefill_points()[0][1]
+        chunks, walk = self._prefill_plan(start, int(prefix.size), W)
         with self._phase(
-            "prefill", rid=int(req.rid), tokens=int(prefix.size) - start, cached=start
+            "prefill", rid=int(req.rid), tokens=int(prefix.size) - start, cached=start, **walk
         ) as phase_t0_ns:
             span_prefill = None
             if req.trace is not None:
@@ -918,9 +926,7 @@ class ServingEngine:
                         parent_id=span_prefill["span_id"], component="engine",
                         src_block=int(src), dst_block=int(dst),
                     ))
-            W = self.lattice.prefill_points()[0][1]
             table = self.allocator.block_table(req.rid, pad_to=W)[None]
-            chunk_cap = self.lattice.prefill_buckets[-1]
             key = self._request_key(req)
             token_idx = np.int32(len(req.generated))
             self.prefix_cached_tokens += start
@@ -933,9 +939,8 @@ class ServingEngine:
             elif req.generated:
                 self.resume_prefill_tokens += int(prefix.size) - start
             chunk_counts = []  # a model's own counts, one a chunk: [(tokens, counts)]
-            while start < prefix.size:
-                chunk = prefix[start : start + chunk_cap]
-                Sb = self.lattice.prefill_bucket(chunk.size)
+            for start, size, Sb in chunks:
+                chunk = prefix[start : start + size]
                 ids = np.zeros((1, Sb), np.int32)
                 ids[0, : chunk.size] = chunk
                 chunk_t0 = _tracing.now_ns() if span_prefill is not None else 0
@@ -951,7 +956,6 @@ class ServingEngine:
                         parent_id=span_prefill["span_id"], component="engine",
                         start=int(start), tokens=int(chunk.size), bucket=int(Sb),
                     ))
-                start += chunk.size
             tok = int(tok)  # the sync: the sampled token is on the host from here
             t_ns, t = self._clock()
             for tokens, counts in chunk_counts:  # computed by now: no wait
@@ -962,6 +966,38 @@ class ServingEngine:
                 _tracing.span_close(span_prefill, t1_ns=t_ns)
         req.generated.append(tok)
         self.prefill_calls += 1
+
+    def _prefill_plan(self, start: int, end: int, W: int) -> "tuple[list, dict]":
+        """The chunks of a prefill of positions ``start..end-1`` as ``(start,
+        tokens, bucket)``, each at the smallest covering prefill bucket (the
+        largest for all but the tail), and what the paged prefill kernel walks
+        for them (``ops.flash_attention.prefill_walk_blocks``, the kernel's own
+        arithmetic on the host's integers): ``walked_blocks``, the blocks its
+        grid visits for ONE full layer, and ``table_blocks``, the entries of the
+        ``W``-wide table its query tiles are handed; for a model with window
+        layers also ``window_walked_blocks``, the same for one such layer.
+        Summed into ``prefill_blocks_*`` of :meth:`stats`."""
+        chunks, cap = [], self.lattice.prefill_buckets[-1]
+        while start < end:
+            size = min(cap, end - start)
+            chunks.append((start, size, self.lattice.prefill_bucket(size)))
+            start += size
+        c = self.config
+        walk = {"walked_blocks": 0, "table_blocks": 0}
+        if self.window is not None:
+            walk["window_walked_blocks"] = 0
+        for at, _, Sb in chunks:
+            Sq, N = prefill_tiling(
+                Sb, c.n_heads, c.n_kv_heads, c.head_dim, self.block_size, self.pool["k"].dtype, W)
+            walk["table_blocks"] += Sb // Sq * W
+            walk["walked_blocks"] += prefill_walk_blocks(at, Sb, Sq, N, W, self.block_size)
+            if self.window is not None:
+                walk["window_walked_blocks"] += prefill_walk_blocks(
+                    at, Sb, Sq, N, W, self.block_size, self.window)
+        self.prefill_blocks_walked += walk["walked_blocks"]
+        self.prefill_blocks_table += walk["table_blocks"]
+        self.prefill_blocks_window += walk.get("window_walked_blocks", 0)
+        return chunks, walk
 
     def _decode_bucket(self, running: "list[Request]") -> "tuple[int, int, dict]":
         """The lattice point of a decode batch and the blocks its rows hold:
@@ -1256,11 +1292,14 @@ class ServingEngine:
             ),
             "decode_blocks_live": self.decode_blocks_live,
             "decode_blocks_walked": self.decode_blocks_walked,
+            "prefill_blocks_walked": self.prefill_blocks_walked,
+            "prefill_blocks_table": self.prefill_blocks_table,
             **self.jit_cache_sizes(),
             **self.allocator.stats(),
         }
         if self.window is not None:
             out["decode_blocks_window"] = self.decode_blocks_window
+            out["prefill_blocks_window"] = self.prefill_blocks_window
         if self.moe["calls"]:
             out.update({"moe_" + name: total for name, total in self.moe.items()})
         if self.spec_tokens > 0:

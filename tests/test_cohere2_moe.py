@@ -211,6 +211,7 @@ def test_engine_records_routing_and_window_blocks_as_counted_by_hand(monkeypatch
     pairs against the reference's own routing of the same tokens, the blocks
     against the rows' lengths."""
     monkeypatch.setattr(tracing, "_RING", collections.deque(maxlen=4096))
+    monkeypatch.setattr(fa, "_prefill_group_blocks", lambda bs, Hkv, D, dtype, W: 2)
     cfg = _config(experts_held=4, first_expert=8)
     params = init_cohere2_moe(cfg, jax.random.PRNGKey(6))
     engine = ServingEngine(params, cfg, num_blocks=65, block_size=BLOCK, max_slots=2,
@@ -239,6 +240,14 @@ def test_engine_records_routing_and_window_blocks_as_counted_by_hand(monkeypatch
     assert builds[0]["window_blocks"] == (9 - 4) + 2
     assert stats["decode_blocks_window"] == sum(b["window_blocks"] for b in builds)
     assert stats["decode_blocks_live"] == sum(b["live_blocks"] for b in builds)
+    # the prefill kernel's walk, 2 blocks a step, one tile a chunk, a table of 16: the long
+    # prompt's chunks at 0, 32 and 64 (a bucket of 16: positions 64-79) end in blocks 3, 7 and
+    # 9; a window layer's walk of the last starts at position 64 - 31 = 33, in block 4
+    walks = [key for _, _, _, key in tracing.recorded("atpu.serve.prefill")]
+    assert [(w["walked_blocks"], w["window_walked_blocks"], w["table_blocks"]) for w in walks] == [
+        (4 + 8 + 10, 4 + 8 + 6, 3 * 16), (2, 2, 16)]
+    assert stats["prefill_blocks_walked"] == 24 and stats["prefill_blocks_table"] == 64
+    assert stats["prefill_blocks_window"] == 20
 
 
 # ------------------------------------------------------------ the window predicate
@@ -287,24 +296,20 @@ def test_window_kernels_agree_with_the_gather_twin(window):
 
 
 def _mistral_shaped(kernel):
-    """A Mistral-shaped call (32/8 heads of 128, blocks of 16, bf16) of one
-    paged kernel with no window, on seeded inputs: ``(fn, args)``."""
+    """A Mistral-shaped call (32/8 heads of 128, blocks of 16, bf16) of the
+    paged decode kernel with no window, on seeded inputs: ``(fn, args)``."""
+    assert kernel == "decode"  # the prefill kernel's twin went with that program (ISSUE 30)
     rng = np.random.default_rng(7)
     bs, Hkv, H, D, nb, W = 16, 8, 32, 128, 40, 12
     k_pool = jnp.asarray(rng.normal(size=(nb, bs, Hkv, D)), jnp.bfloat16)
     v_pool = jnp.asarray(rng.normal(size=(nb, bs, Hkv, D)), jnp.bfloat16)
-    lengths = np.array([1, 37, 100, 192]) if kernel == "decode" else np.array([70, 0]) + 32
+    lengths = np.array([1, 37, 100, 192])
     tables = np.zeros((len(lengths), W), np.int32)
     for b, n in enumerate(lengths):
         tables[b, :-(-int(n) // bs)] = rng.permutation(np.arange(1, nb))[:-(-int(n) // bs)]
-    if kernel == "decode":
-        q = jnp.asarray(rng.normal(size=(4, 1, H, D)), jnp.bfloat16)
-        return (lambda *a: fa.paged_attention_decode(*a, interpret=True),
-                (q, k_pool, v_pool, jnp.asarray(tables), jnp.asarray(lengths)))
-    positions = jnp.asarray((lengths - 32)[:, None] + np.arange(32)[None])
-    q = jnp.asarray(rng.normal(size=(2, 32, H, D)), jnp.bfloat16)
-    return (lambda *a: fa.paged_attention_prefill(*a, interpret=True),
-            (q, k_pool, v_pool, jnp.asarray(tables), positions))
+    q = jnp.asarray(rng.normal(size=(4, 1, H, D)), jnp.bfloat16)
+    return (lambda *a: fa.paged_attention_decode(*a, interpret=True),
+            (q, k_pool, v_pool, jnp.asarray(tables), jnp.asarray(lengths)))
 
 
 # sha256 of the float32 bytes of the output, and of the text of the call's jaxpr (kernel body
@@ -313,12 +318,10 @@ def _mistral_shaped(kernel):
 PARENT = {
     "decode": ("85a33e2d5cdea56c2c898b827c26810457aadb0ec61be652ba282e7fae895e34",
                "d067ad745fae206e8cb5fc8d7f496e2abe319d1e4576a18d60841f4505616eff"),
-    "prefill": ("e933df59c63b02fe3bee147610c1055fd6f38833130053f940ddebe1d9c659b5",
-                "8836e10ba45db2a0ea992f4eb5d5f39c7e4f5eb034d5aae62e99562c40c51bd1"),
-}
+}  # (the prefill kernel's pair went with that program: its grid and tile are ISSUE 30's)
 
 
-@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+@pytest.mark.parametrize("kernel", ["decode"])
 def test_without_a_window_both_paged_kernels_are_the_parents_bit_for_bit(kernel):
     fn, args = _mistral_shaped(kernel)
     out = hashlib.sha256(np.asarray(fn(*args).astype(jnp.float32)).tobytes()).hexdigest()

@@ -117,15 +117,49 @@ def test_paged_prefill_compiles_for_v5e(one_chip, chunk, block_size):
     )
 
 
+# (B, S, H, Hkv, D, block_size, W, window) -> (Sq, N): the serve cells' own prefill
+# calls (chat-sat / chat-r80: chunks of 256 and 512 at 32/8 heads, a table of 144;
+# rag-sat: 128/8 heads, a table of 400, full and window layers) and a verify step
+PREFILL_CELL_CASES = {
+    "mistral-chunk256": ((1, 256, 32, 8, 128, 16, 144, None), (128, 8)),
+    "mistral-chunk512": ((1, 512, 32, 8, 128, 16, 144, None), (128, 8)),
+    "command-a-chunk512-full": ((1, 512, 128, 8, 128, 16, 400, None), (32, 8)),
+    "command-a-chunk256-window": ((1, 256, 128, 8, 128, 16, 400, 4096), (32, 8)),
+    "verify-b64-s4": ((64, 4, 32, 8, 128, 16, 144, None), (4, 8)),
+}
+
+
+@pytest.mark.parametrize("case", list(PREFILL_CELL_CASES))
+def test_paged_prefill_compiles_at_the_cells_shapes(one_chip, case):
+    (B, S, H, Hkv, D, bs, W, window), tiling = PREFILL_CELL_CASES[case]
+    assert fa.prefill_tiling(S, H, Hkv, D, bs, BF16, W) == tiling
+    pool = ((3201, bs, Hkv, D), BF16)
+    text = _compile(
+        lambda q, k, v, t, p: fa.paged_attention_prefill(q, k, v, t, p, window=window),
+        one_chip, ((B, S, H, D), BF16), pool, pool, ((B, W), jnp.int32), ((B, S), jnp.int32),
+    )
+    assert "paged_prefill" + ("_win" if window else "") in text
+
+
 def test_prefill_query_tile_stays_inside_scoped_vmem():
-    """chunk 512 at 16 heads was 27 MB of scoped VMEM in one program against
-    a 16 MB limit: the query tile is what keeps a program inside it."""
+    """A program holds its query tile for all heads (q and o tiles double
+    buffered, acc in f32) beside N blocks of K and V: the tile of
+    ``_PREFILL_TILE_ROWS`` rows over all heads and the N of
+    ``_PREFILL_VMEM_BYTES`` are what keep it inside the v5e's 16 MB of scoped
+    VMEM (the compiles above). A key head's G query heads times the tile are
+    the width of its matmuls: 512 at both serve configurations."""
     assert fa._prefill_query_tile(128, 16, 64) == 128
-    assert fa._prefill_query_tile(512, 16, 64) == 128
-    assert fa._prefill_query_tile(512, 32, 128) == 64
+    assert fa._prefill_query_tile(512, 16, 64) == 256
+    assert fa._prefill_query_tile(512, 32, 128) == 128   # mistral-7b: 4 x 128 a key head
+    assert fa._prefill_query_tile(512, 128, 128) == 32   # command-a-plus: 16 x 32
     assert fa._prefill_query_tile(4, 32, 128) == 4  # a verify step's k+1 tokens
     with pytest.raises(ValueError, match="cannot tile S=4099"):
         fa._prefill_query_tile(4099, 16, 64)  # prime: no multiple of 8 divides it
+    # N: 128 keys a step at blocks of 16, one block of 128, never over the table
+    assert fa._prefill_group_blocks(16, 8, 128, BF16, 144) == 8
+    assert fa._prefill_group_blocks(16, 8, 128, BF16, 4) == 4
+    assert fa._prefill_group_blocks(128, 8, 64, BF16, 8) == 1
+    assert fa._prefill_group_blocks(16, 8, 64, BF16, 64) == fa._prefill_group_blocks(16, 8, 128, BF16, 64)
 
 
 # ---- the window kernels and the grouped matmul, at the widths of the routed

@@ -120,7 +120,8 @@ PAGES: "dict[str, tuple[str, str, list]]" = {
            "paged_attention"]),
          ("accelerate_tpu.ops.flash_attention",
           ["paged_attention", "paged_attention_decode",
-           "paged_attention_prefill", "paged_kernel_mode"]),
+           "paged_attention_prefill", "prefill_walk_blocks",
+           "paged_kernel_mode"]),
          ("accelerate_tpu.models.transformer",
           ["draft_config", "draft_params"]),
          ("accelerate_tpu.serving.scheduler",
