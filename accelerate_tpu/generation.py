@@ -31,7 +31,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .models.transformer import LlamaConfig, apply_rope, rms_norm, rope_frequencies
+from .models.transformer import LlamaConfig, llama_head, llama_layer, llama_rope
+from .ops.attention import masked_attention
 
 __all__ = [
     "init_kv_cache",
@@ -48,9 +49,10 @@ __all__ = [
 
 def init_kv_cache(config: LlamaConfig, batch_size: int, max_len: int, dtype=jnp.bfloat16):
     """Stacked cache: {"k","v"}: [L, B, max_len, Hkv, D]. The single-stream
-    decode of this module restates the llama layer (``_layer_step``): another
-    model is refused here, where every generate path starts, and is served
-    through ``serving.ServingEngine``."""
+    decode of this module runs the llama layer (``models.transformer.
+    llama_layer``) over a contiguous cache: another model is refused here,
+    where every generate path starts, and is served through
+    ``serving.ServingEngine``."""
     if not isinstance(config, LlamaConfig):
         raise TypeError(
             f"generation.py decodes a LlamaConfig; serve a {type(config).__name__} "
@@ -136,115 +138,50 @@ def _place_for_mesh(mesh, prompt_ids, cache, config):
     return prompt_ids, cache
 
 
-def _masked_attention(q, k_cache, v_cache, allow, scale=None):
-    """The decode attention core shared by the contiguous path here and the
-    paged path (``serving.kv_pager.paged_attention``): q ``[B, S, H, D]``
-    against caches ``[B, T, Hkv, D]`` under a boolean ``allow`` mask
-    broadcastable to ``[B, H, S, T]``. One implementation so the two paths
-    cannot drift — masked slots contribute EXACTLY 0 to the softmax (the
-    ``finfo.min`` fill underflows to 0.0 after the max-subtraction), which is
-    what makes paged decode bitwise-identical to contiguous decode even
-    though the gathered ``T`` differs."""
-    B, S, H, D = q.shape
-    hkv = k_cache.shape[2]
-    # GQA head-repeat: the H/Hkv ratio is fixed per model config, so this
-    # shape branch specializes exactly once — not a per-step recompile
-    if hkv != H:  # jaxlint: disable=R2
-        rep = H // hkv
-        k_cache = jnp.repeat(k_cache, rep, axis=2)
-        v_cache = jnp.repeat(v_cache, rep, axis=2)
-    scale = 1.0 / np.sqrt(D) if scale is None else scale
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_cache,
-                        preferred_element_type=jnp.float32) * scale
-    logits = jnp.where(allow, logits, jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v_cache)
-
-
 def _cached_attention(q, k_cache, v_cache, q_positions, scale=None):
     """q: [B, S, H, D]; caches [B, max_len, Hkv, D]; q_positions [S] — attend
     causally over all cache slots with position <= the query's position."""
     max_len = k_cache.shape[1]
     kv_pos = jnp.arange(max_len)
     allow = kv_pos[None, :] <= q_positions[:, None]  # [S, max_len]
-    return _masked_attention(q, k_cache, v_cache, allow[None, None], scale)
+    return masked_attention(q, k_cache, v_cache, allow[None, None], scale)
 
 
-def _project_qkv(layer_params, x, positions, cos, sin, config):
-    """Shared QKV projection + RoPE for the cached-decode layer step: x
-    ``[B, S, dim]``, per-row ``positions [B, S]``. Returns ``(q, k, v)`` in
-    BSHD; used by both the contiguous layer step here and the paged one in
-    ``serving.engine`` so projection math cannot drift between them."""
-    B, S, _ = x.shape
-    q = (x @ layer_params["wq"]["kernel"]).reshape(B, S, config.n_heads, config.head_dim)
-    k = (x @ layer_params["wk"]["kernel"]).reshape(B, S, config.n_kv_heads, config.head_dim)
-    v = (x @ layer_params["wv"]["kernel"]).reshape(B, S, config.n_kv_heads, config.head_dim)
-    q = apply_rope(q, cos, sin, positions=positions)
-    k = apply_rope(k, cos, sin, positions=positions)
-    return q, k, v
+def _cached_layer(layer_params, h, k_cache, v_cache, positions, cos, sin, config, mesh=None):
+    """One decoder layer over S tokens at ``positions [S]``: ``llama_layer``
+    whose attention writes the [B,max,·,·] caches in place
+    (dynamic_update_slice along the sequence axis), then attends over them."""
 
+    def attend(q, k, v):
+        nonlocal k_cache, v_cache
+        at = (0, positions[0], 0, 0)
+        k_cache = jax.lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype), at)
+        v_cache = jax.lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype), at)
+        return _cached_attention(q, k_cache, v_cache, positions)
 
-def _layer_step(layer_params, h, k_cache, v_cache, positions, cos, sin, config, mesh=None):
-    """One decoder layer over S tokens at ``positions``, updating [B,max,·,·]
-    caches in place (dynamic_update_slice along the sequence axis)."""
-    B, S, _ = h.shape
-    x = rms_norm(h, layer_params["attn_norm"]["scale"], config.norm_eps)
-    q, k, v = _project_qkv(
-        layer_params, x, jnp.broadcast_to(positions[None], (B, S)), cos, sin, config
-    )
-    start = positions[0]
-    k_cache = jax.lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype), (0, start, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype), (0, start, 0, 0))
-    attn = _cached_attention(q, k_cache, v_cache, positions)
-    h = h + attn.reshape(B, S, -1) @ layer_params["wo"]["kernel"]
-    x = rms_norm(h, layer_params["mlp_norm"]["scale"], config.norm_eps)
-    # MoE capacity: DECODE steps (S == 1) route only the B new tokens as one
-    # tiny group, where the training-time capacity ceil(top_k*cf*g/E) would
-    # drop tokens the full-sequence forward keeps (silent divergence) — floor
-    # the factor at E/top_k there so per-step routing is drop-free (Switch/
-    # GShard-style raised eval capacity; cost is bounded by the tiny group).
-    # PREFILL (S > 1) keeps the training factor: its routing group equals the
-    # full forward's at that length, and the floor would blow dispatch memory
-    # up to O(g^2·E) on long prompts. Aux loss is irrelevant at inference.
-    from .models.transformer import llama_ffn
-
-    capacity_factor = None
-    # S == 1 is the decode-vs-prefill split: exactly the two-program shape
-    # bucketing the decode path is built around, not an accidental retrace
-    if config.moe_experts > 0 and S == 1:  # jaxlint: disable=R2
-        capacity_factor = max(config.moe_capacity_factor, config.moe_experts / config.moe_top_k)
-    y, _ = llama_ffn(layer_params, x, config, mesh=mesh, capacity_factor=capacity_factor)
-    h = h + y
+    h, _ = llama_layer(
+        layer_params, h, jnp.broadcast_to(positions[None], h.shape[:2]), cos, sin, config,
+        attend, mesh=mesh)
     return h, k_cache, v_cache
 
 
 def _forward_cached(params, ids, cache, start_pos, config: LlamaConfig, mesh=None):
     """Forward S tokens starting at ``start_pos`` against the cache.
     Returns (logits [B, S, vocab], new_cache)."""
-    cos, sin = rope_frequencies(config.head_dim, config.max_seq_len, config.rope_theta)
-    cos, sin = jnp.asarray(cos), jnp.asarray(sin)
-    h = params["embed_tokens"]["embedding"][ids]
-    S = ids.shape[1]
-    positions = start_pos + jnp.arange(S)
+    cos, sin = llama_rope(config)
+    positions = start_pos + jnp.arange(ids.shape[1])
 
-    def layer(carry, xs):
-        h = carry
+    def layer(h, xs):
         layer_params, k_c, v_c = xs
-        h, k_c, v_c = _layer_step(
-            layer_params, h, k_c, v_c, positions, cos, sin, config, mesh=mesh
-        )
+        h, k_c, v_c = _cached_layer(
+            layer_params, h, k_c, v_c, positions, cos, sin, config, mesh=mesh)
         return h, (k_c, v_c)
 
     h, (k_new, v_new) = jax.lax.scan(
-        layer, h, (params["layers"], cache["k"], cache["v"]),
-        unroll=config.unroll_layers,
+        layer, params["embed_tokens"]["embedding"][ids],
+        (params["layers"], cache["k"], cache["v"]), unroll=config.unroll_layers,
     )
-    h = rms_norm(h, params["final_norm"]["scale"], config.norm_eps)
-    if config.tie_embeddings:
-        logits = h @ params["embed_tokens"]["embedding"].T
-    else:
-        logits = h @ params["lm_head"]["kernel"]
-    return logits, {"k": k_new, "v": v_new}
+    return llama_head(params, h, config), {"k": k_new, "v": v_new}
 
 
 def sample_token_logits(logits, key, *, temperature: float = 1.0, top_k: int = 0,
@@ -566,8 +503,7 @@ def generate_dispatched(
     prompt_ids = jnp.asarray(prompt_ids)
     B, S = prompt_ids.shape
     max_len = S + max_new_tokens
-    cos, sin = rope_frequencies(config.head_dim, config.max_seq_len, config.rope_theta)
-    cos, sin = jnp.asarray(cos), jnp.asarray(sin)
+    cos, sin = llama_rope(config)
 
     per_layer_cache = [
         {
@@ -578,13 +514,13 @@ def generate_dispatched(
     ]
 
     layer_fn = jax.jit(
-        lambda lp, h, kc, vc, positions: _layer_step(lp, h, kc, vc, positions, cos, sin, config)
+        lambda lp, h, kc, vc, positions: _cached_layer(lp, h, kc, vc, positions, cos, sin, config)
     )
     embed_fn = jax.jit(lambda emb, ids: emb["embedding"][ids])
-
-    norm_fn = jax.jit(lambda fp, h: rms_norm(h, fp["scale"], config.norm_eps))
+    head_fn = jax.jit(lambda stages, h: llama_head(stages, h, config))
 
     layer_names = [f"layer_{i:03d}" for i in range(config.n_layers)]
+    head_names = ("final_norm", "embed_tokens" if config.tie_embeddings else "lm_head")
 
     def forward(ids, start_pos):
         positions = start_pos + jnp.arange(ids.shape[1])
@@ -599,13 +535,7 @@ def generate_dispatched(
                 lp, h, cache_i["k"], cache_i["v"], positions
             )
             dispatched.release(name)
-        h = norm_fn(dispatched["final_norm"], h)
-        if config.tie_embeddings:
-            emb = dispatched["embed_tokens"]
-            logits = h @ emb["embedding"].T
-        else:
-            logits = h @ dispatched["lm_head"]["kernel"]
-        return logits
+        return head_fn({name: dispatched[name] for name in head_names}, h)
 
     t0 = time.time()
     logits = forward(prompt_ids, jnp.int32(0))

@@ -29,9 +29,8 @@ added is left out (nothing stands in for the other chips).
 The layer is written once (:func:`_layer`) and parameterised by how attention
 reads and writes its cache: none (:func:`cohere2_moe_forward`, the whole
 sequence at once) or the serving engine's paged pool
-(:meth:`Cohere2MoeConfig.paged_forward`, which ``ServingEngine`` calls: the
-config's type selects it). The engine serves it greedily or sampled;
-speculative decoding (``spec_tokens``), ``serving.disagg.KVHandoff`` and the
+(:meth:`Cohere2MoeConfig.paged_forward`, which ``ServingEngine`` calls). The
+engine serves it greedily or sampled; speculative decoding (``spec_tokens``), ``serving.disagg.KVHandoff`` and the
 single-stream ``generation.py`` are written for ``LlamaConfig`` and refuse it.
 """
 
@@ -44,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.flash_attention import paged_write_attend
 from ..parallel.moe import held_expert_ffn, init_held_experts
 from .transformer import _dense_init, layer_norm
 
@@ -202,23 +202,13 @@ def cohere2_moe_forward(params, ids, config: Cohere2MoeConfig):
 def _paged_forward(params, ids, pool, block_tables, positions, valid, config, block_size):
     """Forward ``ids [B, S]`` at per-row ``positions [B, S]`` against the
     engine's paged pool ``{"k", "v"}: [L, num_blocks, block_size, Hkv, D]``:
-    each layer scatter-writes its keys and values at ``(block_tables[b, pos //
-    block_size], pos % block_size)`` and attends through the paged kernels,
-    with its window or none. ``valid [B, S]`` marks the real tokens of the
-    padded batch (the others are routed to no expert). Returns ``(logits,
-    new pool, counts [L, 3])``. One block table and one pool serve all layer
-    kinds: a window layer keeps (and never reads) what lies behind its
-    window."""
-    from ..ops.flash_attention import paged_attention
-    from ..serving.kv_pager import NULL_BLOCK
-
-    W = block_tables.shape[1]
-    logical = positions // block_size
-    phys = jnp.take_along_axis(block_tables, jnp.minimum(logical, W - 1), axis=1)
-    # positions past the table (a padded prefill tail) and idle slots write to the null block
-    phys = jnp.where(logical < W, phys, NULL_BLOCK)
-    off = positions % block_size
-
+    each layer writes its keys and values through the block tables and attends
+    over the row's blocks, with its window or none
+    (``ops.flash_attention.paged_write_attend``).
+    ``valid [B, S]`` marks the real tokens of the padded batch (the others are
+    routed to no expert). Returns ``(logits, new pool, counts [L, 3])``. One
+    block table and one pool serve all layer kinds: a window layer keeps (and
+    never reads) what lies behind its window."""
     h = params["embed_tokens"]["embedding"][ids]
     k_new, v_new, counts = [], [], []
     for layer in range(config.n_layers):
@@ -226,9 +216,9 @@ def _paged_forward(params, ids, pool, block_tables, positions, valid, config, bl
 
         def attend(q, k, v, window):
             nonlocal k_pool, v_pool
-            k_pool = k_pool.at[phys, off].set(k.astype(k_pool.dtype))
-            v_pool = v_pool.at[phys, off].set(v.astype(v_pool.dtype))
-            return paged_attention(q, k_pool, v_pool, block_tables, positions, window=window)
+            attn, k_pool, v_pool = paged_write_attend(
+                q, k, v, k_pool, v_pool, block_tables, positions, block_size, window)
+            return attn
 
         h, layer_counts = _layer(
             params["layers"][layer], h, positions, valid, config, layer, attend)

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.attention import dot_product_attention
+from ..ops.flash_attention import paged_write_attend
 from ..ops.fp8 import META_KEY, fp8_dot, init_fp8_meta
 
 
@@ -153,6 +154,13 @@ class LlamaConfig:
     def tiny(cls) -> "LlamaConfig":
         return cls(vocab_size=512, dim=128, n_layers=2, n_heads=4, n_kv_heads=2, max_seq_len=256)
 
+    def paged_forward(self, params, ids, pool, block_tables, positions, valid, block_size: int):
+        """What ``ServingEngine`` calls: ``(logits, pool, counts)``. The model
+        takes no notice of ``valid`` and counts nothing (None)."""
+        logits, pool = llama_paged_forward(
+            params, ids, pool, block_tables, positions, self, block_size)
+        return logits, pool, None
+
 
 def init_llama(config: LlamaConfig, key) -> dict:
     """Stacked-layer param pytree: every per-layer tensor has leading dim L.
@@ -264,26 +272,78 @@ def _remat_policy(remat: bool | str):
         ) from None
 
 
-def llama_ffn(layer_params: dict, x: jax.Array, config: LlamaConfig, mesh=None,
-              capacity_factor: Optional[float] = None):
-    """The per-layer FFN block — dense SwiGLU or expert-parallel MoE — shared
-    by the training forward and the cached decode path (generation.py) so the
-    two cannot drift. Returns ``(y, aux)``; ``capacity_factor`` overrides the
-    config's (the decode path floors it for drop-free per-step routing)."""
+def llama_ffn(layer_params: dict, x: jax.Array, config: LlamaConfig, mesh=None):
+    """The per-layer FFN block — dense SwiGLU or expert-parallel MoE — of
+    :func:`llama_layer`. Returns ``(y, aux)``.
+
+    MoE capacity: a DECODE step (``x [B, 1, D]``) routes only the B new tokens
+    as one tiny group, where the training-time capacity ceil(top_k*cf*g/E)
+    would drop tokens the full-sequence forward keeps (silent divergence) —
+    floor the factor at E/top_k there so per-step routing is drop-free
+    (Switch/GShard-style raised eval capacity; cost is bounded by the tiny
+    group). A longer sequence (a prefill, a training batch) keeps the
+    training factor: its routing group equals the full forward's at that
+    length, and the floor would blow dispatch memory up to O(g^2·E) on long
+    prompts."""
     if config.moe_experts > 0:
         from ..parallel.moe import moe_ffn
 
+        capacity_factor = config.moe_capacity_factor
+        # S == 1 is the decode-vs-prefill split: exactly the two-program shape
+        # bucketing the decode paths are built around, not an accidental retrace
+        if x.shape[1] == 1:  # jaxlint: disable=R2
+            capacity_factor = max(capacity_factor, config.moe_experts / config.moe_top_k)
         return moe_ffn(
             layer_params["moe"], x,
             top_k=config.moe_top_k,
-            capacity_factor=(
-                config.moe_capacity_factor if capacity_factor is None else capacity_factor
-            ),
+            capacity_factor=capacity_factor,
             mesh=mesh,  # ep-axis dispatch/expert activation constraints
         )
     gate = jax.nn.silu(_proj(layer_params["w1"], x))
     up = _proj(layer_params["w3"], x)
     return _proj(layer_params["w2"], gate * up), jnp.float32(0.0)
+
+
+def llama_layer(layer_params: dict, h: jax.Array, positions, cos, sin, config: LlamaConfig,
+                attend, mesh=None, pin=lambda h: h):
+    """One decoder layer over ``h [B, S, D]``, the one statement of its math:
+    norm, QKV projection, RoPE at ``positions`` (``[B, S]``, or None for
+    ``0..S-1``), attention, output projection, norm, FFN, the two residuals.
+    Returns ``(h, aux)`` (``aux``: the MoE load-balance loss, 0.0 when dense).
+
+    ``attend(q, k, v) -> [B, S, H, D]`` is how attention reaches the keys and
+    values of earlier positions (and stores these): none
+    (:func:`llama_forward`), a contiguous cache (``generation.py``), the
+    serving engine's paged pool (:func:`llama_paged_forward`). ``mesh`` pins
+    the expert layer's activations (:func:`llama_ffn`); ``pin`` is applied to
+    the residual stream after each residual (:func:`llama_forward` pins it
+    over the batch and sequence axes of its mesh; the decode paths place
+    their batch themselves, ``generation.generation_shardings``)."""
+    B, S, _ = h.shape
+    x = rms_norm(h, layer_params["attn_norm"]["scale"], config.norm_eps)
+    q = _proj(layer_params["wq"], x).reshape(B, S, config.n_heads, config.head_dim)
+    k = _proj(layer_params["wk"], x).reshape(B, S, config.n_kv_heads, config.head_dim)
+    v = _proj(layer_params["wv"], x).reshape(B, S, config.n_kv_heads, config.head_dim)
+    q = apply_rope(q, cos, sin, positions=positions)
+    k = apply_rope(k, cos, sin, positions=positions)
+    h = pin(h + _proj(layer_params["wo"], attend(q, k, v).reshape(B, S, -1)))
+    x = rms_norm(h, layer_params["mlp_norm"]["scale"], config.norm_eps)
+    y, aux = llama_ffn(layer_params, x, config, mesh=mesh)
+    return pin(h + y), aux
+
+
+def llama_rope(config: LlamaConfig):
+    """The ``(cos, sin)`` tables ``[max_seq_len, head_dim / 2]`` of :func:`llama_layer`."""
+    cos, sin = rope_frequencies(config.head_dim, config.max_seq_len, config.rope_theta)
+    return jnp.asarray(cos), jnp.asarray(sin)
+
+
+def llama_head(params: dict, h: jax.Array, config: LlamaConfig) -> jax.Array:
+    """Final norm and the tied or untied head: ``h [B, S, D] -> logits [B, S, vocab]``."""
+    h = rms_norm(h, params["final_norm"]["scale"], config.norm_eps)
+    if config.tie_embeddings:
+        return h @ params["embed_tokens"]["embedding"].T
+    return h @ params["lm_head"]["kernel"]
 
 
 def llama_forward(
@@ -317,8 +377,7 @@ def llama_forward(
     (the CP/SP rings don't carry segment info)."""
     if attention_impl is None:
         attention_impl = config.attn_impl
-    cos, sin = rope_frequencies(config.head_dim, config.max_seq_len, config.rope_theta)
-    cos, sin = jnp.asarray(cos), jnp.asarray(sin)
+    cos, sin = llama_rope(config)
     if segment_ids is not None:
         if attention_fn is not None:
             raise ValueError("segment_ids (packing) cannot combine with attention_fn (CP/SP)")
@@ -335,43 +394,58 @@ def llama_forward(
     # sharding that conflicts with the (batch, seq) activation layout and
     # GSPMD falls back to full rematerialization
     table = _constrain(params["embed_tokens"]["embedding"], mesh, "tp", None)
-    h = table[input_ids]
-    h = _constrain(h, mesh, _batch_axes, "cp", None)
-    B, S, D = h.shape
+
+    def pin(h):
+        return _constrain(h, mesh, _batch_axes, "cp", None)
+
+    def attend(q, k, v):
+        if attention_fn is not None:
+            return attention_fn(q, k, v, causal=True)
+        return dot_product_attention(
+            q, k, v, causal=True, segment_ids=segment_ids, impl=attention_impl
+        )
 
     def layer(h, layer_params):
-        x = rms_norm(h, layer_params["attn_norm"]["scale"], config.norm_eps)
-        q = _proj(layer_params["wq"], x).reshape(B, S, config.n_heads, config.head_dim)
-        k = _proj(layer_params["wk"], x).reshape(B, S, config.n_kv_heads, config.head_dim)
-        v = _proj(layer_params["wv"], x).reshape(B, S, config.n_kv_heads, config.head_dim)
-        q = apply_rope(q, cos, sin, positions=positions)
-        k = apply_rope(k, cos, sin, positions=positions)
-        if attention_fn is not None:
-            attn = attention_fn(q, k, v, causal=True)
-        else:
-            attn = dot_product_attention(
-                q, k, v, causal=True, segment_ids=segment_ids, impl=attention_impl
-            )
-        h = h + _proj(layer_params["wo"], attn.reshape(B, S, -1))
-        h = _constrain(h, mesh, _batch_axes, "cp", None)
-        x = rms_norm(h, layer_params["mlp_norm"]["scale"], config.norm_eps)
-        y, aux = llama_ffn(layer_params, x, config, mesh=mesh)
-        h = h + y
-        h = _constrain(h, mesh, _batch_axes, "cp", None)
-        return h, aux
+        return llama_layer(
+            layer_params, h, positions, cos, sin, config, attend, mesh=mesh, pin=pin)
 
     if remat:
         layer = jax.checkpoint(layer, policy=_remat_policy(remat))
-    h, aux_per_layer = jax.lax.scan(layer, h, params["layers"], unroll=config.unroll_layers)
-    h = rms_norm(h, params["final_norm"]["scale"], config.norm_eps)
-    if config.tie_embeddings:
-        logits = h @ params["embed_tokens"]["embedding"].T
-    else:
-        logits = h @ params["lm_head"]["kernel"]
-    logits = _constrain(logits, mesh, _batch_axes, "cp", "tp")
+    h, aux_per_layer = jax.lax.scan(
+        layer, pin(table[input_ids]), params["layers"], unroll=config.unroll_layers)
+    logits = _constrain(llama_head(params, h, config), mesh, _batch_axes, "cp", "tp")
     if with_aux:
         return logits, jnp.mean(aux_per_layer)
     return logits
+
+
+def llama_paged_forward(params, ids, pool, block_tables, positions, config: LlamaConfig,
+                        block_size: int):
+    """Forward ``ids [B, S]`` at per-row ``positions [B, S]`` against the
+    serving engine's paged pool ``{"k", "v"}: [L, num_blocks, block_size, Hkv,
+    D]`` (``ops.flash_attention`` owns its format): each layer writes its keys
+    and values through the block tables and attends over the row's blocks.
+    Returns ``(logits [B, S, vocab], new_pool)`` — the paged counterpart of
+    ``generation._forward_cached``."""
+    cos, sin = llama_rope(config)
+
+    def layer(h, xs):
+        layer_params, k_pool, v_pool = xs
+
+        def attend(q, k, v):
+            nonlocal k_pool, v_pool
+            attn, k_pool, v_pool = paged_write_attend(
+                q, k, v, k_pool, v_pool, block_tables, positions, block_size)
+            return attn
+
+        h, _ = llama_layer(layer_params, h, positions, cos, sin, config, attend)
+        return h, (k_pool, v_pool)
+
+    h, (k_new, v_new) = jax.lax.scan(
+        layer, params["embed_tokens"]["embedding"][ids],
+        (params["layers"], pool["k"], pool["v"]), unroll=config.unroll_layers,
+    )
+    return llama_head(params, h, config), {"k": k_new, "v": v_new}
 
 
 def llama_loss(params: dict, batch: dict, config: LlamaConfig, **fwd_kwargs) -> jax.Array:

@@ -191,6 +191,32 @@ def _xla_attention(q, k, v, *, causal, mask, scale, window=None):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def masked_attention(q, k_cache, v_cache, allow, scale=None):
+    """The decode attention core shared by the contiguous cache
+    (``generation._cached_attention``) and the paged pool's gather path
+    (``ops.flash_attention.paged_attention_gather``): q ``[B, S, H, D]``
+    against caches ``[B, T, Hkv, D]`` under a boolean ``allow`` mask
+    broadcastable to ``[B, H, S, T]``. One implementation so the two paths
+    cannot drift — masked slots contribute EXACTLY 0 to the softmax (the
+    ``finfo.min`` fill underflows to 0.0 after the max-subtraction), which is
+    what makes paged decode bitwise-identical to contiguous decode even
+    though the gathered ``T`` differs."""
+    B, S, H, D = q.shape
+    hkv = k_cache.shape[2]
+    # GQA head-repeat: the H/Hkv ratio is fixed per model config, so this
+    # shape branch specializes exactly once — not a per-step recompile
+    if hkv != H:  # jaxlint: disable=R2
+        rep = H // hkv
+        k_cache = jnp.repeat(k_cache, rep, axis=2)
+        v_cache = jnp.repeat(v_cache, rep, axis=2)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_cache,
+                        preferred_element_type=jnp.float32) * scale
+    logits = jnp.where(allow, logits, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v_cache)
+
+
 def make_padding_mask(attention_mask: jax.Array, sq: int) -> jax.Array:
     """[B, Skv] 1/0 padding mask -> [B, 1, Sq, Skv] bool mask."""
     return jnp.broadcast_to(

@@ -37,6 +37,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .attention import masked_attention
+
 
 def flash_kernel_mode() -> str:
     """Dispatch mode for :func:`flash_attention`, read once per trace (step
@@ -650,19 +652,27 @@ def paged_kernel_mode() -> str:
     engine's step functions bake it in at compile time — flipping the env var
     mid-run does not retrace warm jit entries):
 
-    - ``"on"`` (default): Pallas decode kernel when the backend is TPU,
-      gather reference everywhere else;
-    - ``"off"`` (``ACCELERATE_PAGED_KERNEL=0``): gather reference always —
-      the kill switch, byte-identical to the pre-kernel engine;
+    - ``"on"`` (default, and any value but the next): the Pallas kernels when
+      the backend is TPU, the gather reference everywhere else;
     - ``"interpret"`` (``ACCELERATE_PAGED_KERNEL=interpret``): the Pallas
-      kernel in interpreter mode on ANY backend — how CPU CI drives the
-      kernel's exact dataflow through the full engine."""
-    raw = os.environ.get("ACCELERATE_PAGED_KERNEL", "1").strip().lower()
-    if raw in ("0", "off", "false"):
-        return "off"
-    if raw == "interpret":
-        return "interpret"
-    return "on"
+      kernels in interpreter mode on ANY backend — how CPU CI drives the
+      kernels' exact dataflow through the full engine."""
+    raw = os.environ.get("ACCELERATE_PAGED_KERNEL", "").strip().lower()
+    return "interpret" if raw == "interpret" else "on"
+
+
+#: physical block index reserved for inactive/padded writes (never allocated)
+NULL_BLOCK = 0
+
+
+def init_block_pool(config, num_blocks: int, block_size: int, dtype=jnp.bfloat16) -> dict:
+    """Device pool ``{"k","v"}: [L, num_blocks, block_size, Hkv, D]``
+    (``num_blocks`` INCLUDES the reserved null block 0). ``config`` is any
+    model description with ``n_layers``, ``n_kv_heads`` and ``head_dim``; every
+    layer gets the same blocks, whatever its kind (a window layer keeps what
+    lies behind its window: an allocator by layer kind is ROADMAP B-m2's)."""
+    shape = (config.n_layers, num_blocks, block_size, config.n_kv_heads, config.head_dim)
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 # VMEM the decode kernel plans for a grid step: its N blocks of K and of V,
@@ -1016,7 +1026,7 @@ def _paged_prefill_kernel(
     N*block_size] x [N*block_size, G*Sq]`` value product, so K and V are never
     copied out to the query heads' width, and the probabilities are rounded to
     the pool's dtype for the value product (what
-    ``generation._masked_attention`` computes). Scores are held keys by
+    ``ops.attention.masked_attention`` computes). Scores are held keys by
     queries: the running max ``m``, the sum ``l`` and the rescaling of ``acc``
     are lane-dense rows over the queries and their reductions run down the
     sublanes, where the other way round every ``[G*Sq, 1]`` column cost as
@@ -1209,6 +1219,31 @@ def paged_attention_prefill(
     return out.reshape(B, T, Hkv, D, G, Sq).transpose(0, 1, 5, 2, 4, 3).reshape(B, S, H, D)
 
 
+def paged_attention_gather(q, k_pool, v_pool, block_tables, q_positions, scale=None,
+                           window=None):
+    """The XLA twin of the paged kernels, and their reference semantics.
+
+    q ``[B, S, H, D]``; per-layer pools ``[num_blocks, block_size, Hkv, D]``;
+    ``block_tables [B, W]`` (physical block ids, null-padded);
+    ``q_positions [B, S]`` per-row absolute positions. Gathers each row's
+    blocks into a contiguous ``[B, W*block_size, Hkv, D]`` view and runs the
+    shared masked-attention core: a slot at gathered position ``t`` holds
+    logical token ``t`` of that sequence, and only slots with ``t <=
+    q_position`` are attended, so null/stale slots are masked to an exact
+    0 contribution (bitwise parity with the contiguous path,
+    ``generation._cached_attention``). With a static ``window`` a query also
+    sees nothing at or before ``q_position - window`` (a sliding-window layer:
+    the kernels' predicate)."""
+    B = q.shape[0]
+    k_cache = k_pool[block_tables].reshape(B, -1, k_pool.shape[2], k_pool.shape[3])
+    v_cache = v_pool[block_tables].reshape(B, -1, v_pool.shape[2], v_pool.shape[3])
+    kv_pos = jnp.arange(k_cache.shape[1])
+    allow = kv_pos[None, None, :] <= q_positions[:, :, None]  # [B, S, T]
+    if window is not None:
+        allow = allow & (kv_pos[None, None, :] > q_positions[:, :, None] - window)
+    return masked_attention(q, k_cache, v_cache, allow[:, None], scale)
+
+
 def paged_attention(q, k_pool, v_pool, block_tables, q_positions, scale=None, window=None):
     """Paged attention for the serving engine (kernel dispatch point).
 
@@ -1219,30 +1254,46 @@ def paged_attention(q, k_pool, v_pool, block_tables, q_positions, scale=None, wi
     :func:`paged_attention_decode` and chunked prefill / multi-token verify
     (``S > 1``) to :func:`paged_attention_prefill` — block-table walk + VMEM
     block streaming + online softmax, no materialized gathered KV per layer.
-    Everywhere else — non-TPU backends and the ``ACCELERATE_PAGED_KERNEL=0``
-    kill switch — runs the XLA reference path (``serving.kv_pager.
-    paged_attention``: gather blocks by table, shared masked-attention core
-    — bitwise-identical to contiguous decode), exactly like
-    :func:`flash_attention`'s pallas-vs-xla split.
+    Every other backend runs :func:`paged_attention_gather` (gather blocks by
+    table, shared masked-attention core — bitwise-identical to contiguous
+    decode), exactly like :func:`flash_attention`'s pallas-vs-xla split.
     ``ACCELERATE_PAGED_KERNEL=interpret`` forces the kernels (interpreter
     mode) on any backend so CPU CI can drive the kernel dataflow through
     the full engine. A static ``window`` (a sliding-window layer: a query at
     position ``p`` sees ``p - window < kv_pos <= p``) is the same predicate on
     all three paths; None is full causal attention, every program as it was."""
-    mode = paged_kernel_mode()
-    if mode != "off":
-        interpret = mode == "interpret"
-        if interpret or jax.default_backend() == "tpu":
-            windowed = {} if window is None else {"window": window}  # None: the call as it was
-            if q.shape[1] == 1:
-                return paged_attention_decode(
-                    q, k_pool, v_pool, block_tables, q_positions[:, 0] + 1,
-                    scale, interpret=interpret, **windowed,
-                )
-            return paged_attention_prefill(
-                q, k_pool, v_pool, block_tables, q_positions,
+    interpret = paged_kernel_mode() == "interpret"
+    if interpret or jax.default_backend() == "tpu":
+        windowed = {} if window is None else {"window": window}  # None: the call as it was
+        if q.shape[1] == 1:
+            return paged_attention_decode(
+                q, k_pool, v_pool, block_tables, q_positions[:, 0] + 1,
                 scale, interpret=interpret, **windowed,
             )
-    from ..serving.kv_pager import paged_attention as _xla_paged
+        return paged_attention_prefill(
+            q, k_pool, v_pool, block_tables, q_positions,
+            scale, interpret=interpret, **windowed,
+        )
+    return paged_attention_gather(q, k_pool, v_pool, block_tables, q_positions, scale, window)
 
-    return _xla_paged(q, k_pool, v_pool, block_tables, q_positions, scale, window)
+
+def paged_write_attend(q, k, v, k_pool, v_pool, block_tables, positions, block_size: int,
+                       window=None):
+    """One layer's step against its slice of the pool, the one place that
+    knows where a position lives: token ``positions[b, s]`` of row ``b`` is
+    slot ``pos % block_size`` of physical block ``block_tables[b, pos //
+    block_size]``. Writes ``k``, ``v`` ``[B, S, Hkv, D]`` there (in the pool's
+    dtype), then attends ``q [B, S, H, D]`` over the row's blocks
+    (:func:`paged_attention`, with ``window`` or none). A position past the
+    table (a padded prefill tail) and every position of an idle slot (its
+    table is all null) write to the null block — a pad write may never land
+    in a live block. Returns ``(attn [B, S, H, D], k_pool, v_pool)``."""
+    W = block_tables.shape[1]
+    logical = positions // block_size
+    phys = jnp.take_along_axis(block_tables, jnp.minimum(logical, W - 1), axis=1)
+    phys = jnp.where(logical < W, phys, NULL_BLOCK)
+    off = positions % block_size
+    k_pool = k_pool.at[phys, off].set(k.astype(k_pool.dtype))
+    v_pool = v_pool.at[phys, off].set(v.astype(v_pool.dtype))
+    attn = paged_attention(q, k_pool, v_pool, block_tables, positions, window=window)
+    return attn, k_pool, v_pool

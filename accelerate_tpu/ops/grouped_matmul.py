@@ -124,9 +124,9 @@ def grouped_matmul(lhs, rhs, group_sizes):
     ``g`` being the next ``group_sizes[g]`` rows; ``[M, N]`` in ``lhs``'s
     dtype, accumulated in float32. Rows past ``sum(group_sizes)`` are
     unspecified. Dispatch as in the module docstring."""
-    mode = paged_kernel_mode()
-    if mode != "off" and (mode == "interpret" or jax.default_backend() == "tpu"):
-        return grouped_matmul_kernel(lhs, rhs, group_sizes, interpret=mode == "interpret")
+    interpret = paged_kernel_mode() == "interpret"
+    if interpret or jax.default_backend() == "tpu":
+        return grouped_matmul_kernel(lhs, rhs, group_sizes, interpret=interpret)
     return jax.lax.ragged_dot(
         lhs, rhs, group_sizes.astype(jnp.int32), preferred_element_type=jnp.float32
     ).astype(lhs.dtype)

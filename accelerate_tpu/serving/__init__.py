@@ -5,7 +5,8 @@ The serve-many-concurrent-requests counterpart of ``generation.py``'s
 single-stream decode (ROADMAP item 1). Five pillars:
 
 - :mod:`~accelerate_tpu.serving.kv_pager` — fixed-size KV blocks in one
-  preallocated device pool, host-side block allocator, paged attention;
+  preallocated device pool: the host-side block allocator (the pool's device
+  side, its format, write and paged attention, is ``ops.flash_attention``'s);
 - :mod:`~accelerate_tpu.serving.scheduler` — step-granular admission,
   immediate completion/backfill, LIFO preemption with persisted resume;
 - :mod:`~accelerate_tpu.serving.engine` — the
@@ -48,16 +49,19 @@ from .admission import (
     TokenBucket,
 )
 from .buckets import BucketLattice
-from .engine import ServingEngine, paged_forward
-from .kv_pager import (
+from ..models.transformer import llama_paged_forward as paged_forward
+from ..ops.flash_attention import (
     NULL_BLOCK,
+    init_block_pool,
+    paged_attention_gather as paged_attention,
+)
+from .engine import ServingEngine
+from .kv_pager import (
     BlockAllocator,
     BlockAllocatorError,
     BlockPoolExhausted,
     PrefixAllocation,
     PrefixPlan,
-    init_block_pool,
-    paged_attention,
 )
 from .autoscaler import AutoscalerPolicy, lattice_fns
 from .canary import CanaryGolden, CanaryProbe, precompute_goldens

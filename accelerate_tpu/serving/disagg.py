@@ -61,8 +61,9 @@ from ..telemetry import goodput as _goodput
 from ..telemetry import metrics as _metrics
 from ..telemetry import tracing as _tracing
 from ..telemetry import watchdog as _watchdog
+from ..ops.flash_attention import NULL_BLOCK
 from .engine import ServingEngine
-from .kv_pager import NULL_BLOCK, BlockPoolExhausted, _chain_hash
+from .kv_pager import BlockPoolExhausted, _chain_hash
 from .replica import ReplicaState
 from .router import RouterRequestStatus, ServingRouter
 from .scheduler import Request

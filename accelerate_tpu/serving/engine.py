@@ -40,25 +40,24 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..generation import _project_qkv, sample_token_logits, serving_shardings
-from ..models.transformer import (
-    LlamaConfig,
-    draft_config,
-    draft_params,
-    rms_norm,
-    rope_frequencies,
+from ..generation import sample_token_logits, serving_shardings
+from ..models.transformer import LlamaConfig, draft_config, draft_params
+from ..ops.flash_attention import (
+    NULL_BLOCK,
+    init_block_pool,
+    prefill_tiling,
+    prefill_walk_blocks,
 )
-from ..ops.flash_attention import paged_attention, prefill_tiling, prefill_walk_blocks
 from ..telemetry import events as tel
 from ..telemetry import goodput as _goodput
 from ..telemetry import metrics as _metrics
 from ..telemetry import tracing as _tracing
 from ..telemetry import watchdog as _watchdog
 from .buckets import BucketLattice
-from .kv_pager import NULL_BLOCK, BlockAllocator, init_block_pool
+from .kv_pager import BlockAllocator
 from .scheduler import Request, Scheduler
 
-__all__ = ["ServingEngine", "paged_forward"]
+__all__ = ["ServingEngine"]
 
 # the ``engine=`` key of an engine's phases and request records: a sequence
 # number unique in the process (``heartbeat_name`` defaults to one string for
@@ -74,91 +73,19 @@ def _chaos_inject(point: str, step: int) -> None:
     _chaos.maybe_inject(point, step=step)
 
 
-def _paged_layer_step(layer_params, h, k_pool, v_pool, block_tables, positions,
-                      cos, sin, config, block_size):
-    """One decoder layer over per-row positions, writing K/V into the paged
-    pool (scatter at ``(block_tables[b, pos // block_size], pos %
-    block_size)``) — the paged counterpart of ``generation._layer_step``,
-    built from the same shared pieces (``_project_qkv``, ``llama_ffn``, the
-    masked-attention core) so the math cannot drift."""
-    B, S, _ = h.shape
-    x = rms_norm(h, layer_params["attn_norm"]["scale"], config.norm_eps)
-    q, k, v = _project_qkv(layer_params, x, positions, cos, sin, config)
-    W = block_tables.shape[1]
-    logical = positions // block_size
-    phys = jnp.take_along_axis(block_tables, jnp.minimum(logical, W - 1), axis=1)
-    # positions past the table (padded prefill tail) and inactive slots write
-    # to the null block — a pad write may never land in a live block
-    phys = jnp.where(logical < W, phys, NULL_BLOCK)
-    off = positions % block_size
-    k_pool = k_pool.at[phys, off].set(k.astype(k_pool.dtype))
-    v_pool = v_pool.at[phys, off].set(v.astype(v_pool.dtype))
-    attn = paged_attention(q, k_pool, v_pool, block_tables, positions)
-    h = h + attn.reshape(B, S, -1) @ layer_params["wo"]["kernel"]
-    x = rms_norm(h, layer_params["mlp_norm"]["scale"], config.norm_eps)
-    from ..models.transformer import llama_ffn
-
-    capacity_factor = None
-    # decode-vs-prefill program split, same two-bucket shape family as the
-    # contiguous path (generation._layer_step) — not a per-step retrace
-    if config.moe_experts > 0 and S == 1:  # jaxlint: disable=R2
-        capacity_factor = max(config.moe_capacity_factor, config.moe_experts / config.moe_top_k)
-    y, _ = llama_ffn(layer_params, x, config, capacity_factor=capacity_factor)
-    return h + y, k_pool, v_pool
-
-
 def model_paged_forward(config, block_size: int):
     """The paged forward of the model ``config`` describes, as the engine's
     step programs call it: ``fn(params, ids [B, S], pool, block_tables,
-    positions [B, S], valid [B, S]) -> (logits, new pool, counts)``. The
-    config's type selects: a ``LlamaConfig`` gets :func:`paged_forward` (it
-    takes no notice of ``valid`` and counts nothing: None); any other model
-    brings its own as ``config.paged_forward`` (``models/cohere2_moe.py``),
-    whose ``counts`` is a small integer array the engine fetches with the
-    step's tokens and records (:meth:`ServingEngine._record_counts`)."""
-    if isinstance(config, LlamaConfig):
-        def forward(params, ids, pool, block_tables, positions, valid):
-            logits, pool = paged_forward(
-                params, ids, pool, block_tables, positions, config, block_size)
-            return logits, pool, None
-
-        return forward
+    positions [B, S], valid [B, S]) -> (logits, new pool, counts)``. Every
+    model brings its own as ``config.paged_forward`` (``models/transformer.py``,
+    ``models/cohere2_moe.py``); ``counts`` is None or a small integer array
+    the engine fetches with the step's tokens and records
+    (:meth:`ServingEngine._record_counts`)."""
     if not callable(getattr(config, "paged_forward", None)):
         raise TypeError(
-            f"ServingEngine cannot serve a {type(config).__name__}: it is no LlamaConfig "
-            "and has no paged_forward(params, ids, pool, block_tables, positions, valid, "
-            "block_size)")
+            f"ServingEngine cannot serve a {type(config).__name__}: it has no "
+            "paged_forward(params, ids, pool, block_tables, positions, valid, block_size)")
     return partial(config.paged_forward, block_size=block_size)
-
-
-def paged_forward(params, ids, pool, block_tables, positions, config: LlamaConfig,
-                  block_size: int):
-    """Forward ``ids [B, S]`` at per-row ``positions [B, S]`` against the
-    paged pool. Returns ``(logits [B, S, vocab], new_pool)`` — the paged
-    counterpart of ``generation._forward_cached``."""
-    cos, sin = rope_frequencies(config.head_dim, config.max_seq_len, config.rope_theta)
-    cos, sin = jnp.asarray(cos), jnp.asarray(sin)
-    h = params["embed_tokens"]["embedding"][ids]
-
-    def layer(carry, xs):
-        h = carry
-        layer_params, k_p, v_p = xs
-        h, k_p, v_p = _paged_layer_step(
-            layer_params, h, k_p, v_p, block_tables, positions, cos, sin,
-            config, block_size,
-        )
-        return h, (k_p, v_p)
-
-    h, (k_new, v_new) = jax.lax.scan(
-        layer, h, (params["layers"], pool["k"], pool["v"]),
-        unroll=config.unroll_layers,
-    )
-    h = rms_norm(h, params["final_norm"]["scale"], config.norm_eps)
-    if config.tie_embeddings:
-        logits = h @ params["embed_tokens"]["embedding"].T
-    else:
-        logits = h @ params["lm_head"]["kernel"]
-    return logits, {"k": k_new, "v": v_new}
 
 
 class ServingEngine:
@@ -185,7 +112,7 @@ class ServingEngine:
     ``config`` describes the model: the engine reads its ``n_layers``,
     ``n_kv_heads``, ``head_dim`` (the pool's shape), ``max_seq_len`` and, where
     it has one, ``sliding_window``, and runs its paged forward
-    (:func:`model_paged_forward`: the config's type selects). Speculative
+    (:func:`model_paged_forward`: the config's own method). Speculative
     decoding drafts with a ``LlamaConfig``'s own first layers and refuses
     another model.
     """
@@ -216,7 +143,7 @@ class ServingEngine:
     ):
         self.params = params
         self.config = config
-        forward = model_paged_forward(config, block_size)  # the config's type selects
+        forward = model_paged_forward(config, block_size)
         #: a window layer of the model reads the last `window` positions only
         self.window = getattr(config, "sliding_window", None)
         self.block_size = block_size
@@ -320,7 +247,7 @@ class ServingEngine:
 
         if self.spec_tokens > 0:
             n_draft = int(draft_layers)
-            d_cfg = draft_config(config, n_draft)
+            draft_forward = model_paged_forward(draft_config(config, n_draft), block_size)
             # truncated-layer self-draft: layer i IS verifier layer i (shared
             # leaves, no copy), so the verifier's landed KV is valid draft KV
             # and the draft needs no pool/prefill/warmup of its own
@@ -334,10 +261,8 @@ class ServingEngine:
                 # the overwrite is value-exact, and rejected positions are
                 # re-written before any later read (scatter-then-attend).
                 dpool = {"k": pool["k"][:n_draft], "v": pool["v"][:n_draft]}
-                logits, dpool = paged_forward(
-                    dparams, last_tok[:, None], dpool, tables, positions[:, None],
-                    d_cfg, block_size,
-                )
+                logits, dpool, _ = draft_forward(
+                    dparams, last_tok[:, None], dpool, tables, positions[:, None], None)
                 pool = {
                     "k": pool["k"].at[:n_draft].set(dpool["k"]),
                     "v": pool["v"].at[:n_draft].set(dpool["v"]),
@@ -357,9 +282,7 @@ class ServingEngine:
                 # one in greedy AND sampled modes.
                 B, S = cand.shape
                 pos = positions[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-                logits, pool = paged_forward(
-                    params, cand, pool, tables, pos, config, block_size
-                )
+                logits, pool, _ = forward(params, cand, pool, tables, pos, None)
                 idx = token_idx[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
                 folded = jax.vmap(jax.vmap(jax.random.fold_in, in_axes=(None, 0)))(
                     keys, idx

@@ -37,14 +37,10 @@ refcounts; a cached block whose count reaches zero parks in an LRU pool
 list runs dry — reclaim-before-reject, so caching can never cause an
 admission rejection that an uncached pool would have accepted.
 
-:func:`paged_attention` is the paged variant of the contiguous
-``generation._cached_attention``: gather the sequence's blocks via its block
-table, then run the SAME shared masked-attention core
-(``generation._masked_attention``) — masked slots contribute exactly 0 to the
-softmax, so paged decode is bitwise-identical to contiguous decode (the
-parity tests in ``tests/test_serving.py`` hold this line). The TPU Pallas
-kernel behind ``ops.flash_attention.paged_attention`` replaces the gather
-with VMEM block streaming; this function stays the reference semantics.
+This module is the HOST side only. The pool's device side — its format, the
+null block, a layer's write and the attention over a row's blocks (Pallas
+kernels on the TPU, their XLA gather twin elsewhere) — is
+``ops.flash_attention``'s; the constants come from there, never the reverse.
 """
 
 from __future__ import annotations
@@ -56,24 +52,16 @@ from typing import Optional
 
 import numpy as np
 
-import jax.numpy as jnp
-
-from ..generation import _masked_attention
+from ..ops.flash_attention import NULL_BLOCK
 from ..telemetry import metrics as _metrics
 
 __all__ = [
-    "NULL_BLOCK",
     "BlockPoolExhausted",
     "BlockAllocatorError",
     "BlockAllocator",
     "PrefixPlan",
     "PrefixAllocation",
-    "init_block_pool",
-    "paged_attention",
 ]
-
-#: physical block index reserved for inactive/padded writes (never allocated)
-NULL_BLOCK = 0
 
 
 class BlockAllocatorError(RuntimeError):
@@ -82,16 +70,6 @@ class BlockAllocatorError(RuntimeError):
 
 class BlockPoolExhausted(RuntimeError):
     """No free block available — the scheduler should preempt or defer."""
-
-
-def init_block_pool(config, num_blocks: int, block_size: int, dtype=jnp.bfloat16) -> dict:
-    """Device pool ``{"k","v"}: [L, num_blocks, block_size, Hkv, D]``
-    (``num_blocks`` INCLUDES the reserved null block 0). ``config`` is any
-    model description with ``n_layers``, ``n_kv_heads`` and ``head_dim``; every
-    layer gets the same blocks, whatever its kind (a window layer keeps what
-    lies behind its window: an allocator by layer kind is ROADMAP B-m2's)."""
-    shape = (config.n_layers, num_blocks, block_size, config.n_kv_heads, config.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def _chain_hash(prev: bytes, block_tokens: np.ndarray) -> bytes:
@@ -569,26 +547,3 @@ class BlockAllocator:
                 reclaimed_blocks=self.reclaimed_blocks,
             )
         return out
-
-
-def paged_attention(q, k_pool, v_pool, block_tables, q_positions, scale=None, window=None):
-    """Paged variant of ``generation._cached_attention``.
-
-    q ``[B, S, H, D]``; per-layer pools ``[num_blocks, block_size, Hkv, D]``;
-    ``block_tables [B, W]`` (physical block ids, null-padded);
-    ``q_positions [B, S]`` per-row absolute positions. Gathers each row's
-    blocks into a contiguous ``[B, W*block_size, Hkv, D]`` view and runs the
-    shared masked-attention core: a slot at gathered position ``t`` holds
-    logical token ``t`` of that sequence, and only slots with ``t <=
-    q_position`` are attended, so null/stale slots are masked to an exact
-    0 contribution (bitwise parity with the contiguous path). With a static
-    ``window`` a query also sees nothing at or before ``q_position - window``
-    (a sliding-window layer; the CPU twin of the paged kernels' predicate)."""
-    B = q.shape[0]
-    k_cache = k_pool[block_tables].reshape(B, -1, k_pool.shape[2], k_pool.shape[3])
-    v_cache = v_pool[block_tables].reshape(B, -1, v_pool.shape[2], v_pool.shape[3])
-    kv_pos = jnp.arange(k_cache.shape[1])
-    allow = kv_pos[None, None, :] <= q_positions[:, :, None]  # [B, S, T]
-    if window is not None:
-        allow = allow & (kv_pos[None, None, :] > q_positions[:, :, None] - window)
-    return _masked_attention(q, k_cache, v_cache, allow[:, None], scale)

@@ -667,7 +667,7 @@ def _prefill_kernel_microbench(on_tpu: bool, *, iters: int = 20):
     import jax.numpy as jnp
 
     from accelerate_tpu.ops.flash_attention import paged_attention_prefill
-    from accelerate_tpu.serving.kv_pager import paged_attention as gather_ref
+    from accelerate_tpu.ops.flash_attention import paged_attention_gather as gather_ref
 
     if on_tpu:
         B, S, H, Hkv, D, bs, nb, W = 8, 64, 16, 8, 128, 16, 256, 24

@@ -57,7 +57,9 @@ def _reference_logits(params, ids, cfg):
 def kernel_mode(request, monkeypatch):
     """The paged kernels and the grouped matmul on their XLA twins, or their
     Pallas bodies through the interpreter."""
-    monkeypatch.setenv("ACCELERATE_PAGED_KERNEL", "0" if request.param == "xla" else "interpret")
+    monkeypatch.delenv("ACCELERATE_PAGED_KERNEL", raising=False)  # xla: the default off the TPU
+    if request.param == "interpret":
+        monkeypatch.setenv("ACCELERATE_PAGED_KERNEL", "interpret")
     return request.param
 
 
@@ -255,11 +257,11 @@ def test_engine_records_routing_and_window_blocks_as_counted_by_hand(monkeypatch
 
 @pytest.mark.parametrize("window", [5, 32, 200])
 def test_window_kernels_agree_with_the_gather_twin(window):
-    """Both paged kernels in interpret mode against ``kv_pager.paged_attention``
+    """Both paged kernels in interpret mode against ``paged_attention_gather``
     with the same window: rows shorter than, at and far beyond the window, a
     prefill chunk behind 70 live tokens and one behind none, at a small query
     tile too (so that a tile's first block differs from the chunk's)."""
-    from accelerate_tpu.serving.kv_pager import paged_attention as gather_twin
+    from accelerate_tpu.ops.flash_attention import paged_attention_gather as gather_twin
 
     rng = np.random.default_rng(0)
     bs, Hkv, H, D, nb, W = 8, 2, 8, 16, 64, 16
@@ -327,6 +329,45 @@ def test_without_a_window_both_paged_kernels_are_the_parents_bit_for_bit(kernel)
     out = hashlib.sha256(np.asarray(fn(*args).astype(jnp.float32)).tobytes()).hexdigest()
     program = hashlib.sha256(str(jax.make_jaxpr(fn)(*args)).encode()).hexdigest()
     assert (out, program) == PARENT[kernel]
+
+
+# ------------------------------------------------------------ the pool's one owner
+
+
+@pytest.mark.parametrize("model", ["llama", "cohere2_moe"])
+def test_a_pad_write_lands_in_the_null_block(model):
+    """Both models write the pool through ``ops.flash_attention.
+    paged_write_attend``: a position past the table (the padded tail of a
+    prefill chunk: 6 positions, a table of one block of 4) and every position
+    of an idle slot (its table all null) go to the null block. The live block
+    holds what a forward of the 4 real tokens alone leaves there, and no
+    other block is touched."""
+    from accelerate_tpu.models import LlamaConfig, init_llama
+    from accelerate_tpu.serving import NULL_BLOCK, init_block_pool
+
+    if model == "llama":
+        cfg = LlamaConfig(vocab_size=128, dim=32, n_layers=2, n_heads=4, n_kv_heads=2, max_seq_len=64)
+        params = init_llama(cfg, jax.random.PRNGKey(0))
+    else:
+        cfg = _config()
+        params = init_cohere2_moe(cfg, jax.random.PRNGKey(0))
+    bs, live, real = 4, 3, 4
+    ids = jnp.asarray(np.random.default_rng(2).integers(1, 128, (2, 6)), jnp.int32)
+    positions = jnp.broadcast_to(jnp.arange(6)[None], (2, 6))
+    tables = jnp.asarray([[live], [NULL_BLOCK]], jnp.int32)
+    valid = jnp.asarray([[True] * real + [False] * 2, [False] * 6])
+    _, pool, _ = cfg.paged_forward(
+        params, ids, init_block_pool(cfg, 6, bs, jnp.float32), tables, positions, valid, bs)
+    _, clean, _ = cfg.paged_forward(
+        params, ids[:1, :real], init_block_pool(cfg, 6, bs, jnp.float32), tables[:1],
+        positions[:1, :real], valid[:1, :real], bs)
+    for side in ("k", "v"):
+        got, want = np.asarray(pool[side]), np.asarray(clean[side])
+        np.testing.assert_allclose(got[:, live], want[:, live], rtol=1e-5, atol=1e-6)
+        assert np.abs(want[:, live]).min() > 0  # all four slots of the live block written
+        assert np.abs(got[:, NULL_BLOCK]).max() > 0  # the pad writes went somewhere: here
+        untouched = [b for b in range(6) if b not in (NULL_BLOCK, live)]
+        assert not got[:, untouched].any() and not want[:, NULL_BLOCK].any()
 
 
 # ------------------------------------------------------------------- what refuses

@@ -106,6 +106,55 @@ def test_llama_gqa_heads():
     assert llama_forward(params, ids, cfg).shape == (1, 8, 128)
 
 
+@pytest.mark.parametrize("ffn", ["dense", "moe"])
+@pytest.mark.parametrize("attend", ["whole", "contiguous", "paged"])
+def test_the_one_llama_layer_under_its_three_attends(attend, ffn):
+    """``llama_layer`` is the one statement of the decoder layer; what differs
+    between its callers is ``attend``: the whole sequence at once, a contiguous
+    cache (``generation._forward_cached``), the serving engine's paged pool
+    (``LlamaConfig.paged_forward``, its blocks scrambled, a table one block
+    wider than the sequence). Float32, the cached paths in two chunks (5 tokens,
+    then 3 behind them): the logits are ``llama_forward``'s. The expert layer's
+    capacity is high enough that no chunk drops a token."""
+    from accelerate_tpu.generation import _forward_cached, init_kv_cache
+    from accelerate_tpu.models.transformer import llama_head, llama_layer, llama_rope
+    from accelerate_tpu.ops.attention import dot_product_attention
+    from accelerate_tpu.serving import NULL_BLOCK, init_block_pool
+
+    moe = dict(moe_experts=4, moe_top_k=2, moe_capacity_factor=8.0) if ffn == "moe" else {}
+    cfg = LlamaConfig(vocab_size=128, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
+                      max_seq_len=64, **moe)
+    params = init_llama(cfg, jax.random.PRNGKey(0))
+    B, S, cut, bs = 2, 8, 5, 4
+    ids = jnp.asarray(np.random.default_rng(1).integers(0, cfg.vocab_size, (B, S)), jnp.int32)
+    want = np.asarray(llama_forward(params, ids, cfg, attention_impl="xla"))
+
+    if attend == "whole":
+        cos, sin = llama_rope(cfg)
+        h = params["embed_tokens"]["embedding"][ids]
+        for l in range(cfg.n_layers):
+            h, _ = llama_layer(
+                jax.tree_util.tree_map(lambda x: x[l], params["layers"]), h, None, cos, sin,
+                cfg, lambda q, k, v: dot_product_attention(q, k, v, causal=True, impl="xla"))
+        got = llama_head(params, h, cfg)
+    elif attend == "contiguous":
+        cache = init_kv_cache(cfg, B, S, jnp.float32)
+        head, cache = _forward_cached(params, ids[:, :cut], cache, jnp.int32(0), cfg)
+        tail, cache = _forward_cached(params, ids[:, cut:], cache, jnp.int32(cut), cfg)
+        got = jnp.concatenate([head, tail], axis=1)
+    else:
+        pool = init_block_pool(cfg, 8, bs, jnp.float32)
+        tables = jnp.asarray([[5, 2, NULL_BLOCK], [1, 6, NULL_BLOCK]], jnp.int32)
+        positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
+        head, pool, counts = cfg.paged_forward(
+            params, ids[:, :cut], pool, tables, positions[:, :cut], None, bs)
+        tail, pool, _ = cfg.paged_forward(
+            params, ids[:, cut:], pool, tables, positions[:, cut:], None, bs)
+        assert counts is None
+        got = jnp.concatenate([head, tail], axis=1)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+
+
 def test_bert_forward_and_padding_mask():
     cfg = BertConfig.tiny()
     params = init_bert(cfg, jax.random.PRNGKey(0))

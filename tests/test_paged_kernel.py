@@ -3,13 +3,13 @@
 The kernel (``ops.flash_attention.paged_attention_decode``) walks each row's
 LIVE block-table entries, ``N`` blocks a grid step (ISSUE 26), and streams
 them through VMEM with online softmax; the XLA gather path
-(``serving.kv_pager.paged_attention``) is the reference semantics. These
+(``ops.flash_attention.paged_attention_gather``) is the reference semantics. These
 tests drive the SAME kernel through the Pallas interpreter
 on CPU — identical dataflow, no TPU required — and hold the line the
 acceptance criteria name: parity across scrambled non-contiguous block
 tables, GQA head ratios, ragged per-slot lengths, null-block rows, and
 tables aliased at a copy-on-write divergence point; plus the
-``ACCELERATE_PAGED_KERNEL`` dispatch/kill-switch contract.
+``ACCELERATE_PAGED_KERNEL`` dispatch contract.
 """
 
 import numpy as np
@@ -23,12 +23,13 @@ import importlib
 from accelerate_tpu.generation import greedy_generate
 from accelerate_tpu.models import LlamaConfig, init_llama
 from accelerate_tpu.ops.flash_attention import (
+    NULL_BLOCK,
     paged_attention as dispatch_paged,
     paged_attention_decode,
+    paged_attention_gather as gather_ref,
     paged_kernel_mode,
 )
 from accelerate_tpu.serving import BucketLattice, ServingEngine
-from accelerate_tpu.serving.kv_pager import NULL_BLOCK, paged_attention as gather_ref
 
 CONFIG = LlamaConfig.tiny()
 # the module, not the function ``accelerate_tpu.ops`` re-exports under its name
@@ -227,21 +228,21 @@ def test_dead_table_entries_are_skipped_not_masked(group_of, N):
 def test_paged_kernel_mode_parsing(monkeypatch):
     monkeypatch.delenv("ACCELERATE_PAGED_KERNEL", raising=False)
     assert paged_kernel_mode() == "on"
-    for raw, want in [("0", "off"), ("off", "off"), ("FALSE", "off"),
-                      ("1", "on"), ("interpret", "interpret")]:
+    # "0" was the kill switch's spelling: no value selects the gather path on a TPU now
+    for raw, want in [("1", "on"), ("0", "on"), ("interpret", "interpret")]:
         monkeypatch.setenv("ACCELERATE_PAGED_KERNEL", raw)
         assert paged_kernel_mode() == want
 
 
 def test_kill_switch_path_is_byte_identical_to_reference(monkeypatch):
-    """``ACCELERATE_PAGED_KERNEL=0`` must route straight to the gather
+    """Off the TPU the default path (the variable unset) is the gather
     reference — byte-identical output, the pre-kernel engine exactly."""
     q, k_pool, v_pool, tables, lens = _random_paged_case(
         5, B=2, H=4, Hkv=2, D=16, bs=4, nb=8, W=3, lens=[9, 6]
     )
     args = (jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
             jnp.asarray(tables), jnp.asarray(lens - 1)[:, None])
-    monkeypatch.setenv("ACCELERATE_PAGED_KERNEL", "0")
+    monkeypatch.delenv("ACCELERATE_PAGED_KERNEL", raising=False)
     out = dispatch_paged(*args)
     ref = gather_ref(*args)
     assert np.array_equal(np.asarray(out, np.float32), np.asarray(ref, np.float32))

@@ -5,7 +5,7 @@ kernel (ISSUE 14, ``tests/test_paged_kernel.py``) to S>1 query chunks: a
 query tile walks the table entries its queries can see, ``N`` blocks a grid
 step, each scored against the tile with a per-query causal mask ``kv_pos <=
 q_position`` (ISSUE 30: the walk, its window, its counter). The XLA
-gather path (``serving.kv_pager.paged_attention``) remains the reference
+gather path (``ops.flash_attention.paged_attention_gather``) remains the reference
 semantics. These tests drive the kernel through the Pallas interpreter on
 CPU — identical dataflow, no TPU required — across scrambled block tables,
 ragged chunk start offsets, GQA ratios, null-block rows, COW-diverged
@@ -22,14 +22,16 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from accelerate_tpu.generation import _masked_attention, greedy_generate
+from accelerate_tpu.generation import greedy_generate
 from accelerate_tpu.models import LlamaConfig, init_llama
+from accelerate_tpu.ops.attention import masked_attention
 from accelerate_tpu.ops.flash_attention import (
+    NULL_BLOCK,
     paged_attention as dispatch_paged,
+    paged_attention_gather as gather_ref,
     paged_attention_prefill,
 )
 from accelerate_tpu.serving import BucketLattice, ServingEngine
-from accelerate_tpu.serving.kv_pager import NULL_BLOCK, paged_attention as gather_ref
 from accelerate_tpu.telemetry import tracing
 
 # the module, not the function ``accelerate_tpu.ops`` re-exports under its name
@@ -152,7 +154,7 @@ def test_kernel_parity_bf16_pools_within_one_ulp():
     """bf16 pools (the engine's cache dtype): the kernel hands the MXU bf16
     operands with f32 accumulation, keeps ``m``, ``l`` and ``acc`` in f32 and
     rounds the probabilities to bf16 for the value product, which is what the
-    program's plain path computes (``generation._masked_attention`` over the
+    program's plain path computes (``ops.attention.masked_attention`` over the
     gathered keys, which normalises before it rounds where the kernel divides
     after): the two agree to one bf16 ulp of a (query, head) pair's largest
     output."""
@@ -167,7 +169,7 @@ def test_kernel_parity_bf16_pools_within_one_ulp():
     keys = k_pool[tables].reshape(B, W * 8, 2, 32)
     values = v_pool[tables].reshape(B, W * 8, 2, 32)
     allow = (np.arange(W * 8)[None, None, :] <= qpos[:, :, None])[:, None]
-    ref = _masked_attention(q, keys, values, jnp.asarray(allow)).astype(jnp.float32)
+    ref = masked_attention(q, keys, values, jnp.asarray(allow)).astype(jnp.float32)
     # one ulp of the largest value a (query, head) pair puts out
     ulp = 2.0 ** (np.floor(np.log2(np.abs(np.asarray(ref)).max(axis=-1, keepdims=True))) - 7)
     assert np.all(np.abs(np.asarray(out.astype(jnp.float32)) - np.asarray(ref)) <= ulp)
@@ -287,14 +289,14 @@ def test_kernel_rejects_single_token_queries():
 
 
 def test_kill_switch_path_is_byte_identical_to_reference(monkeypatch):
-    """``ACCELERATE_PAGED_KERNEL=0`` routes S>1 straight to the gather
-    reference — byte-identical output, the pre-kernel engine exactly."""
+    """Off the TPU the default path (the variable unset) routes S>1 to the
+    gather reference — byte-identical output, the pre-kernel engine exactly."""
     q, k_pool, v_pool, tables, qpos = _random_prefill_case(
         6, B=2, S=3, H=4, Hkv=2, D=16, bs=4, nb=8, W=3, starts=[6, 1]
     )
     args = (jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
             jnp.asarray(tables), jnp.asarray(qpos))
-    monkeypatch.setenv("ACCELERATE_PAGED_KERNEL", "0")
+    monkeypatch.delenv("ACCELERATE_PAGED_KERNEL", raising=False)
     out = dispatch_paged(*args)
     ref = gather_ref(*args)
     assert np.array_equal(np.asarray(out, np.float32), np.asarray(ref, np.float32))

@@ -73,3 +73,58 @@ def test_jaxlint_baseline_only_shrinks():
     )
     for entry in entries:
         assert {"rule", "path", "symbol", "line_content"} <= set(entry)
+
+
+# ---------------------------------------------------------------------------
+# the main path's arrows point down: serving / generation -> models -> ops
+
+
+def _imports(path):
+    """``(module, name)`` of every import in the file, top level or inside a
+    function, relative ones resolved against the file's own package."""
+    import ast
+
+    rel = os.path.relpath(path, REPO)[: -len(".py")].split(os.sep)
+    package = rel[:-1]
+    found = []
+    for node in ast.walk(ast.parse(open(path).read(), filename=path)):
+        if isinstance(node, ast.Import):
+            found += [(alias.name, None) for alias in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            base = package[: len(package) - (node.level - 1)] if node.level else []
+            module = ".".join(base + (node.module.split(".") if node.module else []))
+            found += [(module, alias.name) for alias in node.names]
+    return found
+
+
+@pytest.mark.parametrize("package", ["ops", "models"])
+def test_kernels_and_models_import_no_serving_code(package):
+    """Nothing under ``ops/`` or ``models/`` imports ``serving`` or
+    ``generation``, not even lazily: the pool's device side lives in ``ops``,
+    a model's paged forward in ``models``, and the engine calls down."""
+    above = ("accelerate_tpu.serving", "accelerate_tpu.generation")
+    root = os.path.join(REPO, "accelerate_tpu", package)
+    bad = []
+    for dirpath, _, files in os.walk(root):
+        for name in files:
+            if not name.endswith(".py"):
+                continue
+            path = os.path.join(dirpath, name)
+            for module, imported in _imports(path):
+                full = module if imported is None else f"{module}.{imported}"
+                if any(full == a or full.startswith(a + ".") for a in above):
+                    bad.append(f"{os.path.relpath(path, REPO)}: {full}")
+    assert bad == []
+
+
+def test_the_engine_holds_no_model_code():
+    """``serving/engine.py`` takes configuration from ``models.transformer``
+    (the config type, the self-draft's config and parameter view) and no
+    layer math: the model step is ``config.paged_forward``. The host side of
+    the pool takes nothing from ``generation``."""
+    engine = os.path.join(REPO, "accelerate_tpu", "serving", "engine.py")
+    from_models = {name for module, name in _imports(engine)
+                   if module.startswith("accelerate_tpu.models")}
+    assert from_models <= {"LlamaConfig", "draft_config", "draft_params"}
+    pager = os.path.join(REPO, "accelerate_tpu", "serving", "kv_pager.py")
+    assert not [m for m, _ in _imports(pager) if m.startswith("accelerate_tpu.generation")]

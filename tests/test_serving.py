@@ -956,7 +956,9 @@ def fixed_greedy_workload(kernel_mode: str) -> "list[list[int]]":
     """The fixed workload behind ``golden/serving_greedy_pr23.json``: float32
     weights and cache, six staggered greedy requests through chunked prefill,
     queueing and a preemption. Returns each request's prompt + output."""
-    os.environ["ACCELERATE_PAGED_KERNEL"] = kernel_mode
+    os.environ.pop("ACCELERATE_PAGED_KERNEL", None)  # "xla": off the TPU the default path
+    if kernel_mode == "interpret":
+        os.environ["ACCELERATE_PAGED_KERNEL"] = kernel_mode
     try:
         params = init_llama(CONFIG, jax.random.PRNGKey(0))
         engine = ServingEngine(
@@ -974,10 +976,10 @@ def fixed_greedy_workload(kernel_mode: str) -> "list[list[int]]":
         assert engine.scheduler.preemption_count >= 1
         return [r.output_ids().tolist() for r in reqs]
     finally:
-        del os.environ["ACCELERATE_PAGED_KERNEL"]
+        os.environ.pop("ACCELERATE_PAGED_KERNEL", None)
 
 
-@pytest.mark.parametrize("kernel_mode", ["0", "interpret"])
+@pytest.mark.parametrize("kernel_mode", ["xla", "interpret"])
 def test_greedy_outputs_are_bitwise_the_parents(kernel_mode):
     """Naming the kernels and timing the step changed no arithmetic: the
     fixed workload's tokens equal those the parent commit (PR 23) produced,

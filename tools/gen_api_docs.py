@@ -113,17 +113,17 @@ PAGES: "dict[str, tuple[str, str, list]]" = {
         "See `docs/serving.md` for the guide and `benchmarks/serving/` "
         "(`make bench-serve`) for the continuous-vs-static, replicated, "
         "shared-prefix, disaggregated and speculative-decoding benchmarks.",
-        [("accelerate_tpu.serving.engine", ["ServingEngine", "paged_forward"]),
+        [("accelerate_tpu.serving.engine", ["ServingEngine"]),
          ("accelerate_tpu.serving.kv_pager",
           ["BlockAllocator", "BlockAllocatorError", "BlockPoolExhausted",
-           "PrefixPlan", "PrefixAllocation", "init_block_pool",
-           "paged_attention"]),
+           "PrefixPlan", "PrefixAllocation"]),
          ("accelerate_tpu.ops.flash_attention",
-          ["paged_attention", "paged_attention_decode",
+          ["init_block_pool", "paged_write_attend", "paged_attention",
+           "paged_attention_gather", "paged_attention_decode",
            "paged_attention_prefill", "prefill_walk_blocks",
            "paged_kernel_mode"]),
          ("accelerate_tpu.models.transformer",
-          ["draft_config", "draft_params"]),
+          ["llama_paged_forward", "llama_layer", "draft_config", "draft_params"]),
          ("accelerate_tpu.serving.scheduler",
           ["Request", "RequestStatus", "Scheduler", "SchedulingError"]),
          ("accelerate_tpu.serving.buckets", ["BucketLattice"]),
