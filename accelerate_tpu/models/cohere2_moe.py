@@ -202,28 +202,27 @@ def cohere2_moe_forward(params, ids, config: Cohere2MoeConfig):
 def _paged_forward(params, ids, pool, block_tables, positions, valid, config, block_size):
     """Forward ``ids [B, S]`` at per-row ``positions [B, S]`` against the
     engine's paged pool ``{"k", "v"}: [L, num_blocks, block_size, Hkv, D]``:
-    each layer writes its keys and values through the block tables and attends
-    over the row's blocks, with its window or none
-    (``ops.flash_attention.paged_write_attend``).
+    each layer writes its keys and values through the block tables into its
+    part of the whole stack and attends over the row's blocks, with its window
+    or none (``ops.flash_attention.paged_write_attend``). The stack goes from
+    layer to layer whole, never sliced or restacked, so a program that donates
+    the pool writes in place and holds no second pool among its temporaries.
     ``valid [B, S]`` marks the real tokens of the padded batch (the others are
     routed to no expert). Returns ``(logits, new pool, counts [L, 3])``. One
     block table and one pool serve all layer kinds: a window layer keeps (and
     never reads) what lies behind its window."""
     h = params["embed_tokens"]["embedding"][ids]
-    k_new, v_new, counts = [], [], []
+    k_pool, v_pool = pool["k"], pool["v"]
+    counts = []
     for layer in range(config.n_layers):
-        k_pool, v_pool = pool["k"][layer], pool["v"][layer]
 
         def attend(q, k, v, window):
             nonlocal k_pool, v_pool
             attn, k_pool, v_pool = paged_write_attend(
-                q, k, v, k_pool, v_pool, block_tables, positions, block_size, window)
+                q, k, v, k_pool, v_pool, layer, block_tables, positions, block_size, window)
             return attn
 
         h, layer_counts = _layer(
             params["layers"][layer], h, positions, valid, config, layer, attend)
-        k_new.append(k_pool)
-        v_new.append(v_pool)
         counts.append(layer_counts)
-    pool = {"k": jnp.stack(k_new), "v": jnp.stack(v_new)}
-    return _logits(params, h, config), pool, jnp.stack(counts)
+    return _logits(params, h, config), {"k": k_pool, "v": v_pool}, jnp.stack(counts)

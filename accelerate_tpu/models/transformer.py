@@ -424,28 +424,34 @@ def llama_paged_forward(params, ids, pool, block_tables, positions, config: Llam
     """Forward ``ids [B, S]`` at per-row ``positions [B, S]`` against the
     serving engine's paged pool ``{"k", "v"}: [L, num_blocks, block_size, Hkv,
     D]`` (``ops.flash_attention`` owns its format): each layer writes its keys
-    and values through the block tables and attends over the row's blocks.
+    and values through the block tables into its part of the whole stack and
+    attends over the row's blocks. The stack rides the layer loop as carry and
+    is never sliced or restacked, so a program that donates the pool writes in
+    place and holds no second pool among its temporaries. The pool may have
+    more layers than ``config`` (a truncated draft): layer ``i`` of the model
+    is layer ``i`` of the pool and the rest are left as they were.
     Returns ``(logits [B, S, vocab], new_pool)`` — the paged counterpart of
     ``generation._forward_cached``."""
     cos, sin = llama_rope(config)
 
-    def layer(h, xs):
-        layer_params, k_pool, v_pool = xs
+    def layer(carry, xs):
+        h, k_pool, v_pool = carry
+        layer_params, index = xs
 
         def attend(q, k, v):
             nonlocal k_pool, v_pool
             attn, k_pool, v_pool = paged_write_attend(
-                q, k, v, k_pool, v_pool, block_tables, positions, block_size)
+                q, k, v, k_pool, v_pool, index, block_tables, positions, block_size)
             return attn
 
         h, _ = llama_layer(layer_params, h, positions, cos, sin, config, attend)
-        return h, (k_pool, v_pool)
+        return (h, k_pool, v_pool), None
 
-    h, (k_new, v_new) = jax.lax.scan(
-        layer, params["embed_tokens"]["embedding"][ids],
-        (params["layers"], pool["k"], pool["v"]), unroll=config.unroll_layers,
+    (h, k_pool, v_pool), _ = jax.lax.scan(
+        layer, (params["embed_tokens"]["embedding"][ids], pool["k"], pool["v"]),
+        (params["layers"], jnp.arange(config.n_layers)), unroll=config.unroll_layers,
     )
-    return llama_head(params, h, config), {"k": k_new, "v": v_new}
+    return llama_head(params, h, config), {"k": k_pool, "v": v_pool}
 
 
 def llama_loss(params: dict, batch: dict, config: LlamaConfig, **fwd_kwargs) -> jax.Array:

@@ -667,10 +667,15 @@ NULL_BLOCK = 0
 
 def init_block_pool(config, num_blocks: int, block_size: int, dtype=jnp.bfloat16) -> dict:
     """Device pool ``{"k","v"}: [L, num_blocks, block_size, Hkv, D]``
-    (``num_blocks`` INCLUDES the reserved null block 0). ``config`` is any
-    model description with ``n_layers``, ``n_kv_heads`` and ``head_dim``; every
-    layer gets the same blocks, whatever its kind (a window layer keeps what
-    lies behind its window: an allocator by layer kind is ROADMAP B-m2's)."""
+    (``num_blocks`` INCLUDES the reserved null block 0, one a layer). ``config``
+    is any model description with ``n_layers``, ``n_kv_heads`` and ``head_dim``;
+    every layer gets the same blocks, whatever its kind (a window layer keeps
+    what lies behind its window: an allocator by layer kind is ROADMAP B-m2's).
+    This is the format at the programs' boundary (``engine._cow``,
+    ``serving/disagg.py``, a pool's checkpoint). Inside a step program a layer
+    addresses the stack flat: block ``b`` of layer ``l`` is block ``l *
+    num_blocks + b`` of ``[L * num_blocks, block_size, Hkv, D]``, and the
+    layer's null block is ``l * num_blocks`` (:func:`paged_write_attend`)."""
     shape = (config.n_layers, num_blocks, block_size, config.n_kv_heads, config.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
@@ -1277,23 +1282,32 @@ def paged_attention(q, k_pool, v_pool, block_tables, q_positions, scale=None, wi
     return paged_attention_gather(q, k_pool, v_pool, block_tables, q_positions, scale, window)
 
 
-def paged_write_attend(q, k, v, k_pool, v_pool, block_tables, positions, block_size: int,
-                       window=None):
-    """One layer's step against its slice of the pool, the one place that
-    knows where a position lives: token ``positions[b, s]`` of row ``b`` is
-    slot ``pos % block_size`` of physical block ``block_tables[b, pos //
-    block_size]``. Writes ``k``, ``v`` ``[B, S, Hkv, D]`` there (in the pool's
-    dtype), then attends ``q [B, S, H, D]`` over the row's blocks
-    (:func:`paged_attention`, with ``window`` or none). A position past the
-    table (a padded prefill tail) and every position of an idle slot (its
-    table is all null) write to the null block — a pad write may never land
-    in a live block. Returns ``(attn [B, S, H, D], k_pool, v_pool)``."""
+def paged_write_attend(q, k, v, k_pool, v_pool, layer, block_tables, positions,
+                       block_size: int, window=None):
+    """One layer's step against the whole pool, the one place that knows
+    where a position lives. ``k_pool``, ``v_pool`` are the stacks of ALL
+    layers, ``[L, num_blocks, block_size, Hkv, D]``, and ``layer`` (a Python
+    int or a traced scalar) says whose step this is: token ``positions[b, s]``
+    of row ``b`` is slot ``pos % block_size`` of block ``layer * num_blocks +
+    block_tables[b, pos // block_size]`` of the flat view ``[L * num_blocks,
+    block_size, Hkv, D]`` (a reshape of the leading two axes: a bitcast).
+    Writes ``k``, ``v`` ``[B, S, Hkv, D]`` there (in the pool's dtype), then
+    attends ``q [B, S, H, D]`` over the row's blocks (:func:`paged_attention`
+    on the flat view and the offset tables, with ``window`` or none). A
+    position past the table (a padded prefill tail) and every position of an
+    idle slot (its table is all null) write to the LAYER'S null block, ``layer
+    * num_blocks + NULL_BLOCK`` — a pad write may never land in a live block,
+    nor in another layer. The stack is never taken apart, so a program that
+    donates the pool updates it in place. Returns ``(attn [B, S, H, D],
+    k_pool, v_pool)``, the stacks in the shape they came in."""
+    shape = k_pool.shape
+    base = layer * shape[1]
     W = block_tables.shape[1]
     logical = positions // block_size
     phys = jnp.take_along_axis(block_tables, jnp.minimum(logical, W - 1), axis=1)
-    phys = jnp.where(logical < W, phys, NULL_BLOCK)
+    phys = base + jnp.where(logical < W, phys, NULL_BLOCK)
     off = positions % block_size
-    k_pool = k_pool.at[phys, off].set(k.astype(k_pool.dtype))
-    v_pool = v_pool.at[phys, off].set(v.astype(v_pool.dtype))
-    attn = paged_attention(q, k_pool, v_pool, block_tables, positions, window=window)
-    return attn, k_pool, v_pool
+    k_flat = k_pool.reshape(-1, *shape[2:]).at[phys, off].set(k.astype(k_pool.dtype))
+    v_flat = v_pool.reshape(-1, *shape[2:]).at[phys, off].set(v.astype(v_pool.dtype))
+    attn = paged_attention(q, k_flat, v_flat, base + block_tables, positions, window=window)
+    return attn, k_flat.reshape(shape), v_flat.reshape(shape)

@@ -260,13 +260,8 @@ class ServingEngine:
                 # same layer-i KV for accepted tokens (identical math), so
                 # the overwrite is value-exact, and rejected positions are
                 # re-written before any later read (scatter-then-attend).
-                dpool = {"k": pool["k"][:n_draft], "v": pool["v"][:n_draft]}
-                logits, dpool, _ = draft_forward(
-                    dparams, last_tok[:, None], dpool, tables, positions[:, None], None)
-                pool = {
-                    "k": pool["k"].at[:n_draft].set(dpool["k"]),
-                    "v": pool["v"].at[:n_draft].set(dpool["v"]),
-                }
+                logits, pool, _ = draft_forward(
+                    dparams, last_tok[:, None], pool, tables, positions[:, None], None)
                 folded = jax.vmap(jax.random.fold_in)(keys, token_idx)
                 tok = jax.vmap(select_one)(logits[:, -1], folded)
                 return pool, tok.astype(jnp.int32)

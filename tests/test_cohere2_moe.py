@@ -331,45 +331,6 @@ def test_without_a_window_both_paged_kernels_are_the_parents_bit_for_bit(kernel)
     assert (out, program) == PARENT[kernel]
 
 
-# ------------------------------------------------------------ the pool's one owner
-
-
-@pytest.mark.parametrize("model", ["llama", "cohere2_moe"])
-def test_a_pad_write_lands_in_the_null_block(model):
-    """Both models write the pool through ``ops.flash_attention.
-    paged_write_attend``: a position past the table (the padded tail of a
-    prefill chunk: 6 positions, a table of one block of 4) and every position
-    of an idle slot (its table all null) go to the null block. The live block
-    holds what a forward of the 4 real tokens alone leaves there, and no
-    other block is touched."""
-    from accelerate_tpu.models import LlamaConfig, init_llama
-    from accelerate_tpu.serving import NULL_BLOCK, init_block_pool
-
-    if model == "llama":
-        cfg = LlamaConfig(vocab_size=128, dim=32, n_layers=2, n_heads=4, n_kv_heads=2, max_seq_len=64)
-        params = init_llama(cfg, jax.random.PRNGKey(0))
-    else:
-        cfg = _config()
-        params = init_cohere2_moe(cfg, jax.random.PRNGKey(0))
-    bs, live, real = 4, 3, 4
-    ids = jnp.asarray(np.random.default_rng(2).integers(1, 128, (2, 6)), jnp.int32)
-    positions = jnp.broadcast_to(jnp.arange(6)[None], (2, 6))
-    tables = jnp.asarray([[live], [NULL_BLOCK]], jnp.int32)
-    valid = jnp.asarray([[True] * real + [False] * 2, [False] * 6])
-    _, pool, _ = cfg.paged_forward(
-        params, ids, init_block_pool(cfg, 6, bs, jnp.float32), tables, positions, valid, bs)
-    _, clean, _ = cfg.paged_forward(
-        params, ids[:1, :real], init_block_pool(cfg, 6, bs, jnp.float32), tables[:1],
-        positions[:1, :real], valid[:1, :real], bs)
-    for side in ("k", "v"):
-        got, want = np.asarray(pool[side]), np.asarray(clean[side])
-        np.testing.assert_allclose(got[:, live], want[:, live], rtol=1e-5, atol=1e-6)
-        assert np.abs(want[:, live]).min() > 0  # all four slots of the live block written
-        assert np.abs(got[:, NULL_BLOCK]).max() > 0  # the pad writes went somewhere: here
-        untouched = [b for b in range(6) if b not in (NULL_BLOCK, live)]
-        assert not got[:, untouched].any() and not want[:, NULL_BLOCK].any()
-
-
 # ------------------------------------------------------------------- what refuses
 
 

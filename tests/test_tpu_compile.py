@@ -194,3 +194,78 @@ def test_grouped_matmul_compiles_for_v5e(one_chip, rows):
         ((rows, 4096), BF16), ((16, 4096, 4096), BF16), ((16,), jnp.int32),
     )
     assert "moe_gmm" in text
+
+
+# ---- the whole step programs' memory: a layer writes into the one pool.
+# Decode and prefill-chunk forwards of both model kinds at the serve cells'
+# widths and a reduced depth (4 layers; abstract shapes, nothing allocated),
+# the pool donated, the kernels dispatched: the program aliases the whole pool
+# and its temporaries stay under one layer's K + V. A forward that takes a
+# layer's slice out of the stack and stacks the layers back (or feeds the stack
+# to ``lax.scan`` as ``xs``) reads more than the whole pool there: the compiler
+# copies it, in every program, and the chip then holds it twice.
+
+POOL_LAYERS, POOL_BLOCK = 4, 16
+
+
+def _llama_cell(unroll_layers):
+    from accelerate_tpu.models import LlamaConfig, init_llama
+
+    config = LlamaConfig(  # mistral-7b's widths (benchmarks/chip/configs/mistral-7b.json)
+        vocab_size=32768, dim=4096, n_layers=POOL_LAYERS, n_heads=32, n_kv_heads=8,
+        ffn_dim=14336, max_seq_len=2304, rope_theta=1e6, unroll_layers=unroll_layers)
+    return config, init_llama, 3201, 144, 64  # chat-sat's pool, table and slots
+
+
+def _cohere_cell():
+    from accelerate_tpu.models.cohere2_moe import Cohere2MoeConfig, init_cohere2_moe
+
+    config = Cohere2MoeConfig(  # command-a-plus's (benchmarks/chip/configs/command-a-plus.json)
+        vocab_size=32768, dim=4096, n_layers=POOL_LAYERS, n_heads=128, n_kv_heads=8,
+        head_dim=128, expert_dim=4096, num_experts=128, experts_per_token=8,
+        num_shared_experts=4, sliding_window=4096, experts_held=16, max_seq_len=6400)
+    return config, init_cohere2_moe, 6401, 400, 32  # rag-sat's
+
+
+# kind -> () -> (config, init, blocks in the pool, the table's width, decode rows)
+POOL_KINDS = {
+    "llama-unrolled": lambda: _llama_cell(True),
+    "llama-scanned": lambda: _llama_cell(False),
+    "cohere2_moe": _cohere_cell,  # its layers are a Python loop
+}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill512"])
+@pytest.mark.parametrize("kind", list(POOL_KINDS))
+def test_step_program_writes_the_donated_pool_in_place(one_chip, monkeypatch, kind, program):
+    from accelerate_tpu.telemetry.memory import compiled_memory_analysis
+
+    config, init, num_blocks, W, rows = POOL_KINDS[kind]()
+    B, S = (rows, 1) if program == "decode" else (1, 512)
+    # the described chip is not attached: the dispatch asks the backend, so say "tpu"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def abstract(make):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, BF16 if jnp.issubdtype(x.dtype, jnp.floating) else x.dtype,
+                sharding=one_chip), jax.eval_shape(make))
+
+    params = abstract(lambda: init(config, jax.random.PRNGKey(0)))
+    pool = abstract(lambda: fa.init_block_pool(config, num_blocks, POOL_BLOCK, BF16))
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def step(params, pool, ids, tables, positions):
+        logits, pool, _ = config.paged_forward(
+            params, ids, pool, tables, positions, jnp.ones(ids.shape, bool), POOL_BLOCK)
+        return pool, logits[:, -1].argmax(-1)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, pool, ints(B, S), ints(B, W), ints(B, S)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("paged_decode" if program == "decode" else "paged_prefill") in text
+    memory = compiled_memory_analysis(compiled)
+    layer_kv = 2 * pool["k"].size // POOL_LAYERS * 2  # one layer's K + V, bf16
+    assert memory["alias_bytes"] == POOL_LAYERS * layer_kv, memory
+    assert memory["temp_bytes"] < layer_kv, (memory, layer_kv)
