@@ -17,6 +17,11 @@ from .cohere2_moe import (
     cohere2_moe_forward,
     init_cohere2_moe,
 )
+from .mellum import (
+    MellumConfig,
+    init_mellum,
+    mellum_forward,
+)
 from .resnet import (
     ResNetConfig,
     init_resnet,

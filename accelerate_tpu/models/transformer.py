@@ -83,11 +83,39 @@ def layer_norm(x, scale, bias, eps=1e-6):
 # RoPE
 
 
+def _rope_table(inv, max_seq: int, scale: float = 1.0):
+    """``(cos, sin) [max_seq, len(inv)]`` of the angles ``position * inv``, times ``scale``."""
+    freqs = np.outer(np.arange(max_seq), inv)
+    return (np.cos(freqs) * scale).astype(np.float32), (np.sin(freqs) * scale).astype(np.float32)
+
+
 def rope_frequencies(head_dim: int, max_seq: int, theta: float = 10000.0):
-    inv = 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
-    t = np.arange(max_seq)
-    freqs = np.outer(t, inv)
-    return np.cos(freqs).astype(np.float32), np.sin(freqs).astype(np.float32)
+    return _rope_table(1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim)), max_seq)
+
+
+def yarn_rope_frequencies(head_dim: int, max_seq: int, theta: float, *, factor: float,
+                          original_max_seq: int, beta_fast: float = 32.0, beta_slow: float = 1.0,
+                          attention_factor: Optional[float] = None):
+    """YaRN's table in :func:`rope_frequencies`' shape (``[max_seq, head_dim /
+    2]`` cos and sin), so :func:`apply_rope` takes it unchanged. Pair ``i``
+    turns ``r`` times over ``original_max_seq`` positions at ``i = corr(r) =
+    head_dim * ln(original_max_seq / (2 pi r)) / (2 ln theta)``: pairs below
+    ``floor(corr(beta_fast))`` keep their frequency, pairs above
+    ``ceil(corr(beta_slow))`` are interpolated by ``factor``, those between
+    are blended linearly. The frequencies hold at every position, not only
+    past ``original_max_seq``. ``attention_factor`` (``0.1 ln factor + 1``
+    where None) multiplies cos AND sin: a layer's scores grow by its square."""
+    def corr(rotations):
+        return head_dim * np.log(original_max_seq / (2 * np.pi * rotations)) / (2 * np.log(theta))
+
+    low = max(int(np.floor(corr(beta_fast))), 0)
+    high = min(int(np.ceil(corr(beta_slow))), head_dim - 1)
+    ramp = np.clip((np.arange(head_dim // 2) - low) / ((high if high != low else high + 0.001) - low),
+                   0, 1)
+    plain = 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
+    if attention_factor is None:
+        attention_factor = 0.1 * np.log(factor) + 1.0
+    return _rope_table((1 - ramp) * plain + ramp * plain / factor, max_seq, attention_factor)
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array, positions=None) -> jax.Array:
@@ -305,7 +333,7 @@ def llama_ffn(layer_params: dict, x: jax.Array, config: LlamaConfig, mesh=None):
 
 
 def llama_layer(layer_params: dict, h: jax.Array, positions, cos, sin, config: LlamaConfig,
-                attend, mesh=None, pin=lambda h: h):
+                attend, mesh=None, pin=lambda h: h, ffn=None):
     """One decoder layer over ``h [B, S, D]``, the one statement of its math:
     norm, QKV projection, RoPE at ``positions`` (``[B, S]``, or None for
     ``0..S-1``), attention, output projection, norm, FFN, the two residuals.
@@ -318,7 +346,11 @@ def llama_layer(layer_params: dict, h: jax.Array, positions, cos, sin, config: L
     the expert layer's activations (:func:`llama_ffn`); ``pin`` is applied to
     the residual stream after each residual (:func:`llama_forward` pins it
     over the batch and sequence axes of its mesh; the decode paths place
-    their batch themselves, ``generation.generation_shardings``)."""
+    their batch themselves, ``generation.generation_shardings``).
+    ``ffn(layer_params, x) -> (y, aux)`` is the layer's second half where it
+    is not :func:`llama_ffn` (``models/mellum.py``: routed experts, ``aux``
+    their counts); ``config`` then needs only ``n_heads``, ``n_kv_heads``,
+    ``head_dim`` and ``norm_eps``."""
     B, S, _ = h.shape
     x = rms_norm(h, layer_params["attn_norm"]["scale"], config.norm_eps)
     q = _proj(layer_params["wq"], x).reshape(B, S, config.n_heads, config.head_dim)
@@ -328,7 +360,7 @@ def llama_layer(layer_params: dict, h: jax.Array, positions, cos, sin, config: L
     k = apply_rope(k, cos, sin, positions=positions)
     h = pin(h + _proj(layer_params["wo"], attend(q, k, v).reshape(B, S, -1)))
     x = rms_norm(h, layer_params["mlp_norm"]["scale"], config.norm_eps)
-    y, aux = llama_ffn(layer_params, x, config, mesh=mesh)
+    y, aux = ffn(layer_params, x) if ffn else llama_ffn(layer_params, x, config, mesh=mesh)
     return pin(h + y), aux
 
 

@@ -23,6 +23,8 @@ cumsum vectorizes over groups instead of serializing across the global batch.
 
 from __future__ import annotations
 
+from functools import partial
+
 import numpy as np
 
 
@@ -170,26 +172,29 @@ def init_held_experts(key, d_model: int, d_ff: int, num_experts: int, held: int)
     }
 
 
-def route_top_k(router_kernel, x, top_k: int):
-    """Sigmoid scores over every expert, the ``top_k`` largest, their weights
+def route_top_k(router_kernel, x, top_k: int, scoring: str = "sigmoid"):
+    """Scores over every expert (``scoring``: ``sigmoid``, each expert's own,
+    or ``softmax`` over them all), the ``top_k`` largest, their weights
     normalised to sum to 1: ``(expert ids [N, k], weights [N, k])``. The
     arithmetic is float32 (a TPU's default matmul precision is not): the gap
     between the k-th and the next score is small against bf16 rounding."""
     import jax
     import jax.numpy as jnp
 
+    score = {"sigmoid": jax.nn.sigmoid, "softmax": partial(jax.nn.softmax, axis=-1)}[scoring]
     logits = jnp.dot(x.astype(jnp.float32), router_kernel.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    scores, experts = jax.lax.top_k(jax.nn.sigmoid(logits), top_k)
+    scores, experts = jax.lax.top_k(score(logits), top_k)
     return experts, scores / jnp.sum(scores, axis=-1, keepdims=True)
 
 
-def held_expert_ffn(params, x, *, top_k: int, first_expert: int = 0, valid=None):
+def held_expert_ffn(params, x, *, top_k: int, first_expert: int = 0, valid=None,
+                    scoring: str = "sigmoid"):
     """The routed experts' part of an expert layer that this chip computes:
     ``x [..., D] -> (y [..., D], counts [3])``.
 
-    The router scores all ``E = router.shape[-1]`` experts and picks ``top_k``
-    a token (:func:`route_top_k`); the ``held = w_gate.shape[0]`` experts
+    The router scores all ``E = router.shape[-1]`` experts by ``scoring`` and
+    picks ``top_k`` a token (:func:`route_top_k`); the ``held = w_gate.shape[0]`` experts
     ``first_expert .. first_expert + held`` live here. The (token, expert)
     pairs that land on them are sorted by expert and go through one grouped
     matmul a weight stack (:func:`accelerate_tpu.ops.grouped_matmul.
@@ -211,7 +216,7 @@ def held_expert_ffn(params, x, *, top_k: int, first_expert: int = 0, valid=None)
     lead, D = x.shape[:-1], x.shape[-1]
     x2 = x.reshape(-1, D)
     held = params["w_gate"]["kernel"].shape[0]
-    experts, weights = route_top_k(params["router"]["kernel"], x2, top_k)
+    experts, weights = route_top_k(params["router"]["kernel"], x2, top_k, scoring)
     local = (experts >= first_expert) & (experts < first_expert + held)
     if valid is not None:
         local = local & valid.reshape(-1, 1)

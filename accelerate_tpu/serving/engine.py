@@ -945,14 +945,19 @@ class ServingEngine:
         call's tokens): per layer the (token, expert) pairs that landed on the
         experts held here, the held experts hit, and the most one of them
         got, for the ``tokens`` real tokens of a ``decode`` batch or a
-        ``prefill`` chunk. Nothing for a model that counts nothing."""
+        ``prefill`` chunk; ``held`` and ``top_k`` are the config's
+        ``experts_held`` and ``experts_per_token`` (what tells a chip's share
+        of a wide router from a layer held whole). Nothing for a model that
+        counts nothing."""
         if counts is None:
             return
         counts = np.asarray(counts)
         pairs, hit, load = (counts[:, i].tolist() for i in range(3))
         _tracing.record(
             "atpu.serve.moe", t_ns, t_ns, engine=self.engine_id, step=self.steps, kind=kind,
-            tokens=tokens, local_pairs=pairs, experts_hit=hit, max_expert_load=load, **key)
+            tokens=tokens, local_pairs=pairs, experts_hit=hit, max_expert_load=load,
+            held=getattr(self.config, "experts_held", None),
+            top_k=getattr(self.config, "experts_per_token", None), **key)
         self.moe["tokens"] += tokens
         self.moe["local_pairs"] += sum(pairs)
         self.moe["experts_hit"] += sum(hit)

@@ -196,6 +196,22 @@ def test_grouped_matmul_compiles_for_v5e(one_chip, rows):
     assert "moe_gmm" in text
 
 
+@pytest.mark.parametrize("rows", [256, 16384], ids=["decode32x8", "chunk2048x8"])
+@pytest.mark.parametrize("K,N", [(2304, 896), (896, 2304)], ids=["gate-up", "down"])
+def test_grouped_matmul_compiles_at_64_narrow_experts(one_chip, rows, K, N):
+    """mellum2-12b's expert layer: 64 experts of 2304 x 896, whose gate and up
+    products fall to 128-column weight tiles (the only multiple of 128 that
+    divides 896 and keeps ``[2304, tn]`` under 2 MB) and whose down product
+    takes 1152."""
+    gm = importlib.import_module("accelerate_tpu.ops.grouped_matmul")
+    assert gm._tile_n(K, N, 2) == (128 if N == 896 else 1152)
+    text = _compile(
+        gm.grouped_matmul_kernel, one_chip,
+        ((rows, K), BF16), ((64, K, N), BF16), ((64,), jnp.int32),
+    )
+    assert "moe_gmm" in text
+
+
 # ---- the whole step programs' memory: a layer writes into the one pool.
 # Decode and prefill-chunk forwards of both model kinds at the serve cells'
 # widths and a reduced depth (4 layers; abstract shapes, nothing allocated),
@@ -227,11 +243,22 @@ def _cohere_cell():
     return config, init_cohere2_moe, 6401, 400, 32  # rag-sat's
 
 
+def _mellum_cell():
+    from accelerate_tpu.models.mellum import MellumConfig, init_mellum
+
+    config = MellumConfig(  # mellum2-12b's (benchmarks/chip/configs/mellum2-12b.json), one period
+        vocab_size=98304, dim=2304, n_layers=POOL_LAYERS, n_heads=32, n_kv_heads=4, head_dim=128,
+        expert_dim=896, num_experts=64, experts_per_token=8, sliding_window=1024,
+        max_seq_len=16896, yarn=(("factor", 16), ("original_max_seq", 8192)))
+    return config, init_mellum, 14401, 1056, 32  # code-sat's
+
+
 # kind -> () -> (config, init, blocks in the pool, the table's width, decode rows)
 POOL_KINDS = {
     "llama-unrolled": lambda: _llama_cell(True),
     "llama-scanned": lambda: _llama_cell(False),
     "cohere2_moe": _cohere_cell,  # its layers are a Python loop
+    "mellum": _mellum_cell,       # likewise, through `llama_layer`
 }
 
 
