@@ -680,40 +680,70 @@ def init_block_pool(config, num_blocks: int, block_size: int, dtype=jnp.bfloat16
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
-# VMEM the decode kernel plans for a grid step: its N blocks of K and of V,
-# double buffered, and their f32 working set. On the v5e, at the serve cells'
-# shapes, N 2 and 4 were the fastest and 8 was 7-14 % slower (my chip runs, PR
-# 26): a grid step is cheap, and a row's last step computes on what it masks.
-# This gives those shapes N = 4.
-_DECODE_VMEM_BYTES = 3 * 1024 * 1024
+# Keys a grid step of the decode kernel folds, and the VMEM it may plan for
+# them. More keys a step spread its start (0.35 us) and the row's init and
+# finalize over more blocks, against a row's last step, which fetches and
+# computes on what it masks (half a step a row), and against a process's start:
+# each of the ``2 N`` K and V BlockSpecs is an index map traced and lowered for
+# every kernel instance of every decode program, compile cache warm or not. On
+# the v5e, blocks of 16, bf16 (my chip runs, PR 34: the kernel's ms a call at N
+# 4 / 8 / 16): 64 rows of 46 live blocks at 32 / 8 heads 0.546 / 0.456 / 0.419;
+# 32 rows of 124 at 128 / 8 heads 0.935 / 0.740 / 0.652; 32 rows of 290 at 32 /
+# 4 heads 1.583 / 1.211 / 1.056, inside a window of 1024 (64 blocks a row) 0.371
+# / 0.297 / 0.277; 3 live rows and 29 padded slots 0.047 / 0.056 / 0.079; N 32
+# level with 16 or behind it. Tracing and lowering 16 instances (one program of
+# `mistral-7b`'s six) took 0.95 s at N 8, 1.8 s at 16 and 1.4 s for the body
+# this replaced (this sandbox's CPU, for a described v5e). 128 keys is N = 8 at
+# blocks of 16: 8-13 % behind 16 in a saturated batch, ahead of it where most
+# slots are padding, and some 5 s less of a `mistral-7b` cell's `setup_s`. The
+# bytes only bind where a block is far wider than the serve cells' (217 KB of
+# VMEM a block at 32 / 8 heads of 128 with its share of the scores, 290 KB at
+# 128 / 8).
+_DECODE_STEP_KEYS = 128
+_DECODE_VMEM_BYTES = 6 * 1024 * 1024
 
 
 def _decode_group_blocks(block_size, Hkv, D, dtype, groups, W) -> int:
     """Blocks fetched and folded per grid step of the decode walk (``N``),
-    read from shapes: how many fit :data:`_DECODE_VMEM_BYTES`, a block costing
-    its four pool-dtype copies (K and V, double buffered) and its f32 working
-    set (K and V upcast, and the ``groups`` score and value products), every
-    ``[Hkv, D]`` tile padded to 8 sublanes and 128 lanes. Clamped to ``[1,
-    W]``."""
-    tile = block_size * -(-Hkv // 8) * 8 * -(-D // 128) * 128  # elements
-    per_block = 4 * tile * jnp.dtype(dtype).itemsize + (2 + 2 * groups) * tile * 4
-    return max(1, min(W, _DECODE_VMEM_BYTES // per_block))
+    read from shapes: :data:`_DECODE_STEP_KEYS` keys a step, no more than fit
+    :data:`_DECODE_VMEM_BYTES`, a block costing six pool-dtype copies of its
+    ``[block_size * Hkv, D]`` rows (K and V, double buffered, and as the
+    step's matmul operands; ``D`` padded to 128 lanes) and its columns of the
+    ``groups * Hkv`` query heads' scores (f32) and probabilities (pool dtype).
+    Clamped to ``[1, W]``."""
+    itemsize = jnp.dtype(dtype).itemsize
+    rows = block_size * Hkv
+    per_block = 6 * rows * -(-D // 128) * 128 * itemsize + groups * Hkv * rows * (4 + itemsize)
+    return max(1, min(W, _DECODE_STEP_KEYS // block_size, _DECODE_VMEM_BYTES // per_block))
+
+
+def _div_rem(x, n: int):
+    """``(x // n, x % n)`` of a non-negative int32 array by a static ``n``:
+    shifts where ``n`` is a power of two (every head count the serve cells
+    have), the truncating division otherwise."""
+    if n & (n - 1) == 0:
+        return x >> (n.bit_length() - 1), x & (n - 1)
+    return jax.lax.div(x, n), jax.lax.rem(x, n)
 
 
 def _paged_decode_kernel(
-    walk_ref,    # [B, steps*N] int32 scalar-prefetch: the block tables, every
-                 # entry past a row's last live one replaced by that one
+    walk_ref,    # [B * table] int32 scalar-prefetch: the block tables, each row
+                 # padded to ``table`` entries, every entry past a row's last
+                 # live one replaced by that one
     lens_ref,    # [B] int32 scalar-prefetch: per-row live kv length
     row_ref,     # [B*steps] int32 scalar-prefetch: the row of grid step i
     group_ref,   # [B*steps] int32 scalar-prefetch: its group within the row
-    *refs,       # with a window first_ref [B] int32 scalar-prefetch, the block
-                 # a row's walk starts at; then q_ref [1, G, Hkv, D], the row's
-                 # query, query-group major; N K blocks, N V blocks [1,
-                 # block_size, Hkv, D]; o_ref [1, G, Hkv, D] and the
-                 # online-softmax carries in VMEM: acc [G, Hkv, D], m [G, Hkv,
-                 # 1], l [G, Hkv, 1], f32
+    base_ref,    # [B*steps] int32 scalar-prefetch: where in ``walk_ref`` the
+                 # step's first block stands, ``row * table + entry`` (what the
+                 # K and V index maps read)
+    q_ref,       # [1, H, D]: the row's query, head ``h`` of key head ``h // G``
+    *refs,       # N K slabs, N V slabs [1, block_size * Hkv, D] (a block's
+                 # rows are (token, key head), token major); o_ref [1, H, D];
+                 # the online-softmax carries in VMEM, f32: acc [H, D], m and
+                 # l [H, 1]
     block_size: int,
     group: int,
+    table: int,
     scale: float,
     window: Optional[int] = None,
 ):
@@ -724,80 +754,88 @@ def _paged_decode_kernel(
     row ``b`` takes ``ceil(live_b / N)`` steps, ``live_b = ceil(kv_len_b /
     block_size)``, one row after the other (``row_ref``/``group_ref`` say
     which step is whose). The pool comes in through ``N`` BlockSpecs a side
-    whose index maps fetch physical blocks ``walk[b, g*N : g*N+N]``; the
-    pipeline fetches the next step's blocks, the next row's first ones too,
-    while this step computes. So a row costs its live blocks, rounded up to
-    ``N``, whatever the width of the bucketed table: the table's padding is
-    neither fetched nor computed on. Where a row's last step reaches past its
-    last live block, ``walk`` repeats that block (already there, the row's
-    own, never the null block's or another row's values), and those positions
-    are masked like the tail of the last live block (``pos < kv_len``, the mask
-    of the gather reference). A padded slot (``kv_len`` 1, all-null table) is
-    one step on one fetched block.
+    whose index maps fetch physical blocks ``walk[base[i] + j]``, the row's
+    table entries ``g*N .. g*N+N-1``; the pipeline fetches the next step's
+    blocks, the next row's first ones too, while this step computes. So a row
+    costs its live blocks, rounded up to ``N``, whatever the width of the
+    bucketed table: the table's padding is neither fetched nor computed on.
+    Where a row's last step reaches past its last live block, ``walk`` repeats
+    that block (already there, the row's own, never the null block's or
+    another row's values), and those positions are masked like the tail of the
+    last live block (``pos < kv_len``, the mask of the gather reference). A
+    padded slot (``kv_len`` 1, all-null table) is one step on one fetched
+    block.
 
-    The arithmetic is f32 on the VPU, per KV head: ``q`` arrives as ``[G,
-    Hkv, D]`` (``G`` query heads to a KV head), so a token's ``[Hkv, D]`` tile
-    of K meets each of the ``G`` query tiles as it lies, and no copy of K at
-    the query heads' width exists. Scores are lane reductions kept as ``[..,
-    Hkv, 1]`` columns, the shape the value product broadcasts from. The ``G``
-    heads are unrolled: their chains interleave, and rolled into a loop the
-    kernel ran 2.4 times longer (my chip runs, PR 26).
+    A step is two matmuls in the pool's dtype, f32 accumulation, with no copy
+    of K or V: the step's ``N`` blocks stand as they lie in the pool, one slab
+    ``[N * block_size * Hkv, D]`` whose rows are (token, key head), and ALL
+    ``H`` query heads meet all of its rows, ``[H, D] x [rows, D]^T``. A query
+    head's scores against another key head's rows are masked to -inf like dead
+    positions, so their probabilities are exact zeros in the value product
+    ``[H, rows] x [rows, D]``. That spends ``Hkv`` times the MXU's work (8.4
+    MFLOP a step of 256 keys at 32 heads: 0.04 us at peak) and ``Hkv`` times
+    the exponentials to save the relayout of K and V to head-major, which was
+    the larger cost at every head ratio measured: on the v5e, bf16, blocks of
+    16 (my chip runs, PR 34; the kernel's ms a call for 64 rows / 2.9 k live
+    blocks at 32 / 8 heads, 32 / 4.0 k at 128 / 8, 32 / 9.3 k at 32 / 4, every
+    form on the walk it then had, three SMEM reads a map and the blocks
+    reshaped in the kernel) this form 0.544 / 0.855 / 1.675 at N 8 and 0.504 /
+    0.758 / 1.500 at 16; a key head a loop step over a head-major copy (``[G,
+    D] x [D, keys]``, the copy a bf16 transpose, N 8) 0.818 / 1.116 / 1.986,
+    through an f32 transpose 1.05 / 1.41 / 2.30 (host clock), per-head strided
+    reads 1.14 / - / 6.2 (host clock); the scores held keys by queries (N 16)
+    0.696 / 0.830 / 1.691; a decode row as a one-query tile of the prefill
+    kernel 0.738 / 0.937 / 1.790; the f32 multiply-reduce on the VPU this
+    replaced 1.209 / 4.715 / 6.216. The scale is applied to the f32 scores,
+    the online softmax (``m``, ``l``, ``acc``) is f32, and the probabilities
+    are rounded to the pool's dtype for the value product, as
+    ``ops.attention.masked_attention`` rounds them (an f32 pool stays f32 all
+    through).
 
     With a ``window`` a query sees the last ``window`` positions only, itself
     among them (``kv_len - window <= pos < kv_len``): the walk starts at block
-    ``first = max(0, kv_len - window) // block_size`` (the wrapper's ``walk``
-    begins there), so the blocks wholly behind the window are never fetched,
-    and the head of block ``first`` is masked like the tail of the last."""
+    ``first = max(0, kv_len - window) // block_size`` (``base`` points there),
+    so the blocks wholly behind the window are never fetched, and the head of
+    block ``first`` is masked like the tail of the last."""
     from jax.experimental import pallas as pl  # deferred with pallas_call's
 
     i = pl.program_id(0)
-    if window is not None:
-        first_ref, *refs = refs
-    q_ref, *refs = refs
     k_refs, v_refs = refs[:group], refs[group : 2 * group]
     o_ref, acc_ref, m_ref, l_ref = refs[2 * group :]
-    g = group_ref[i]
-    kv_len = lens_ref[row_ref[i]]
+    row = row_ref[i]
+    kv_len = lens_ref[row]
+    # position of the step's first key: its table entry times the block
+    start = (base_ref[i] - row * table) * block_size
 
-    @pl.when(g == 0)
+    @pl.when(group_ref[i] == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0].astype(jnp.float32) * scale             # [G, Hkv, D]
-    k = jnp.concatenate([r[0] for r in k_refs]).astype(jnp.float32)  # [N*bs, Hkv, D]
-    v = jnp.concatenate([r[0] for r in v_refs]).astype(jnp.float32)
-    # the walk begins at position 0, with a window at the row's block `first`
-    walk_start = 0 if window is None else first_ref[row_ref[i]] * block_size
-    start = g * group * block_size
-    if window is not None:
-        start = walk_start + start
-    pos = start + jax.lax.broadcasted_iota(
-        jnp.int32, (k.shape[0], k.shape[1], 1), 0
-    )
+    k = jnp.concatenate([r[0] for r in k_refs])          # [rows, D]
+    v = jnp.concatenate([r[0] for r in v_refs])
+    H, rows = q_ref.shape[1], k.shape[0]
+    Hkv = rows // (group * block_size)
+    s = _dot_nt2(q_ref[0], k) * scale                    # [H, rows] f32
+    token, key_head = _div_rem(jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1), Hkv)
+    pos = start + token
     # true somewhere in a row's first step (at its first position without a
     # window, at `kv_len - window` with one): `m_new` is finite
     live = pos < kv_len
     if window is not None:
         live = live & (pos >= kv_len - window)
-    for h in range(q.shape[0]):  # one query head of every KV head
-        # s[t, kv] = q[h, kv] . k[t, kv]: multiply-reduce over the lanes
-        s = jnp.sum(q[h] * k, axis=-1, keepdims=True)    # [N*bs, Hkv, 1]
-        s = jnp.where(live, s, -jnp.inf)
-        m_prev = m_ref[h]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))  # [Hkv, 1]
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                           # masked -> 0
-        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=0)
-        acc_ref[h] = acc_ref[h] * alpha + jnp.sum(p * v, axis=0)
-        m_ref[h] = m_new
+    own = key_head == _div_rem(jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0), H // Hkv)[0]
+    s = jnp.where(own & live, s, -jnp.inf)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))  # [H, 1]
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)                               # masked -> 0
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + _dot_nn2(p.astype(v.dtype), v)
+    m_ref[...] = m_new
 
-    end = (g + 1) * group * block_size
-    if window is not None:
-        end = walk_start + end
-
-    @pl.when(end >= kv_len)  # the row's last step
+    @pl.when(start + group * block_size >= kv_len)  # the row's last step
     def _finalize():
         o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
@@ -814,11 +852,24 @@ def paged_attention_decode(
     from the shapes (:func:`_decode_group_blocks`) and the length of the grid
     from ``kv_lens``. The gathered ``[B, W*block_size]`` cache the XLA
     reference materializes per layer never exists, and the padding of a
-    bucketed table is neither fetched nor computed on. On one v5e at 32/8
-    heads of 128, blocks of 16, bf16 (my chip runs, PR 26): 1.2-1.3 ms a call
-    for 64 rows holding 3000 live blocks of a 64 x 144 table (8.1-8.2 ms on
-    the ``(B, W)`` one-block grid this replaced), 3.5 ms with the table full,
-    whose 604 MB need 0.74 ms: 0.38 us a block is VPU arithmetic.
+    bucketed table is neither fetched nor computed on. The kernel reads the
+    pool as ``[num_blocks, block_size * Hkv, D]`` (at a head width that is a
+    multiple of 128 a bitcast, by the compiled programs) and the queries and
+    outputs as ``[B, H, D]``: no transpose stands round the call. One form
+    serves every head ratio (the body's docstring has what the others read).
+    On one v5e, bf16, blocks of 16 (my chip runs, PR 34; the kernel's device
+    time a call, beside the f32 VPU body it replaced and what the bytes
+    allow): 64 rows / 2.9 k live blocks of a 144-wide table at 32 / 8 heads of
+    128 **0.456 ms** (1.209; 0.235), 0.155 us a block; 32 rows / 4.0 k of 400
+    at 128 / 8 **0.740 ms** (4.715; 0.316), 0.187; 32 rows / 9.3 k of 1056 at
+    32 / 4 **1.211 ms** (6.216; 0.370), 0.131, and inside a window of 1024
+    (2.0 k blocks) **0.297 ms** (1.387; 0.082), 0.145; 3 live rows and 29
+    padded slots of a 48-wide table 0.056 ms (0.098). With the matmuls and the
+    softmax taken out the same walk took 0.30 / 0.37 / 0.72 / 0.20 ms (N 16):
+    fetching through ``2 N`` BlockSpecs is most of what is left.
+    The walk's integer arrays are selects over the padded tables and one
+    gather of ``B`` entries (an elementwise gather of the whole table, which
+    this replaced, took 0.13 ms a layer at 64 x 144 and 0.34 at 32 x 1056).
     A static ``window`` (None: the program as it was, named ``paged_decode``)
     makes a row see its last ``window`` positions only and walk only the blocks
     that hold them, under the name ``paged_decode_win``, so that a trace tells
@@ -835,73 +886,71 @@ def paged_attention_decode(
     W = block_tables.shape[1]
     if H % Hkv:
         raise ValueError(f"q heads {H} not a multiple of kv heads {Hkv}")
-    G = H // Hkv
     sm_scale = (1.0 / math.sqrt(D)) if scale is None else float(scale)
-    N = _decode_group_blocks(block_size, Hkv, D, k_pool.dtype, G, W)
+    N = _decode_group_blocks(block_size, Hkv, D, k_pool.dtype, H // Hkv, W)
     steps = pl_.cdiv(W, N)  # of a row whose table is full
+    table = W + N           # a row of the walk: a last step may reach N - 1 past the table
 
     # The walk, from the lengths (the same small integer arrays for every
     # layer of a step). A longer kv_len than the table holds reads the table.
     kv_lens = jnp.minimum(jnp.asarray(kv_lens, jnp.int32).reshape(B), W * block_size)
     live = jnp.maximum(pl_.cdiv(kv_lens, block_size), 1)  # table entries, >= 1
-    entry = jnp.minimum(jnp.arange(steps * N, dtype=jnp.int32), live[:, None] - 1)
-    first = ()
-    if window is not None:  # the walk starts at the block that holds `kv_len - window`
-        first_block = jnp.maximum(kv_lens - int(window), 0) // block_size
-        live = live - first_block
-        entry = first_block[:, None] + jnp.minimum(
-            jnp.arange(steps * N, dtype=jnp.int32), live[:, None] - 1)
-        first = (first_block,)
-    walk = jnp.take_along_axis(block_tables.astype(jnp.int32), entry, axis=1)
-    ends = jnp.cumsum(pl_.cdiv(live, N))  # grid steps up to and with row b
+    tables = block_tables.astype(jnp.int32)
+    last = jnp.take_along_axis(tables, live[:, None] - 1, axis=1)
+    walk = jnp.where(jnp.arange(table, dtype=jnp.int32) < live[:, None],
+                     jnp.pad(tables, ((0, 0), (0, N))), last)
+    first = 0  # the table entry a row's walk starts at
+    if window is not None:  # the block that holds `kv_len - window`
+        first = jnp.maximum(kv_lens - int(window), 0) // block_size
+    ends = jnp.cumsum(pl_.cdiv(live - first, N))  # grid steps up to and with row b
     step = jnp.arange(B * steps, dtype=jnp.int32)
     done = step[:, None] >= ends  # [B*steps, B]: row b ends before this step
     # (steps past ends[-1] never run; their entries only have to stay in range)
     step_row = jnp.minimum(jnp.sum(done, axis=1, dtype=jnp.int32), B - 1)
     step_group = step - jnp.max(jnp.where(done, ends, 0), axis=1)
+    step_entry = step_group * N if window is None else first[step_row] + step_group * N
+    step_base = step_row * table + jnp.minimum(step_entry, W - 1)
 
-    def pool_block(j):
+    def pool_block(j):  # two SMEM reads a map: lowering them is what a process's start pays
         return pl_.BlockSpec(
-            (1, block_size, Hkv, D),
-            lambda i, walk, lens, row, group, *_: (walk[row[i], group[i] * N + j], 0, 0, 0),
+            (1, block_size * Hkv, D),
+            lambda i, walk, lens, row, group, base: (walk[base[i] + j], 0, 0),
         )
 
-    row = pl_.BlockSpec(
-        (1, G, Hkv, D), lambda i, walk, lens, row, group, *_: (row[i], 0, 0, 0)
-    )
+    row = pl_.BlockSpec((1, H, D), lambda i, walk, lens, row, group, base: (row[i], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4 + len(first),
+        num_scalar_prefetch=5,
         grid=(ends[-1],),
         in_specs=[row] + 2 * [pool_block(j) for j in range(N)],
         out_specs=row,
         scratch_shapes=[
-            pltpu.VMEM((G, Hkv, D), jnp.float32),
-            pltpu.VMEM((G, Hkv, 1), jnp.float32),
-            pltpu.VMEM((G, Hkv, 1), jnp.float32),
+            pltpu.VMEM((H, D), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
         ],
     )
     kernel = partial(
-        _paged_decode_kernel, block_size=block_size, group=N, scale=sm_scale,
+        _paged_decode_kernel, block_size=block_size, group=N, table=table, scale=sm_scale,
         window=None if window is None else int(window),
     )
+    # a block's rows as they lie: (token, key head), token major
+    slabs = (num_blocks, block_size * Hkv, D)
     out = pl_.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, G, Hkv, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=interpret,
         name="paged_decode" if window is None else "paged_decode_win",
     )(
-        walk,
+        walk.reshape(-1),
         kv_lens,
         step_row,
         step_group,
-        *first,
-        # query head h = kv_head * G + g: [B, Hkv, G, D] -> group major
-        q.reshape(B, Hkv, G, D).transpose(0, 2, 1, 3),
-        *(N * [k_pool] + N * [v_pool]),
+        step_base,
+        q.reshape(B, H, D),
+        *(N * [k_pool.reshape(slabs)] + N * [v_pool.reshape(slabs)]),
     )
-    # back to [B, 1, H, D], the caller's BSHD contract
-    return out.transpose(0, 2, 1, 3).reshape(B, 1, H, D)
+    return out.reshape(B, 1, H, D)
 
 
 # VMEM the prefill kernel plans for a grid step's keys and values: its N blocks

@@ -9,7 +9,6 @@ with the program. Seeded random float32 weights; logits, not tokens."""
 
 import ast
 import collections
-import hashlib
 import importlib
 import os
 import sys
@@ -297,10 +296,13 @@ def test_window_kernels_agree_with_the_gather_twin(window):
         np.testing.assert_allclose(got, want, atol=2e-6)
 
 
-def _mistral_shaped(kernel):
+def test_a_window_no_row_reaches_past_is_the_windowless_decode_bit_for_bit():
     """A Mistral-shaped call (32/8 heads of 128, blocks of 16, bf16) of the
-    paged decode kernel with no window, on seeded inputs: ``(fn, args)``."""
-    assert kernel == "decode"  # the prefill kernel's twin went with that program (ISSUE 30)
+    paged decode kernel on seeded inputs: a window as long as the longest row
+    starts every walk at block 0 and masks nothing, so it is ``window=None``'s
+    output bit for bit. (Until ISSUE 34 this held ``window=None`` to the hash
+    of the program of the PR before the window, which went with that kernel
+    body, as the prefill kernel's pair went with ISSUE 30.)"""
     rng = np.random.default_rng(7)
     bs, Hkv, H, D, nb, W = 16, 8, 32, 128, 40, 12
     k_pool = jnp.asarray(rng.normal(size=(nb, bs, Hkv, D)), jnp.bfloat16)
@@ -310,25 +312,13 @@ def _mistral_shaped(kernel):
     for b, n in enumerate(lengths):
         tables[b, :-(-int(n) // bs)] = rng.permutation(np.arange(1, nb))[:-(-int(n) // bs)]
     q = jnp.asarray(rng.normal(size=(4, 1, H, D)), jnp.bfloat16)
-    return (lambda *a: fa.paged_attention_decode(*a, interpret=True),
-            (q, k_pool, v_pool, jnp.asarray(tables), jnp.asarray(lengths)))
-
-
-# sha256 of the float32 bytes of the output, and of the text of the call's jaxpr (kernel body
-# included), both computed on the PARENT of the PR that brought the window (commit 4b88539) by this
-# same function: `window=None` is that program, bit for bit
-PARENT = {
-    "decode": ("85a33e2d5cdea56c2c898b827c26810457aadb0ec61be652ba282e7fae895e34",
-               "d067ad745fae206e8cb5fc8d7f496e2abe319d1e4576a18d60841f4505616eff"),
-}  # (the prefill kernel's pair went with that program: its grid and tile are ISSUE 30's)
-
-
-@pytest.mark.parametrize("kernel", ["decode"])
-def test_without_a_window_both_paged_kernels_are_the_parents_bit_for_bit(kernel):
-    fn, args = _mistral_shaped(kernel)
-    out = hashlib.sha256(np.asarray(fn(*args).astype(jnp.float32)).tobytes()).hexdigest()
-    program = hashlib.sha256(str(jax.make_jaxpr(fn)(*args)).encode()).hexdigest()
-    assert (out, program) == PARENT[kernel]
+    args = (q, k_pool, v_pool, jnp.asarray(tables), jnp.asarray(lengths))
+    full = fa.paged_attention_decode(*args, interpret=True)
+    wide = fa.paged_attention_decode(*args, window=int(lengths.max()), interpret=True)
+    assert np.array_equal(np.asarray(full, np.float32), np.asarray(wide, np.float32))
+    narrow = fa.paged_attention_decode(*args, window=64, interpret=True)
+    assert not np.array_equal(np.asarray(full[2:], np.float32), np.asarray(narrow[2:], np.float32))
+    assert np.array_equal(np.asarray(full[:2], np.float32), np.asarray(narrow[:2], np.float32))
 
 
 # ------------------------------------------------------------------- what refuses

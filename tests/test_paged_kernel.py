@@ -2,7 +2,8 @@
 
 The kernel (``ops.flash_attention.paged_attention_decode``) walks each row's
 LIVE block-table entries, ``N`` blocks a grid step (ISSUE 26), and streams
-them through VMEM with online softmax; the XLA gather path
+them through VMEM with online softmax, the score and value products of all
+query heads against a step's blocks as two matmuls (ISSUE 34); the XLA gather path
 (``ops.flash_attention.paged_attention_gather``) is the reference semantics. These
 tests drive the SAME kernel through the Pallas interpreter
 on CPU — identical dataflow, no TPU required — and hold the line the
@@ -54,12 +55,13 @@ def _random_paged_case(seed, *, B, H, Hkv, D, bs, nb, W, lens):
     return q, k_pool, v_pool, tables, np.asarray(lens, np.int32)
 
 
-def _assert_parity(q, k_pool, v_pool, tables, lens, tol=1e-6):
+def _assert_parity(q, k_pool, v_pool, tables, lens, tol=1e-6, window=None):
     qj = jnp.asarray(q)
     kj, vj = jnp.asarray(k_pool), jnp.asarray(v_pool)
     tj = jnp.asarray(tables)
-    ref = gather_ref(qj, kj, vj, tj, jnp.asarray(lens - 1)[:, None])
-    out = paged_attention_decode(qj, kj, vj, tj, jnp.asarray(lens), interpret=True)
+    windowed = {} if window is None else {"window": window}
+    ref = gather_ref(qj, kj, vj, tj, jnp.asarray(lens - 1)[:, None], **windowed)
+    out = paged_attention_decode(qj, kj, vj, tj, jnp.asarray(lens), interpret=True, **windowed)
     err = float(jnp.max(jnp.abs(ref.astype(jnp.float32) - out.astype(jnp.float32))))
     assert err <= tol, f"kernel diverged from gather reference by {err}"
 
@@ -114,9 +116,10 @@ def test_kernel_parity_at_cow_divergence_point():
 
 
 def test_kernel_parity_bf16_pools_within_one_ulp():
-    """bf16 pools (the engine's cache dtype): the kernel computes the whole
-    softmax in f32 while the reference rounds probabilities through bf16, so
-    agreement is to bf16 resolution, not bitwise."""
+    """bf16 pools (the engine's cache dtype): both products are bf16 matmuls
+    with f32 accumulation and the probabilities are rounded to bf16 for the
+    value product, as the reference rounds them; the online softmax sums in
+    another order, so agreement is to bf16 resolution, not bitwise."""
     case = _random_paged_case(
         4, B=2, H=4, Hkv=2, D=32, bs=8, nb=12, W=3, lens=[20, 9]
     )
@@ -169,32 +172,56 @@ def test_walk_parity(group_of, case):
     _assert_parity(q, k_pool, v_pool, tables, lens)
 
 
-@pytest.mark.parametrize(
-    "bs,G,Hkv,D,dtype,W,lens,N",
-    [(128, 2, 8, 64, jnp.bfloat16, 3, [300, 128, 1], 1),   # the big-block compile shape
-     (16, 4, 8, 128, jnp.float32, 6, [90, 17, 64], 3)],
-    ids=["bs128-bf16", "bs16-f32"],
-)
-def test_walk_parity_at_the_derived_group(bs, G, Hkv, D, dtype, W, lens, N):
-    """No pinning: ``N`` is what the shapes give, and it is under ``W``."""
+# (bs, G, Hkv, D, dtype, W, lens, nb) -> N: no pinning, ``N`` is what the shapes
+# give and it is under ``W``. The serve cells' head ratios at their head width
+# and block (4 query heads a key head at 8 key heads, 16 at 8, 8 at 4), one and
+# two query heads a key head, a big block, f32 pools.
+DERIVED_CASES = {
+    "bs128-bf16": ((128, 2, 8, 64, jnp.bfloat16, 3, [300, 128, 1], 14), 1),  # the big-block compile shape
+    "bs16-f32": ((16, 4, 8, 128, jnp.float32, 6, [90, 17, 64], 14), 6),
+    "G4-Hkv8": ((16, 4, 8, 128, jnp.float32, 40, [601, 37, 256, 1], 60), 8),   # mistral-7b
+    "G16-Hkv8": ((16, 16, 8, 128, jnp.float32, 40, [601, 37, 256, 1], 60), 8),  # command-a-plus
+    "G8-Hkv4": ((16, 8, 4, 128, jnp.float32, 40, [601, 37, 256, 1], 60), 8),   # mellum2-12b
+    "G16-Hkv8-bf16": ((16, 16, 8, 128, jnp.bfloat16, 40, [601, 37, 256, 1], 60), 8),
+    "G8-Hkv4-bf16": ((16, 8, 4, 128, jnp.bfloat16, 40, [601, 37, 256, 1], 60), 8),
+    "G1-Hkv8": ((16, 1, 8, 64, jnp.float32, 20, [300, 17, 160], 32), 8),
+    "G2-Hkv4": ((16, 2, 4, 64, jnp.float32, 20, [300, 17, 160], 32), 8),
+    "G1-Hkv3": ((16, 1, 3, 32, jnp.float32, 20, [300, 17, 160], 32), 8),      # no power of two
+}
+
+
+@pytest.mark.parametrize("window", [None, 100], ids=["full", "window100"])
+@pytest.mark.parametrize("case", list(DERIVED_CASES))
+def test_walk_parity_at_the_derived_group(case, window):
+    (bs, G, Hkv, D, dtype, W, lens, nb), N = DERIVED_CASES[case]
     assert fa._decode_group_blocks(bs, Hkv, D, dtype, G, W) == N
     q, k_pool, v_pool, tables, lens = _random_paged_case(
-        11, B=len(lens), H=G * Hkv, Hkv=Hkv, D=D, bs=bs, nb=14, W=W, lens=lens)
+        11, B=len(lens), H=G * Hkv, Hkv=Hkv, D=D, bs=bs, nb=nb, W=W, lens=lens)
+    for b, n in enumerate(lens):
+        if n == 1:  # a padded slot: all-null table, one token
+            tables[b, :] = NULL_BLOCK
     bf16 = dtype == jnp.bfloat16
     _assert_parity(q.astype(dtype), k_pool.astype(dtype), v_pool.astype(dtype), tables, lens,
-                   tol=2e-2 if bf16 else 1e-6)
+                   tol=2e-2 if bf16 else 2e-6, window=window)
 
 
 def test_group_comes_from_shapes():
-    """``N`` at the serve cells' shapes, at a big block, and its clamps."""
+    """``N`` at the three serve configurations' shapes (128 keys a step at
+    every head ratio), at a big block, and its clamps."""
     bf16 = jnp.bfloat16
-    assert fa._decode_group_blocks(16, 8, 128, bf16, 4, 144) == 4
-    assert fa._decode_group_blocks(16, 8, 128, bf16, 4, 48) == 4
-    assert fa._decode_group_blocks(16, 8, 128, bf16, 4, 2) == 2      # never over W
-    assert fa._decode_group_blocks(128, 8, 64, bf16, 2, 8) == 1      # never under 1
-    assert fa._decode_group_blocks(16, 8, 128, jnp.float32, 4, 144) == 3
+    assert fa._decode_group_blocks(16, 8, 128, bf16, 4, 144) == 8     # mistral-7b.chat-sat
+    assert fa._decode_group_blocks(16, 8, 128, bf16, 4, 48) == 8      # mistral-7b.chat-r80
+    assert fa._decode_group_blocks(16, 8, 128, bf16, 16, 400) == 8    # command-a-plus.rag-sat
+    assert fa._decode_group_blocks(16, 4, 128, bf16, 8, 1056) == 8    # mellum2-12b.code-sat
+    assert fa._decode_group_blocks(16, 8, 128, jnp.float32, 16, 400) == 8
+    assert fa._decode_group_blocks(16, 8, 128, bf16, 4, 2) == 2       # never over W
+    assert fa._decode_group_blocks(128, 8, 64, bf16, 2, 8) == 1       # a block of 128 keys
+    assert fa._decode_group_blocks(512, 8, 128, bf16, 4, 8) == 1      # never under 1
+    assert fa._decode_group_blocks(4, 8, 128, bf16, 4, 64) == 32      # small blocks: 128 keys
+    assert fa._decode_group_blocks(16, 32, 256, jnp.float32, 4, 64) == 1  # the bytes bind: 3 MB a block
+    assert fa._decode_group_blocks(16, 16, 128, jnp.float32, 16, 64) == 4
     assert fa._decode_group_blocks(16, 8, 64, bf16, 2, 32) == \
-        fa._decode_group_blocks(16, 8, 128, bf16, 2, 32)              # D pads to a lane tile
+        fa._decode_group_blocks(16, 8, 128, bf16, 2, 32)               # D pads to a lane tile
 
 
 @pytest.mark.parametrize("N", [2, 3, 8])

@@ -88,21 +88,29 @@ def test_flash_attention_compiles_for_v5e(one_chip, case, direction):
     )
 
 
-# (B, H, Hkv, D, block_size, W)
-@pytest.mark.parametrize(
-    "B,H,Hkv,D,block_size,W",
-    [(8, 16, 8, 64, 16, 32), (8, 32, 8, 128, 16, 64), (4, 16, 8, 64, 128, 8),
-     # the serve cells' own decode shapes: chat-sat's and chat-r80's buckets
-     (64, 32, 8, 128, 16, 144), (32, 32, 8, 128, 16, 48)],
-    ids=["b8-16x8-d64-bs16", "b8-32x8-d128-bs16", "b4-16x8-d64-bs128",
-         "b64-32x8-d128-bs16-w144", "b32-32x8-d128-bs16-w48"],
-)
-def test_paged_decode_compiles_for_v5e(one_chip, B, H, Hkv, D, block_size, W):
-    pool = ((512, block_size, Hkv, D), BF16)
-    _compile(
-        fa.paged_attention_decode, one_chip,
+# (B, H, Hkv, D, block_size, W, window, blocks in the pool)
+PAGED_DECODE_CASES = {
+    "b8-16x8-d64-bs16": (8, 16, 8, 64, 16, 32, None, 512),
+    "b8-32x8-d128-bs16": (8, 32, 8, 128, 16, 64, None, 512),
+    "b4-16x8-d64-bs128": (4, 16, 8, 64, 128, 8, None, 512),
+    # the serve cells' own decode shapes: chat-sat's and chat-r80's buckets, then
+    # code-sat's (rag-sat's are further down, beside its prefill chunks)
+    "b64-32x8-d128-bs16-w144": (64, 32, 8, 128, 16, 144, None, 3201),
+    "b32-32x8-d128-bs16-w48": (32, 32, 8, 128, 16, 48, None, 3201),
+    "b32-32x4-d128-bs16-w1056": (32, 32, 4, 128, 16, 1056, None, 14401),
+    "b32-32x4-d128-bs16-w1056-window1024": (32, 32, 4, 128, 16, 1056, 1024, 14401),
+}
+
+
+@pytest.mark.parametrize("case", list(PAGED_DECODE_CASES))
+def test_paged_decode_compiles_for_v5e(one_chip, case):
+    B, H, Hkv, D, block_size, W, window, num_blocks = PAGED_DECODE_CASES[case]
+    pool = ((num_blocks, block_size, Hkv, D), BF16)
+    text = _compile(
+        lambda q, k, v, t, n: fa.paged_attention_decode(q, k, v, t, n, window=window), one_chip,
         ((B, 1, H, D), BF16), pool, pool, ((B, W), jnp.int32), ((B,), jnp.int32),
     )
+    assert ("paged_decode_win" in text) == (window is not None) and "paged_decode" in text
 
 
 @pytest.mark.parametrize("block_size", [16, 128])
