@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from .models.transformer import LlamaConfig, llama_head, llama_layer, llama_rope
 from .ops.attention import masked_attention
+from .ops.flash_attention import kv_lane_pack
 
 __all__ = [
     "init_kv_cache",
@@ -126,7 +127,8 @@ def serving_shardings(mesh, config: LlamaConfig):
     from .parallel.sharding import canonicalize_spec
 
     axes = dict(mesh.shape)
-    tp = "tp" if axes.get("tp", 1) > 1 and config.n_kv_heads % axes["tp"] == 0 else None
+    heads = config.n_kv_heads // kv_lane_pack(config.n_kv_heads, config.head_dim)  # a row's
+    tp = "tp" if axes.get("tp", 1) > 1 and heads % axes["tp"] == 0 else None
     return NamedSharding(mesh, canonicalize_spec(P(None, None, None, tp, None), axes))
 
 

@@ -22,6 +22,11 @@ from .mellum import (
     init_mellum,
     mellum_forward,
 )
+from .lfm2 import (
+    Lfm2Config,
+    init_lfm2,
+    lfm2_forward,
+)
 from .resnet import (
     ResNetConfig,
     init_resnet,

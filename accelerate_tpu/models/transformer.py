@@ -350,12 +350,17 @@ def llama_layer(layer_params: dict, h: jax.Array, positions, cos, sin, config: L
     ``ffn(layer_params, x) -> (y, aux)`` is the layer's second half where it
     is not :func:`llama_ffn` (``models/mellum.py``: routed experts, ``aux``
     their counts); ``config`` then needs only ``n_heads``, ``n_kv_heads``,
-    ``head_dim`` and ``norm_eps``."""
+    ``head_dim`` and ``norm_eps``. A layer whose parameters hold ``q_norm`` and
+    ``k_norm`` (one scale over ``head_dim`` each) passes every query and key
+    head through that RMSNorm before the rotary turn (``models/lfm2.py``)."""
     B, S, _ = h.shape
     x = rms_norm(h, layer_params["attn_norm"]["scale"], config.norm_eps)
     q = _proj(layer_params["wq"], x).reshape(B, S, config.n_heads, config.head_dim)
     k = _proj(layer_params["wk"], x).reshape(B, S, config.n_kv_heads, config.head_dim)
     v = _proj(layer_params["wv"], x).reshape(B, S, config.n_kv_heads, config.head_dim)
+    if "q_norm" in layer_params:  # the tree's structure, static under jit  # jaxlint: disable=R1
+        q = rms_norm(q, layer_params["q_norm"]["scale"], config.norm_eps)
+        k = rms_norm(k, layer_params["k_norm"]["scale"], config.norm_eps)
     q = apply_rope(q, cos, sin, positions=positions)
     k = apply_rope(k, cos, sin, positions=positions)
     h = pin(h + _proj(layer_params["wo"], attend(q, k, v).reshape(B, S, -1)))

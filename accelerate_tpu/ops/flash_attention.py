@@ -665,7 +665,12 @@ def paged_kernel_mode() -> str:
 NULL_BLOCK = 0
 
 
-def init_block_pool(config, num_blocks: int, block_size: int, dtype=jnp.bfloat16) -> dict:
+#: row of a pool's per-sequence state reserved for idle slots (never handed out)
+NULL_STATE_ROW = 0
+
+
+def init_block_pool(config, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
+                    state_rows: int = 0) -> dict:
     """Device pool ``{"k","v"}: [L, num_blocks, block_size, Hkv, D]``
     (``num_blocks`` INCLUDES the reserved null block 0, one a layer). ``config``
     is any model description with ``n_layers``, ``n_kv_heads`` and ``head_dim``;
@@ -675,9 +680,78 @@ def init_block_pool(config, num_blocks: int, block_size: int, dtype=jnp.bfloat16
     ``serving/disagg.py``, a pool's checkpoint). Inside a step program a layer
     addresses the stack flat: block ``b`` of layer ``l`` is block ``l *
     num_blocks + b`` of ``[L * num_blocks, block_size, Hkv, D]``, and the
-    layer's null block is ``l * num_blocks`` (:func:`paged_write_attend`)."""
-    shape = (config.n_layers, num_blocks, block_size, config.n_kv_heads, config.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    layer's null block is ``l * num_blocks`` (:func:`paged_write_attend`).
+
+    The pool holds what the model says it needs. Where only some layers attend
+    the model says how many (``config.n_kv_layers``) and ``L`` is that, a
+    layer's index its rank among them. Where key heads are narrower than the
+    128 lanes of a tile and fill them exactly several at a time
+    (:func:`kv_lane_pack`), that many lie side by side in a row: ``[..., Hkv /
+    pack, pack * D]``, the same bytes in the same order, and
+    :func:`paged_write_attend` alone knows. A model with ``state_shape``
+    ``(layers, *row)`` keeps that much a SEQUENCE beside its blocks, whatever
+    its length: the pool then also has ``"state": [layers, state_rows, *row]``,
+    one row a sequence, row ``NULL_STATE_ROW`` for the slots that hold none."""
+    pack = kv_lane_pack(config.n_kv_heads, config.head_dim)
+    shape = (getattr(config, "n_kv_layers", config.n_layers), num_blocks, block_size,
+             config.n_kv_heads // pack, config.head_dim * pack)
+    pool = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    state_shape = getattr(config, "state_shape", None)
+    if state_shape is not None:
+        pool["state"] = jnp.zeros((state_shape[0], state_rows, *state_shape[1:]), dtype)
+    return pool
+
+
+def kv_lane_pack(n_kv_heads: int, head_dim: int) -> int:
+    """Key heads that share a row of the paged pool: ``128 / head_dim`` where
+    that many fill a tile's 128 lanes exactly and divide the key heads, else 1
+    (every head of 128 or wider).
+
+    Why: a pool whose rows are 64 wide fills half of the 128 lanes of the
+    TPU's tiles. The paged kernels want the pool in the layout that pads such
+    a row to 128 (twice the memory), the runtime's default layout for the same
+    array is another one without the padding, and every step program would
+    copy the whole pool from one to the other and back (compiled for a
+    described v5e, PR 35: 3 GB of temporaries for a 2 GB pool). With the rows
+    full both layouts are the same, and the kernels run at a shape they
+    already serve. A row that packing would still leave short of 128 gains
+    nothing and is left alone."""
+    pack = max(1, 128 // head_dim)
+    return pack if pack * head_dim == 128 and n_kv_heads % pack == 0 else 1
+
+
+def _lane_segment(H: int, Hkv: int, pack: int) -> np.ndarray:
+    """Of each of ``H`` query heads, which of the ``pack`` segments of its
+    packed key head holds its own key head ``h // (H / Hkv)``."""
+    return (np.arange(H) // (H // Hkv)) % pack
+
+
+def _pack_heads(q, k, v, pack: int):
+    """Attention with ``Hkv`` key heads of ``D`` as attention with ``Hkv /
+    pack`` key heads of ``pack * D``, exactly: key heads ``pack * j .. pack * j
+    + pack - 1`` lie side by side in packed head ``j`` (a reshape of ``k``, ``v``
+    ``[B, S, Hkv, D]``: no data moves), and query head ``h``, whose key head
+    ``g = h // (H / Hkv)`` is segment ``g % pack`` of packed head ``g // pack``,
+    is widened with zeros everywhere but in that segment, so its product with
+    a packed key is its product with its own key head. The scores still want
+    ``D``'s ``1 / sqrt(D)``, not the packed width's; the packed output
+    carries every segment's values and :func:`_unpack_heads` keeps the query
+    head's own. Costs ``pack`` times the score and value products (a few
+    MFLOP a step) and nothing in bytes."""
+    B, S, H, D = q.shape
+    Hkv = k.shape[2]
+    own = jax.nn.one_hot(_lane_segment(H, Hkv, pack), pack, dtype=q.dtype)  # [H, pack]
+    widened = q[:, :, :, None, :] * own[:, :, None]
+    return (widened.reshape(B, S, H, pack * D), k.reshape(B, S, Hkv // pack, pack * D),
+            v.reshape(B, S, Hkv // pack, pack * D))
+
+
+def _unpack_heads(attn, pack: int, n_kv_heads: int):
+    """The other end of :func:`_pack_heads`: of ``attn [B, S, H, pack * D]``
+    each query head's own segment, ``[B, S, H, D]``."""
+    B, S, H, wide = attn.shape
+    own = _lane_segment(H, n_kv_heads, pack)
+    return attn.reshape(B, S, H, pack, wide // pack)[:, :, np.arange(H), own]
 
 
 # Keys a grid step of the decode kernel folds, and the VMEM it may plan for
@@ -1001,7 +1075,11 @@ def _prefill_query_tile(S: int, H: int, D: int) -> int:
 def prefill_tiling(S: int, H: int, Hkv: int, D: int, block_size: int, dtype, W: int):
     """``(Sq, N)`` of a :func:`paged_attention_prefill` call, from its shapes:
     the queries a tile (:func:`_prefill_query_tile`) and the blocks a grid
-    step (:func:`_prefill_group_blocks`)."""
+    step (:func:`_prefill_group_blocks`). The shapes may be a model's as well
+    as the call's: heads that :func:`kv_lane_pack` packs reach the call
+    packed, and packed heads pack no further."""
+    pack = kv_lane_pack(Hkv, D)
+    Hkv, D = Hkv // pack, D * pack
     return _prefill_query_tile(S, H, D), _prefill_group_blocks(block_size, Hkv, D, dtype, W)
 
 
@@ -1347,9 +1425,19 @@ def paged_write_attend(q, k, v, k_pool, v_pool, layer, block_tables, positions,
     idle slot (its table is all null) write to the LAYER'S null block, ``layer
     * num_blocks + NULL_BLOCK`` — a pad write may never land in a live block,
     nor in another layer. The stack is never taken apart, so a program that
-    donates the pool updates it in place. Returns ``(attn [B, S, H, D],
-    k_pool, v_pool)``, the stacks in the shape they came in."""
+    donates the pool updates it in place. Where the pool's rows hold several
+    key heads (:func:`init_block_pool`: its last axis is that many times
+    ``D``), the layer's heads are packed to them on the way in and each query
+    head's own segment taken on the way out (:func:`_pack_heads`): the caller
+    sees neither. Returns ``(attn [B, S, H, D], k_pool, v_pool)``, the stacks
+    in the shape they came in."""
     shape = k_pool.shape
+    Hkv, D = k.shape[2:]
+    pack = shape[-1] // D  # key heads to a row of the pool
+    scale = None
+    if pack > 1:
+        q, k, v = _pack_heads(q, k, v, pack)
+        scale = D ** -0.5
     base = layer * shape[1]
     W = block_tables.shape[1]
     logical = positions // block_size
@@ -1358,5 +1446,8 @@ def paged_write_attend(q, k, v, k_pool, v_pool, layer, block_tables, positions,
     off = positions % block_size
     k_flat = k_pool.reshape(-1, *shape[2:]).at[phys, off].set(k.astype(k_pool.dtype))
     v_flat = v_pool.reshape(-1, *shape[2:]).at[phys, off].set(v.astype(v_pool.dtype))
-    attn = paged_attention(q, k_flat, v_flat, base + block_tables, positions, window=window)
+    attn = paged_attention(
+        q, k_flat, v_flat, base + block_tables, positions, scale, window=window)
+    if pack > 1:
+        attn = _unpack_heads(attn, pack, Hkv)
     return attn, k_flat.reshape(shape), v_flat.reshape(shape)

@@ -172,29 +172,43 @@ def init_held_experts(key, d_model: int, d_ff: int, num_experts: int, held: int)
     }
 
 
-def route_top_k(router_kernel, x, top_k: int, scoring: str = "sigmoid"):
+def route_top_k(router_kernel, x, top_k: int, scoring: str = "sigmoid", *,
+                select_bias=None, weight_eps: float = 0.0):
     """Scores over every expert (``scoring``: ``sigmoid``, each expert's own,
     or ``softmax`` over them all), the ``top_k`` largest, their weights
     normalised to sum to 1: ``(expert ids [N, k], weights [N, k])``. The
     arithmetic is float32 (a TPU's default matmul precision is not): the gap
-    between the k-th and the next score is small against bf16 rounding."""
+    between the k-th and the next score is small against bf16 rounding.
+
+    ``select_bias [E]`` sets selection apart from weighting: the experts are
+    the ``top_k`` largest of ``score + select_bias``, their weights come from
+    ``score`` alone (a load-balancing bias moves who is chosen, never how much
+    a chosen expert counts). ``weight_eps`` is added to the weights' sum before
+    the division. None and 0.0 are the function as it was, bit for bit."""
     import jax
     import jax.numpy as jnp
 
     score = {"sigmoid": jax.nn.sigmoid, "softmax": partial(jax.nn.softmax, axis=-1)}[scoring]
     logits = jnp.dot(x.astype(jnp.float32), router_kernel.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    scores, experts = jax.lax.top_k(score(logits), top_k)
-    return experts, scores / jnp.sum(scores, axis=-1, keepdims=True)
+    if select_bias is None:
+        scores, experts = jax.lax.top_k(score(logits), top_k)
+    else:
+        every = score(logits)
+        _, experts = jax.lax.top_k(every + select_bias.astype(jnp.float32), top_k)
+        scores = jnp.take_along_axis(every, experts, axis=-1)
+    total = jnp.sum(scores, axis=-1, keepdims=True)
+    return experts, scores / (total + weight_eps if weight_eps else total)
 
 
 def held_expert_ffn(params, x, *, top_k: int, first_expert: int = 0, valid=None,
-                    scoring: str = "sigmoid"):
+                    scoring: str = "sigmoid", select_bias=None, weight_eps: float = 0.0):
     """The routed experts' part of an expert layer that this chip computes:
     ``x [..., D] -> (y [..., D], counts [3])``.
 
     The router scores all ``E = router.shape[-1]`` experts by ``scoring`` and
-    picks ``top_k`` a token (:func:`route_top_k`); the ``held = w_gate.shape[0]`` experts
+    picks ``top_k`` a token (:func:`route_top_k`, which also takes
+    ``select_bias`` and ``weight_eps``); the ``held = w_gate.shape[0]`` experts
     ``first_expert .. first_expert + held`` live here. The (token, expert)
     pairs that land on them are sorted by expert and go through one grouped
     matmul a weight stack (:func:`accelerate_tpu.ops.grouped_matmul.
@@ -216,7 +230,8 @@ def held_expert_ffn(params, x, *, top_k: int, first_expert: int = 0, valid=None,
     lead, D = x.shape[:-1], x.shape[-1]
     x2 = x.reshape(-1, D)
     held = params["w_gate"]["kernel"].shape[0]
-    experts, weights = route_top_k(params["router"]["kernel"], x2, top_k, scoring)
+    experts, weights = route_top_k(params["router"]["kernel"], x2, top_k, scoring,
+                                   select_bias=select_bias, weight_eps=weight_eps)
     local = (experts >= first_expert) & (experts < first_expert + held)
     if valid is not None:
         local = local & valid.reshape(-1, 1)

@@ -52,6 +52,7 @@ from .buckets import BucketLattice
 from ..models.transformer import llama_paged_forward as paged_forward
 from ..ops.flash_attention import (
     NULL_BLOCK,
+    NULL_STATE_ROW,
     init_block_pool,
     paged_attention_gather as paged_attention,
 )
@@ -82,6 +83,7 @@ __all__ = [
     "ServingEngine",
     "paged_forward",
     "NULL_BLOCK",
+    "NULL_STATE_ROW",
     "BlockAllocator",
     "BlockAllocatorError",
     "BlockPoolExhausted",

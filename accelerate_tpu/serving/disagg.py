@@ -320,6 +320,17 @@ class LocalBlockCopyTransport(KVTransport):
 # role-split engines
 
 
+def _refuse_sequence_state(engine: ServingEngine) -> None:
+    """A handoff carries a request's K/V blocks and nothing else: a model
+    that also keeps per-sequence state rows (``ServingEngine.state_shape``)
+    would land on the decode tier without them."""
+    if engine.state_shape is not None:
+        raise TypeError(
+            f"disaggregated serving hands K/V blocks over, and a "
+            f"{type(engine.config).__name__} also keeps per-sequence state rows: "
+            "a handoff has no place for them")
+
+
 class PrefillEngine(ServingEngine):
     """Chunked prefill only: every admitted request is prefilled (first
     token sampled at fold 0, the monolith's schedule), packed into a KV
@@ -330,6 +341,7 @@ class PrefillEngine(ServingEngine):
     def __init__(self, *args, transport: Optional[KVTransport] = None, **kwargs):
         kwargs.setdefault("prefix_cache", True)
         super().__init__(*args, **kwargs)
+        _refuse_sequence_state(self)
         if not self.prefix_cache:
             raise ValueError("PrefillEngine requires prefix_cache=True "
                              "(chain hashes ARE the handoff addresses)")
@@ -442,6 +454,7 @@ class DecodeEngine(ServingEngine):
     def __init__(self, *args, transport: Optional[KVTransport] = None, **kwargs):
         kwargs.setdefault("prefix_cache", True)
         super().__init__(*args, **kwargs)
+        _refuse_sequence_state(self)
         if not self.prefix_cache:
             raise ValueError("DecodeEngine requires prefix_cache=True "
                              "(handoffs land through the content index)")

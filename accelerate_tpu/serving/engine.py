@@ -44,6 +44,7 @@ from ..generation import sample_token_logits, serving_shardings
 from ..models.transformer import LlamaConfig, draft_config, draft_params
 from ..ops.flash_attention import (
     NULL_BLOCK,
+    NULL_STATE_ROW,
     init_block_pool,
     prefill_tiling,
     prefill_walk_blocks,
@@ -80,7 +81,9 @@ def model_paged_forward(config, block_size: int):
     model brings its own as ``config.paged_forward`` (``models/transformer.py``,
     ``models/cohere2_moe.py``); ``counts`` is None or a small integer array
     the engine fetches with the step's tokens and records
-    (:meth:`ServingEngine._record_counts`)."""
+    (:meth:`ServingEngine._record_counts`). A model with per-sequence state
+    (``config.state_shape``, ``models/lfm2.py``) takes one more argument
+    after ``valid``: ``rows [B]``, each sequence's row of ``pool["state"]``."""
     if not callable(getattr(config, "paged_forward", None)):
         raise TypeError(
             f"ServingEngine cannot serve a {type(config).__name__}: it has no "
@@ -115,6 +118,30 @@ class ServingEngine:
     (:func:`model_paged_forward`: the config's own method). Speculative
     decoding drafts with a ``LlamaConfig``'s own first layers and refuses
     another model.
+
+    Two kinds of cache. Every model has the paged K/V pool, which grows with a
+    sequence: blocks, a block table, the allocator. It has as many layers as
+    the model says attend (``config.n_kv_layers``, else ``n_layers``). A model
+    with ``config.state_shape`` (``models/lfm2.py``: layers that keep a few
+    rows a sequence, whatever its length) also gets ``pool["state"] [layers,
+    max_slots + 1, *row]``. A sequence's state row is ``slot + 1`` of the batch
+    slot the scheduler gives it at admission and takes back, with its blocks,
+    at finish and at preemption (``Scheduler._release`` is the one owner of
+    both); row 0 is the null row of idle slots, as block 0 is the null block.
+    The engine passes the rows to both step programs, and the model resets a
+    row itself: a call whose first position is 0 starts from zeros whatever
+    the row's last owner left, and a preempted request is re-prefilled from
+    position 0, which rebuilds its state. Each row handed out or taken back is
+    an ``atpu.serve.state`` record (``rid``, ``row``, ``why``: admit / finish /
+    preempt), ``atpu.serve.build`` carries ``state_rows``, and :meth:`stats`
+    has ``state_resets`` and ``state_bytes``.
+
+    What such a model is refused, and why: a prefix-cache hit (a hit skips the
+    prompt's head, which is what the state is made of: ``prefix_cache`` is
+    switched off, no hit is taken and every prompt is prefilled whole) and
+    with it copy-on-write; speculative decoding (a rejected candidate would
+    already have moved the state); disaggregated serving (a handoff carries
+    blocks, not rows); a ``mesh`` (the pool's sharding is written for K/V).
     """
 
     def __init__(
@@ -167,6 +194,15 @@ class ServingEngine:
         # batched decode produces a stall dump naming this engine (replicas
         # suffix their name so a stuck replica is attributable)
         self.heartbeat_name = heartbeat_name
+        #: what the model keeps a sequence beside its blocks: ``(layers, *row)``, or None
+        self.state_shape = getattr(config, "state_shape", None)
+        if self.state_shape is not None and mesh is not None:
+            raise TypeError(
+                f"a {type(config).__name__} keeps per-sequence state, and the pool's "
+                "sharding over a mesh is written for keys and values only")
+        # a prefix hit skips the prompt's head, and the state is made of it: no hit is taken
+        self._prefix_cache_asked = prefix_cache
+        prefix_cache = prefix_cache and self.state_shape is None
         self.prefix_cache = prefix_cache
         self.allocator = BlockAllocator(
             num_blocks, block_size, prefix_caching=prefix_cache
@@ -193,7 +229,8 @@ class ServingEngine:
             max_seq_blocks=self.lattice.block_buckets[-1],
             max_seq_tokens=config.max_seq_len,
         )
-        self.pool = init_block_pool(config, num_blocks, block_size, cache_dtype)
+        self.pool = init_block_pool(
+            config, num_blocks, block_size, cache_dtype, state_rows=max_slots + 1)
         if mesh is not None:
             sharding = serving_shardings(mesh, config)
             self.pool = jax.tree_util.tree_map(
@@ -209,25 +246,26 @@ class ServingEngine:
                     row[None], key, temperature=temperature, top_k=top_k, top_p=top_p
                 )[0]
 
-        def _prefill(params, pool, ids, table, start, last_idx, key, token_idx):
+        def _prefill(params, pool, ids, table, start, last_idx, key, token_idx, *rows):
             # one CHUNK of a prefix: ids [1, Sb] holds the tokens at absolute
             # positions start..start+Sb-1 (the host loop feeds long prefixes
             # through the largest bucket chunk by chunk); the sampled token is
-            # meaningful only for the final chunk (last_idx = last real row)
+            # meaningful only for the final chunk (last_idx = last real row).
+            # `rows` is empty, or the sequence's state row (:meth:`_state_rows`)
             B, Sb = ids.shape
             positions = start + jnp.broadcast_to(jnp.arange(Sb)[None], (B, Sb))
             # the chunk's real tokens; the bucket's padding lies behind `last_idx`
             valid = jnp.broadcast_to(jnp.arange(Sb)[None] <= last_idx, (B, Sb))
-            logits, pool, counts = forward(params, ids, pool, table, positions, valid)
+            logits, pool, counts = forward(params, ids, pool, table, positions, valid, *rows)
             last = jax.lax.dynamic_index_in_dim(logits, last_idx, axis=1, keepdims=False)
             tok = select_one(last[0], jax.random.fold_in(key, token_idx))
             return pool, (tok.astype(jnp.int32), counts)
 
-        def _decode(params, pool, last_tok, tables, positions, keys, token_idx):
+        def _decode(params, pool, last_tok, tables, positions, keys, token_idx, *rows):
             # an idle slot's table is all null; a live row's first block never is
             valid = tables[:, :1] != NULL_BLOCK
             logits, pool, counts = forward(
-                params, last_tok[:, None], pool, tables, positions[:, None], valid)
+                params, last_tok[:, None], pool, tables, positions[:, None], valid, *rows)
             folded = jax.vmap(jax.random.fold_in)(keys, token_idx)
             tok = jax.vmap(select_one)(logits[:, -1], folded)
             return pool, (tok.astype(jnp.int32), counts)
@@ -236,6 +274,8 @@ class ServingEngine:
             # copy-on-write for the aligned prefix-cache edge case: duplicate
             # one physical block (all layers, K and V) into a private block
             # before the new sequence's first write can touch shared content
+            if "state" in pool:  # the pool's keys, static under jit  # jaxlint: disable=R1
+                raise TypeError("a shared block has no copy of a sequence's state to go with it")
             return {
                 "k": pool["k"].at[:, dst].set(pool["k"][:, src]),
                 "v": pool["v"].at[:, dst].set(pool["v"][:, src]),
@@ -340,6 +380,10 @@ class ServingEngine:
         #: through the model, (token, expert) pairs on the experts held here,
         #: held experts hit, layer calls, the most pairs one expert got in a call
         self.moe = dict(tokens=0, local_pairs=0, experts_hit=0, calls=0, max_expert_load=0)
+        #: a model with per-sequence state: prefills that started a row from
+        #: zeros, and the bytes of ``pool["state"]``
+        self.state_resets = 0
+        self.state_bytes = int(self.pool["state"].nbytes) if self.state_shape is not None else 0
         #: speculative decoding: draft tokens proposed / accepted, and the
         #: accepted-per-step histogram (index = draft tokens accepted that
         #: slot-step, 0..k) the report's serving section renders
@@ -431,7 +475,7 @@ class ServingEngine:
             table = np.full((1, W), NULL_BLOCK, np.int32)
             args = (
                 self.params, self.pool, ids, table, np.int32(0), np.int32(0),
-                key, np.int32(0),
+                key, np.int32(0), *self._state_rows((), 1),
             )
             if cache is not None:
                 executable, outcome = _ccache.aot_compile(
@@ -449,7 +493,8 @@ class ServingEngine:
             positions = np.zeros((Bb,), np.int32)
             keys = np.zeros((Bb, 2), np.uint32)
             token_idx = np.zeros((Bb,), np.int32)
-            args = (self.params, self.pool, last, tables, positions, keys, token_idx)
+            args = (self.params, self.pool, last, tables, positions, keys, token_idx,
+                    *self._state_rows((), Bb))
             if cache is not None:
                 executable, outcome = _ccache.aot_compile(
                     f"serving_decode[{Bb}x{W}]", self.decode_fn, args,
@@ -597,6 +642,7 @@ class ServingEngine:
             for req in admitted:
                 if req._span_queue is not None and "t1_ns" not in req._span_queue:
                     _tracing.span_close(req._span_queue, t1_ns=t_ns)
+                self._record_state(req, req.slot, "admit", t_ns)
             while self.scheduler.rejected:
                 req = self.scheduler.rejected.pop()
                 req.finish_t = t
@@ -617,6 +663,7 @@ class ServingEngine:
         for req in requests:
             if req.done:
                 t_ns, t = self._clock()
+                self._record_state(req, req.slot, "finish", t_ns)
                 self.scheduler.complete(req, t)
                 self._finish_request(req, t_ns)
                 finished.append(req)
@@ -656,6 +703,8 @@ class ServingEngine:
             # accept are reused, so the per-step delta is what LAST step
             # actually emitted.
             with self._phase("grow"):
+                # a model with per-sequence state: whose row a preemption takes back
+                slots = [r.slot for r in running] if self.state_shape is not None else ()
                 for req in running:
                     if req.slot is not None:
                         if self.spec_tokens > 0:
@@ -668,6 +717,9 @@ class ServingEngine:
                             )
                         else:
                             self.scheduler.grow(req)
+                for req, slot in zip(running, slots):
+                    if req.slot is None:  # preempted: the scheduler took slot and blocks back
+                        self._record_state(req, slot, "preempt", _tracing.now_ns())
                 running = self.scheduler.running()
         if running:
             if self.spec_tokens > 0:
@@ -857,6 +909,9 @@ class ServingEngine:
             elif req.generated:
                 self.resume_prefill_tokens += int(prefix.size) - start
             chunk_counts = []  # a model's own counts, one a chunk: [(tokens, counts)]
+            rows = self._state_rows([req], 1)
+            if rows and start == 0:  # always: such a model takes no prefix hit
+                self.state_resets += 1
             for start, size, Sb in chunks:
                 chunk = prefix[start : start + size]
                 ids = np.zeros((1, Sb), np.int32)
@@ -865,7 +920,7 @@ class ServingEngine:
                 fn = self._aot.get(("prefill", Sb, W), self.prefill_fn)
                 self.pool, (tok, counts) = fn(
                     self.params, self.pool, ids, table, np.int32(start),
-                    np.int32(chunk.size - 1), key, token_idx,
+                    np.int32(chunk.size - 1), key, token_idx, *rows,
                 )
                 chunk_counts.append((int(chunk.size), counts))
                 if span_prefill is not None:
@@ -930,6 +985,8 @@ class ServingEngine:
         Bb = self.lattice.slot_bucket(len(running))
         W = self.lattice.block_bucket(max(blocks))
         held = {"live_blocks": sum(blocks)}
+        if self.state_shape is not None:
+            held["state_rows"] = len(running)  # the batch's live rows of `pool["state"]`
         self.decode_blocks_live += held["live_blocks"]
         self.decode_blocks_walked += Bb * W
         if self.window is not None:
@@ -938,6 +995,28 @@ class ServingEngine:
                 for n, r in zip(blocks, running))
             self.decode_blocks_window += held["window_blocks"]
         return Bb, W, held
+
+    def _state_rows(self, requests, pad_to: int) -> tuple:
+        """The last argument of a step program of a model with per-sequence
+        state, as a tuple to splat: ``(rows [pad_to],)``, request ``i``'s row
+        of ``pool["state"]`` (its batch slot + 1, held from admission to finish
+        or preemption) and the null row for the padding. Empty for every
+        other model: its programs take no such argument."""
+        if self.state_shape is None:
+            return ()
+        rows = np.full((pad_to,), NULL_STATE_ROW, np.int32)
+        for i, req in enumerate(requests):
+            rows[i] = req.slot + 1
+        return (rows,)
+
+    def _record_state(self, req: Request, slot: int, why: str, t_ns: int) -> None:
+        """One ``atpu.serve.state`` record for a state row handed out
+        (``admit``) or taken back (``finish``, ``preempt``) with batch slot
+        ``slot``. Nothing for a model without such state."""
+        if self.state_shape is not None:
+            _tracing.record(
+                "atpu.serve.state", t_ns, t_ns, engine=self.engine_id, step=self.steps,
+                rid=int(req.rid), row=slot + 1, why=why)
 
     def _record_counts(self, kind: str, tokens: int, counts, t_ns: int, **key) -> None:
         """One ``atpu.serve.moe`` record for one call of a model whose paged
@@ -980,6 +1059,7 @@ class ServingEngine:
                 positions[i] = req.prefix_len - 1
                 keys[i] = self._request_key(req)
                 token_idx[i] = len(req.generated)
+            rows = self._state_rows(running, Bb)
         # gate on the requests' own contexts, not the local arming state (a
         # ProcessReplica child traces whenever the router propagated a ctx) —
         # and only for SAMPLED traces: per-token decode spans are the bulk of
@@ -994,7 +1074,7 @@ class ServingEngine:
         fn = self._aot.get(("decode", Bb, W), self.decode_fn)
         with self._phase("dispatch"):
             self.pool, out = fn(
-                self.params, self.pool, last, tables, positions, keys, token_idx
+                self.params, self.pool, last, tables, positions, keys, token_idx, *rows
             )
         with self._phase("fetch"):  # the host waits for the device here
             toks, counts = jax.device_get(out)  # the step's one fetch
@@ -1225,6 +1305,8 @@ class ServingEngine:
             out["prefill_blocks_window"] = self.prefill_blocks_window
         if self.moe["calls"]:
             out.update({"moe_" + name: total for name, total in self.moe.items()})
+        if self.state_shape is not None:
+            out.update(state_resets=self.state_resets, state_bytes=self.state_bytes)
         if self.spec_tokens > 0:
             out.update(
                 spec_tokens=self.spec_tokens,
@@ -1241,7 +1323,7 @@ class ServingEngine:
                 else 0.0,
                 spec_accept_hist=self.spec_accept_hist.tolist(),
             )
-        if self.prefix_cache:
+        if self._prefix_cache_asked:  # asked for and refused (per-sequence state): 0 saved
             # hit rate over PROMPT tokens: cached / (cached + actually
             # prefilled) — the fraction of prefill work the cache deleted
             total = self.prefix_cached_tokens + self.prefill_tokens

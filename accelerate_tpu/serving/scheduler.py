@@ -16,6 +16,11 @@ in-flight batching loop (Orca/vLLM style):
   persisted, so resume re-prefills the full prefix and continues with
   identical output (the preemption parity test proves it).
 
+A request's batch slot is its own from admission to completion or preemption
+(``_release`` gives slot and blocks back together), so it also names what the
+engine keeps a SEQUENCE rather than a token: for a model with per-sequence
+state, row ``slot + 1`` of the engine's ``pool["state"]`` (``engine.py``).
+
 ``continuous=False`` turns the same machinery into the static-batching
 baseline for the serving benchmark: admission only happens when the engine
 is completely idle (gang admission), and finished sequences' slots are NOT

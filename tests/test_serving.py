@@ -710,6 +710,12 @@ def test_serving_shardings_places_kv_heads_on_tp():
 
     odd = dataclasses.replace(CONFIG, n_heads=3, n_kv_heads=3)
     assert serving_shardings(mesh, odd).spec == P()
+    # it is the pool's rows that are placed: two key heads of 64 share one (`kv_lane_pack`),
+    # and one row does not divide over tp=2 where its two heads would have
+    packed = dataclasses.replace(CONFIG, dim=128, n_heads=2, n_kv_heads=2)
+    assert packed.head_dim == 64 and serving_shardings(mesh, packed).spec == P()
+    four = dataclasses.replace(CONFIG, dim=256, n_heads=4, n_kv_heads=4)
+    assert serving_shardings(mesh, four).spec == P(None, None, None, "tp")
 
 
 def test_zero_recompiles_through_churn_on_multidevice_mesh():
@@ -986,3 +992,88 @@ def test_greedy_outputs_are_bitwise_the_parents(kernel_mode):
     through the XLA path and through both paged kernels in interpret mode."""
     golden = json.load(open(GOLDEN_GREEDY))
     assert fixed_greedy_workload(kernel_mode) == golden[kernel_mode]
+
+
+# ---------------------------------------------------------------------------
+# A second kind of cache (per-sequence state, PR 35) leaves the models without
+# one as they were
+
+
+def _stateless_models():
+    from accelerate_tpu.models.cohere2_moe import Cohere2MoeConfig, init_cohere2_moe
+    from accelerate_tpu.models.mellum import MellumConfig, init_mellum
+
+    return {"llama": (LlamaConfig.tiny(), init_llama),
+            "cohere2_moe": (Cohere2MoeConfig(), init_cohere2_moe),
+            "mellum": (MellumConfig(), init_mellum)}
+
+
+# greedy tokens of three requests (prompts of 50, 9 and 21 from seed 0, 10 new
+# tokens each) as the commit BEFORE the state rows served them, float32 on the CPU
+TOKENS_BEFORE_STATE_ROWS = {
+    "llama": [[201, 131, 472, 376, 20, 134, 334, 30, 114, 11],
+              [276, 182, 276, 182, 220, 254, 139, 324, 182, 376],
+              [294, 212, 383, 294, 212, 383, 294, 449, 294, 332]],
+    "cohere2_moe": [[147, 283, 283, 283, 423, 489, 393, 254, 484, 282],
+                    [481, 295, 312, 444, 60, 221, 350, 196, 434, 356],
+                    [113, 97, 312, 275, 174, 68, 141, 141, 141, 141]],
+    "mellum": [[252, 103, 252, 292, 209, 200, 156, 103, 182, 26],
+               [378, 110, 62, 62, 62, 103, 33, 35, 378, 35],
+               [70, 252, 296, 209, 209, 209, 209, 280, 122, 200]],
+}
+
+
+@pytest.mark.parametrize("name", list(TOKENS_BEFORE_STATE_ROWS))
+def test_models_without_sequence_state_serve_the_same_programs_and_tokens(name):
+    """Their step programs take no state rows and their pool has no state
+    (the lowered text of all six programs was compared with the parent
+    commit's by hand, PR 35: identical); a seeded run emits the parent's
+    tokens bit for bit and compiles the lattice's programs and no other."""
+    cfg, init = _stateless_models()[name]
+    engine = ServingEngine(init(cfg, jax.random.PRNGKey(0)), cfg, num_blocks=65, block_size=8,
+                           max_slots=2, cache_dtype=jnp.float32,
+                           lattice=BucketLattice((2,), (16,), (16, 32)))
+    assert engine.state_shape is None and set(engine.pool) == {"k", "v"}
+    assert engine._state_rows([], 2) == () and engine.prefix_cache is True
+    warmed = engine.warmup()
+    assert warmed == {"prefill_compiles": 2, "decode_compiles": 1, "cow_compiles": 1}
+    rng = np.random.default_rng(0)
+    requests = [engine.submit(rng.integers(0, cfg.vocab_size, n).astype(np.int32), 10)
+                for n in (50, 9, 21)]
+    engine.run()
+    assert [r.generated for r in requests] == TOKENS_BEFORE_STATE_ROWS[name]
+    assert engine.jit_cache_sizes() == warmed
+    stats = engine.stats()
+    assert "state_resets" not in stats and "state_bytes" not in stats
+    assert not [k for _, _, _, k in tracing.recorded("atpu.serve.state")
+                if k["engine"] == engine.engine_id]
+    builds = [k for _, _, _, k in tracing.recorded("atpu.serve.build")
+              if k["engine"] == engine.engine_id]
+    assert builds and all("state_rows" not in b for b in builds)
+
+
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
+def test_route_top_k_defaults_are_bitwise_what_they_were(scoring):
+    """The function as it stood before ``select_bias`` / ``weight_eps``,
+    written out here, against the defaults and against None / 0.0 given."""
+    from functools import partial
+
+    from accelerate_tpu.parallel.moe import route_top_k
+
+    def before(router_kernel, x, top_k, scoring):
+        score = {"sigmoid": jax.nn.sigmoid, "softmax": partial(jax.nn.softmax, axis=-1)}[scoring]
+        logits = jnp.dot(x.astype(jnp.float32), router_kernel.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        scores, experts = jax.lax.top_k(score(logits), top_k)
+        return experts, scores / jnp.sum(scores, axis=-1, keepdims=True)
+
+    kernel = jax.random.normal(jax.random.PRNGKey(1), (64, 32)) / 8
+    x = jax.random.normal(jax.random.PRNGKey(2), (200, 64)).astype(jnp.bfloat16)
+    want_ids, want_weights = jax.jit(partial(before, top_k=4, scoring=scoring))(kernel, x)
+    for kwargs in ({}, {"select_bias": None, "weight_eps": 0.0}):
+        ids, weights = jax.jit(partial(route_top_k, top_k=4, scoring=scoring, **kwargs))(kernel, x)
+        np.testing.assert_array_equal(ids, want_ids)
+        np.testing.assert_array_equal(weights, want_weights)
+    lowered = [jax.jit(partial(fn, top_k=4, scoring=scoring)).lower(kernel, x).as_text()
+               for fn in (before, route_top_k)]
+    assert lowered[0].replace("before", "route_top_k") == lowered[1]  # the same program

@@ -304,3 +304,56 @@ def test_step_program_writes_the_donated_pool_in_place(one_chip, monkeypatch, ki
     layer_kv = 2 * pool["k"].size // POOL_LAYERS * 2  # one layer's K + V, bf16
     assert memory["alias_bytes"] == POOL_LAYERS * layer_kv, memory
     assert memory["temp_bytes"] < layer_kv, (memory, layer_kv)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill512"])
+def test_hybrid_step_program_writes_pool_and_state_in_place_with_heads_packed(
+        one_chip, monkeypatch, program):
+    """The hybrid model's step programs at ``lfm2-24b.agent-sat``'s widths and
+    a reduced depth (conv + dense, attention + routed, conv + routed twice):
+    K/V for the one attention layer only, two key heads of 64 to a 128-lane
+    row, and 2 rows of state a sequence a conv layer; both donated and both
+    aliased whole, the temporaries under the layer's K + V. With the heads
+    apart (rows of 64 lanes) the chip's compiler copies the whole pool into
+    the kernels' padded layout and back in every program (PR 35: 3 GB of
+    temporaries for a 2 GB pool), which is what packing them is for."""
+    from accelerate_tpu.models.lfm2 import Lfm2Config, init_lfm2
+    from accelerate_tpu.telemetry.memory import compiled_memory_analysis
+
+    config = Lfm2Config(  # lfm2-24b's (benchmarks/chip/configs/lfm2-24b.json)
+        vocab_size=65536, dim=2048, n_layers=4, n_heads=32, n_kv_heads=8,
+        layer_types=("conv", "full_attention", "conv", "conv"), num_dense_layers=1,
+        dense_dim=11776, expert_dim=1536, num_experts=64, experts_per_token=4,
+        max_seq_len=4096)
+    assert fa.kv_lane_pack(config.n_kv_heads, config.head_dim) == 2  # two heads of 64 to a row
+    num_blocks, W, rows = 32769, 256, 128  # agent-sat's
+    B, S = (rows, 1) if program == "decode" else (1, 512)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def abstract(make):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, BF16 if jnp.issubdtype(x.dtype, jnp.floating) else x.dtype,
+                sharding=one_chip), jax.eval_shape(make))
+
+    params = abstract(lambda: init_lfm2(config, jax.random.PRNGKey(0)))
+    pool = abstract(lambda: fa.init_block_pool(
+        config, num_blocks, POOL_BLOCK, BF16, state_rows=rows + 1))
+    assert pool["k"].shape == (1, num_blocks, POOL_BLOCK, 4, 128)
+    assert pool["state"].shape == (3, rows + 1, 2, 2048)
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def step(params, pool, ids, tables, positions, state_rows):
+        logits, pool, counts = config.paged_forward(
+            params, ids, pool, tables, positions, jnp.ones(ids.shape, bool), state_rows, POOL_BLOCK)
+        return pool, (logits[:, -1].argmax(-1), counts)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, pool, ints(B, S), ints(B, W), ints(B, S), ints(B)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "moe_gmm" in text
+    assert ("paged_decode" if program == "decode" else "paged_prefill") in text
+    memory = compiled_memory_analysis(compiled)
+    layer_kv = 2 * pool["k"].size * 2  # the one attention layer's K + V, bf16
+    assert memory["alias_bytes"] == layer_kv + pool["state"].size * 2, memory
+    assert memory["temp_bytes"] < layer_kv, (memory, layer_kv)

@@ -118,7 +118,7 @@ PAGES: "dict[str, tuple[str, str, list]]" = {
           ["BlockAllocator", "BlockAllocatorError", "BlockPoolExhausted",
            "PrefixPlan", "PrefixAllocation"]),
          ("accelerate_tpu.ops.flash_attention",
-          ["init_block_pool", "paged_write_attend", "paged_attention",
+          ["init_block_pool", "kv_lane_pack", "paged_write_attend", "paged_attention",
            "paged_attention_gather", "paged_attention_decode",
            "paged_attention_prefill", "prefill_walk_blocks",
            "paged_kernel_mode"]),
