@@ -45,7 +45,7 @@ import numpy as np
 
 from ..ops.flash_attention import paged_write_attend
 from ..parallel.moe import held_expert_ffn, init_held_experts
-from .transformer import _dense_init, layer_norm
+from .transformer import _dense_init, layer_norm, pin_qkv
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -157,13 +157,17 @@ def shared_experts(params, u, n: int):
 def _layer(lp, h, positions, valid, config: Cohere2MoeConfig, layer: int, attend):
     """One layer; ``attend(q, k, v, window) -> [B, S, H, D]`` is how attention
     reaches the keys and values of earlier positions (and stores these).
-    Returns ``(h', counts [3])``, the counts of :func:`held_expert_ffn`."""
+    Returns ``(h', counts [3])``, the counts of :func:`held_expert_ffn`. The
+    three projections' outputs are pinned before the reshape to heads, so that
+    no step program copies their weights into another layout
+    (:func:`transformer.pin_qkv`; ``tests/test_tpu_compile.py`` holds it)."""
     c = config
     B, S, _ = h.shape
     u = layer_norm(h, lp["norm"]["scale"], 0.0, c.norm_eps)
-    q = (u @ lp["wq"]["kernel"]).reshape(B, S, c.n_heads, c.head_dim)
-    k = (u @ lp["wk"]["kernel"]).reshape(B, S, c.n_kv_heads, c.head_dim)
-    v = (u @ lp["wv"]["kernel"]).reshape(B, S, c.n_kv_heads, c.head_dim)
+    q, k, v = pin_qkv(u @ lp["wq"]["kernel"], u @ lp["wk"]["kernel"], u @ lp["wv"]["kernel"])
+    q = q.reshape(B, S, c.n_heads, c.head_dim)
+    k = k.reshape(B, S, c.n_kv_heads, c.head_dim)
+    v = v.reshape(B, S, c.n_kv_heads, c.head_dim)
     window = c.window(layer)
     if window is not None:  # a full layer takes no positions at all
         q, k = rope_interleaved(q, positions, c.rope_theta), rope_interleaved(k, positions, c.rope_theta)

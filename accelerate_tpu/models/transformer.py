@@ -52,6 +52,20 @@ def _proj(entry: dict, x: jax.Array) -> jax.Array:
     return x @ entry["kernel"]
 
 
+def pin_qkv(q, k, v):
+    """The ``wq`` / ``wk`` / ``wv`` projections' outputs ``[B, S, out]``, held
+    together as the dots made them (one ``optimization_barrier``; no array, no
+    number and no gradient changes). Without it the layout that the reshape to
+    heads and the rotary turn prefer travels back through the dot, and the
+    chip's compiler meets it by copying the WEIGHT into a transposed layout
+    in every step program (805 MB a ``mistral-7b`` decode or prefill program,
+    604 MB a ``command-a-plus`` one: PERF.md, PR 36) where the activation is
+    a hundredth of it. ``tests/test_tpu_compile.py`` holds the step programs'
+    temporaries under one layer's ``wq``; ``tests/test_qkv_pin.py`` holds the
+    pin invisible to outputs and gradients."""
+    return jax.lax.optimization_barrier((q, k, v))
+
+
 def _stacked_fp8_meta(n_layers: int):
     """Per-layer fp8 meta stacked on the layer axis, so it rides the same
     ``lax.scan`` as the stacked projection kernels (the test_fp8
@@ -352,12 +366,16 @@ def llama_layer(layer_params: dict, h: jax.Array, positions, cos, sin, config: L
     their counts); ``config`` then needs only ``n_heads``, ``n_kv_heads``,
     ``head_dim`` and ``norm_eps``. A layer whose parameters hold ``q_norm`` and
     ``k_norm`` (one scale over ``head_dim`` each) passes every query and key
-    head through that RMSNorm before the rotary turn (``models/lfm2.py``)."""
+    head through that RMSNorm before the rotary turn (``models/lfm2.py``).
+    The three projections' outputs are pinned (:func:`pin_qkv`) before they
+    are reshaped to heads."""
     B, S, _ = h.shape
     x = rms_norm(h, layer_params["attn_norm"]["scale"], config.norm_eps)
-    q = _proj(layer_params["wq"], x).reshape(B, S, config.n_heads, config.head_dim)
-    k = _proj(layer_params["wk"], x).reshape(B, S, config.n_kv_heads, config.head_dim)
-    v = _proj(layer_params["wv"], x).reshape(B, S, config.n_kv_heads, config.head_dim)
+    q, k, v = pin_qkv(
+        _proj(layer_params["wq"], x), _proj(layer_params["wk"], x), _proj(layer_params["wv"], x))
+    q = q.reshape(B, S, config.n_heads, config.head_dim)
+    k = k.reshape(B, S, config.n_kv_heads, config.head_dim)
+    v = v.reshape(B, S, config.n_kv_heads, config.head_dim)
     if "q_norm" in layer_params:  # the tree's structure, static under jit  # jaxlint: disable=R1
         q = rms_norm(q, layer_params["q_norm"]["scale"], config.norm_eps)
         k = rms_norm(k, layer_params["k_norm"]["scale"], config.norm_eps)

@@ -17,6 +17,7 @@ in this ONE file, for the same reason.
 
 import importlib
 import os
+import re
 
 import pytest
 
@@ -270,11 +271,34 @@ POOL_KINDS = {
 }
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill512"])
-@pytest.mark.parametrize("kind", list(POOL_KINDS))
-def test_step_program_writes_the_donated_pool_in_place(one_chip, monkeypatch, kind, program):
+def _compiled_once(build):
+    """``build(one_chip, monkeypatch, *key)`` once a key: the pool's test and
+    the weights' test read one compiled program (what :func:`_program_facts`
+    keeps of it, not the executable)."""
+    programs = {}
+
+    def cached(one_chip, monkeypatch, *key):
+        if key not in programs:
+            programs[key] = build(one_chip, monkeypatch, *key)
+        return programs[key]
+    return cached
+
+
+def _program_facts(compiled, params, pool, wq_kernel, logits_rows, vocab_size):
     from accelerate_tpu.telemetry.memory import compiled_memory_analysis
 
+    return {
+        "memory": compiled_memory_analysis(compiled), "params": params, "pool": pool,
+        "entry": compiled.as_text().split("\nENTRY ")[1],  # the entry computation's text
+        "wq_bytes": wq_kernel.shape[-2] * wq_kernel.shape[-1] * 2,  # one layer's, bf16
+        "logits_bytes": logits_rows * vocab_size * 2,  # every row the program computes has them
+    }
+
+
+@_compiled_once
+def _pool_step_program(one_chip, monkeypatch, kind, program):
+    """The decode or prefill-chunk program of ``POOL_KINDS[kind]``, compiled
+    with the pool donated: :func:`_program_facts` of it."""
     config, init, num_blocks, W, rows = POOL_KINDS[kind]()
     B, S = (rows, 1) if program == "decode" else (1, 512)
     # the described chip is not attached: the dispatch asks the backend, so say "tpu"
@@ -300,25 +324,60 @@ def test_step_program_writes_the_donated_pool_in_place(one_chip, monkeypatch, ki
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert ("paged_decode" if program == "decode" else "paged_prefill") in text
-    memory = compiled_memory_analysis(compiled)
+    layers = params["layers"]  # one stacked tree, or a tuple of one tree a layer
+    return _program_facts(
+        compiled, params, pool, (layers[0] if isinstance(layers, tuple) else layers)["wq"]["kernel"],
+        B * S, config.vocab_size)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill512"])
+@pytest.mark.parametrize("kind", list(POOL_KINDS))
+def test_step_program_writes_the_donated_pool_in_place(one_chip, monkeypatch, kind, program):
+    facts = _pool_step_program(one_chip, monkeypatch, kind, program)
+    memory, pool = facts["memory"], facts["pool"]
     layer_kv = 2 * pool["k"].size // POOL_LAYERS * 2  # one layer's K + V, bf16
     assert memory["alias_bytes"] == POOL_LAYERS * layer_kv, memory
     assert memory["temp_bytes"] < layer_kv, (memory, layer_kv)
 
 
+# ---- and no step program copies a weight. The layout that the reshape to
+# heads and the rotary turn prefer used to travel back through the q/k/v dots,
+# and the compiler met it by copying `wq` / `wk` / `wv` of every layer into the
+# transposed layout `{0,1}`: 105 MB of temporaries at these four llama layers
+# (494 MB at the cell's sixteen), 275 MB for `cohere2_moe`, under the bound
+# above. The projections' outputs are pinned (`transformer.pin_qkv`), so the
+# temporaries stay under ONE layer's `wq` kernel beside the program's own
+# logits (every row of a chunk has them: 101 MB at `mellum`'s 98 304-wide
+# head) and the entry computation holds no two-dimensional bf16 result of
+# 4 MB or more in that layout. Where the compiler stages a weight through
+# fast memory on its way (`mellum`, the hybrid) the copy is in no temporary
+# and the layout alone shows it.
+
+_TRANSPOSED_2D = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = bf16\[(\d+),(\d+)\]\{0,1[^}]*\} ", re.M)
+
+
+def _assert_no_weight_is_copied(facts):
+    bound = facts["wq_bytes"] + facts["logits_bytes"]
+    assert facts["memory"]["temp_bytes"] < bound, (facts["memory"], bound)
+    large = [m.group(0).strip() for m in _TRANSPOSED_2D.finditer(facts["entry"])
+             if int(m.group(1)) * int(m.group(2)) * 2 >= 4 << 20]
+    assert not large, large
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill512"])
-def test_hybrid_step_program_writes_pool_and_state_in_place_with_heads_packed(
-        one_chip, monkeypatch, program):
+@pytest.mark.parametrize("kind", list(POOL_KINDS))
+def test_step_program_copies_no_projection_weight(one_chip, monkeypatch, kind, program):
+    _assert_no_weight_is_copied(_pool_step_program(one_chip, monkeypatch, kind, program))
+
+
+@_compiled_once
+def _hybrid_step_program(one_chip, monkeypatch, program):
     """The hybrid model's step programs at ``lfm2-24b.agent-sat``'s widths and
     a reduced depth (conv + dense, attention + routed, conv + routed twice):
     K/V for the one attention layer only, two key heads of 64 to a 128-lane
-    row, and 2 rows of state a sequence a conv layer; both donated and both
-    aliased whole, the temporaries under the layer's K + V. With the heads
-    apart (rows of 64 lanes) the chip's compiler copies the whole pool into
-    the kernels' padded layout and back in every program (PR 35: 3 GB of
-    temporaries for a 2 GB pool), which is what packing them is for."""
+    row, and 2 rows of state a sequence a conv layer; both donated:
+    :func:`_program_facts` of the compiled program."""
     from accelerate_tpu.models.lfm2 import Lfm2Config, init_lfm2
-    from accelerate_tpu.telemetry.memory import compiled_memory_analysis
 
     config = Lfm2Config(  # lfm2-24b's (benchmarks/chip/configs/lfm2-24b.json)
         vocab_size=65536, dim=2048, n_layers=4, n_heads=32, n_kv_heads=8,
@@ -353,7 +412,25 @@ def test_hybrid_step_program_writes_pool_and_state_in_place_with_heads_packed(
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "moe_gmm" in text
     assert ("paged_decode" if program == "decode" else "paged_prefill") in text
-    memory = compiled_memory_analysis(compiled)
+    return _program_facts(
+        compiled, params, pool, params["layers"][1]["wq"]["kernel"], B * S, config.vocab_size)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill512"])
+def test_hybrid_step_program_writes_pool_and_state_in_place_with_heads_packed(
+        one_chip, monkeypatch, program):
+    """Both aliased whole, the temporaries under the attention layer's K + V.
+    With the heads apart (rows of 64 lanes) the chip's compiler copies the
+    whole pool into the kernels' padded layout and back in every program (PR
+    35: 3 GB of temporaries for a 2 GB pool), which is what packing them is
+    for."""
+    facts = _hybrid_step_program(one_chip, monkeypatch, program)
+    memory, pool = facts["memory"], facts["pool"]
     layer_kv = 2 * pool["k"].size * 2  # the one attention layer's K + V, bf16
     assert memory["alias_bytes"] == layer_kv + pool["state"].size * 2, memory
     assert memory["temp_bytes"] < layer_kv, (memory, layer_kv)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill512"])
+def test_hybrid_step_program_copies_no_projection_weight(one_chip, monkeypatch, program):
+    _assert_no_weight_is_copied(_hybrid_step_program(one_chip, monkeypatch, program))
