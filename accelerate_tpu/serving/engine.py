@@ -613,7 +613,9 @@ class ServingEngine:
         everything, and inside it the disjoint children ``admit``,
         ``prefill`` (one per admitted request), ``grow``, ``build``,
         ``dispatch``, ``fetch`` and ``emit``; what is left of ``step`` is the
-        watchdog beat, metrics and event emission. ``now`` stands in for the
+        watchdog beat, metrics and event emission. A ``prefill`` holds its
+        own disjoint children, ``prefill_call`` and ``prefill_sync``
+        (:meth:`_prefill_request`). ``now`` stands in for the
         clock in every ``Request`` stamp of this step (simulated time)."""
         self._given_now = now
         with self._phase("step"):
@@ -862,7 +864,13 @@ class ServingEngine:
 
         The whole of it, up to the sampled token's arrival on the host, is
         one ``atpu.serve.prefill`` phase; ``first_token_t`` is read inside
-        it, after that sync."""
+        it, after that sync. Its disjoint children: an
+        ``atpu.serve.prefill_call`` round each program call (a chunk's, keyed
+        ``bucket`` and ``start``; the copy-on-write's, keyed ``cow``), and
+        one ``atpu.serve.prefill_sync`` round the one transfer of the sampled
+        token and the chunks' routing counts. What is left is the host's own
+        preparation: the table, the ids, the request's key (whose first
+        computation is a device program of its own)."""
         prefix = req.output_ids()
         start = int(req.cached_tokens)
         W = self.lattice.prefill_points()[0][1]
@@ -883,16 +891,16 @@ class ServingEngine:
                 req.trace_spans.append(span_prefill)
             if req.cow_block is not None:
                 src, dst = req.cow_block
-                cow_t0 = _tracing.now_ns() if span_prefill is not None else 0
                 fn = self._aot.get(("cow",), self.cow_fn)
-                self.pool = fn(self.pool, np.int32(src), np.int32(dst))
+                with self._phase("prefill_call", cow=True) as call_t0_ns:
+                    self.pool = fn(self.pool, np.int32(src), np.int32(dst))
                 # the copy is issued (ordered before any later pool op): release
                 # the allocator's pin so src can park in the reclaimable pool
                 self.allocator.cow_done(src)
                 req.cow_block = None
                 if span_prefill is not None:
                     req.trace_spans.append(_tracing.make_span(
-                        req.trace, "cow_copy", cow_t0, _tracing.now_ns(),
+                        req.trace, "cow_copy", call_t0_ns, _tracing.now_ns(),
                         parent_id=span_prefill["span_id"], component="engine",
                         src_block=int(src), dst_block=int(dst),
                     ))
@@ -916,23 +924,25 @@ class ServingEngine:
                 chunk = prefix[start : start + size]
                 ids = np.zeros((1, Sb), np.int32)
                 ids[0, : chunk.size] = chunk
-                chunk_t0 = _tracing.now_ns() if span_prefill is not None else 0
                 fn = self._aot.get(("prefill", Sb, W), self.prefill_fn)
-                self.pool, (tok, counts) = fn(
-                    self.params, self.pool, ids, table, np.int32(start),
-                    np.int32(chunk.size - 1), key, token_idx, *rows,
-                )
+                with self._phase("prefill_call", bucket=Sb, start=start) as call_t0_ns:
+                    self.pool, (tok, counts) = fn(
+                        self.params, self.pool, ids, table, np.int32(start),
+                        np.int32(chunk.size - 1), key, token_idx, *rows,
+                    )
                 chunk_counts.append((int(chunk.size), counts))
                 if span_prefill is not None:
                     req.trace_spans.append(_tracing.make_span(
-                        req.trace, "prefill_chunk", chunk_t0, _tracing.now_ns(),
+                        req.trace, "prefill_chunk", call_t0_ns, _tracing.now_ns(),
                         parent_id=span_prefill["span_id"], component="engine",
                         start=int(start), tokens=int(chunk.size), bucket=int(Sb),
                     ))
-            tok = int(tok)  # the sync: the sampled token is on the host from here
+            with self._phase("prefill_sync"):  # the sampled token is on the host from here
+                tok, counts = jax.device_get((tok, [c for _, c in chunk_counts]))
+                tok = int(tok)
             t_ns, t = self._clock()
-            for tokens, counts in chunk_counts:  # computed by now: no wait
-                self._record_counts("prefill", tokens, counts, t_ns, rid=int(req.rid))
+            for (tokens, _), chunk_count in zip(chunk_counts, counts):
+                self._record_counts("prefill", tokens, chunk_count, t_ns, rid=int(req.rid))
             if req.first_token_t is None:
                 req.first_token_t = t
             if span_prefill is not None:
@@ -1060,38 +1070,30 @@ class ServingEngine:
                 keys[i] = self._request_key(req)
                 token_idx[i] = len(req.generated)
             rows = self._state_rows(running, Bb)
-        # gate on the requests' own contexts, not the local arming state (a
-        # ProcessReplica child traces whenever the router propagated a ctx) —
-        # and only for SAMPLED traces: per-token decode spans are the bulk of
-        # a trace's cost, and an unsampled trace keeps only its cheap
-        # structural spans (the router flips sampled on for failover
-        # redispatches, whose forced emission needs the detail)
-        decode_t0 = (
-            _tracing.now_ns()
-            if any(r.trace is not None and r.trace.get("sampled") for r in running)
-            else 0
-        )
         fn = self._aot.get(("decode", Bb, W), self.decode_fn)
-        with self._phase("dispatch"):
+        with self._phase("dispatch") as decode_t0:
             self.pool, out = fn(
                 self.params, self.pool, last, tables, positions, keys, token_idx, *rows
             )
         with self._phase("fetch"):  # the host waits for the device here
             toks, counts = jax.device_get(out)  # the step's one fetch
             toks = np.asarray(toks)
-        with self._phase("emit"):
-            self._record_counts("decode", len(running), counts, _tracing.now_ns())
-            if decode_t0:
-                decode_t1 = _tracing.now_ns()
-                for req in running:
-                    if req.trace is not None and req.trace.get("sampled"):
-                        req.trace_spans.append(_tracing.make_span(
-                            req.trace, "decode_step", decode_t0, decode_t1,
-                            parent_id=req._span_root["span_id"], component="engine",
-                            step=int(self.steps), batch=len(running),
-                            token_idx=len(req.generated),
-                        ))
+        with self._phase("emit") as decode_t1:
+            self._record_counts("decode", len(running), counts, decode_t1)
             for i, req in enumerate(running):
+                # gate on the request's own context, not the local arming state
+                # (a ProcessReplica child traces whenever the router propagated a
+                # ctx), and only for SAMPLED traces: per-token decode spans are the
+                # bulk of a trace's cost, and an unsampled trace keeps only its
+                # cheap structural spans (the router flips sampled on for failover
+                # redispatches, whose forced emission needs the detail)
+                if req.trace is not None and req.trace.get("sampled"):
+                    req.trace_spans.append(_tracing.make_span(
+                        req.trace, "decode_step", decode_t0, decode_t1,
+                        parent_id=req._span_root["span_id"], component="engine",
+                        step=int(self.steps), batch=len(running),
+                        token_idx=len(req.generated),
+                    ))
                 req.generated.append(int(toks[i]))
                 if self.prefix_cache:
                     # this decode wrote position prefix_len-2's token (the last
@@ -1143,18 +1145,15 @@ class ServingEngine:
                 rows[i] = self.allocator.tokens(req.rid) - (req.prefix_len - 1)
             cand = np.zeros((Bb, k + 1), np.int32)
             cand[:, 0] = last
-        decode_t0 = (
-            _tracing.now_ns()
-            if any(r.trace is not None and r.trace.get("sampled") for r in running)
-            else 0
-        )
         dfn = self._aot.get(("draft", Bb, W), self.draft_fn)
         d_last, d_pos, d_idx = last, positions, token_idx
+        decode_t0 = None  # the first draft's dispatch
         for j in range(k):  # one dispatch and one fetch per draft round
-            with self._phase("dispatch"):
+            with self._phase("dispatch") as t0_ns:
                 self.pool, d_tok = dfn(
                     self.draft_params, self.pool, d_last, tables, d_pos, keys, d_idx
                 )
+            decode_t0 = decode_t0 or t0_ns
             with self._phase("fetch"):
                 d_tok = np.asarray(jax.device_get(d_tok)).astype(np.int32)
             cand[:, j + 1] = d_tok
@@ -1166,15 +1165,15 @@ class ServingEngine:
             )
         with self._phase("fetch"):
             sel = np.asarray(jax.device_get(sel))
-        with self._phase("emit"):
-            self._spec_emit(running, finished, sel, cand, rows, token_idx, decode_t0)
+        with self._phase("emit") as decode_t1:
+            self._spec_emit(running, finished, sel, cand, rows, token_idx, decode_t0, decode_t1)
 
-    def _spec_emit(self, running, finished, sel, cand, rows, token_idx, decode_t0) -> None:
+    def _spec_emit(self, running, finished, sel, cand, rows, token_idx, decode_t0,
+                   decode_t1) -> None:
         """The host half of a speculative round: accept, per request, the
         longest candidate prefix the verifier's own selections confirm."""
         k = self.spec_tokens
         emitted = 0
-        accepted_by_req: "list[int]" = []
         for i, req in enumerate(running):
             r_i = int(min(rows[i], k + 1))
             before = req.prefix_len - 1
@@ -1189,7 +1188,13 @@ class ServingEngine:
                     n_acc += 1
                     continue
                 break
-            accepted_by_req.append(n_acc)
+            if req.trace is not None and req.trace.get("sampled"):  # as in _decode_batch
+                req.trace_spans.append(_tracing.make_span(
+                    req.trace, "decode_step", decode_t0, decode_t1,
+                    parent_id=req._span_root["span_id"], component="engine",
+                    step=int(self.steps), batch=len(running),
+                    token_idx=int(token_idx[i]), k_accepted=n_acc,
+                ))
             self.draft_proposed_tokens += max(r_i - 1, 0)
             self.draft_accepted_tokens += n_acc
             self.spec_accept_hist[n_acc] += 1
@@ -1207,17 +1212,6 @@ class ServingEngine:
                     self.allocator.register_full_blocks(
                         req.rid, req.output_ids()[:-1]
                     )
-        if decode_t0:
-            decode_t1 = _tracing.now_ns()
-            for i, req in enumerate(running):
-                if req.trace is not None and req.trace.get("sampled"):
-                    req.trace_spans.append(_tracing.make_span(
-                        req.trace, "decode_step", decode_t0, decode_t1,
-                        parent_id=req._span_root["span_id"], component="engine",
-                        step=int(self.steps), batch=len(running),
-                        token_idx=int(token_idx[i]),
-                        k_accepted=int(accepted_by_req[i]),
-                    ))
         self.decode_tokens += emitted
         self._complete_done(running, finished)
 

@@ -11,17 +11,23 @@ and context-propagation glue that builds it:
   ``name``, monotonic-ns ``t0_ns``/``t1_ns`` (the clock
   :func:`time.monotonic_ns`: the span tree's and the phase ring's clock, and
   the one ``Request``'s stamps are read from. It is NOT the clock a device
-  trace stamps — the profiler has its own, and nothing converts between the
-  two), plus free-form attributes. :func:`span_open` / :func:`span_close` /
+  trace stamps — the profiler has its own; the two meet in the phases,
+  below), plus free-form attributes. :func:`span_open` / :func:`span_close` /
   :func:`make_span` build them; holders (the router request, the engine
   request) accumulate them in a list.
 - **phases** — :func:`phase` brackets a stretch of host code (the serving
-  engine's ``atpu.serve.*`` step phases). It enters a
-  ``jax.profiler.TraceAnnotation`` of the same name, which is the ONE join
-  with a device trace (the profiler stamps the annotation on its own clock,
-  beside the device's operations), and keeps ``(name, t0_ns, t1_ns, key)``
-  in a bounded in-memory ring that :func:`recorded` reads back. Always on:
-  a phase costs two clock reads and one tuple.
+  engine's ``atpu.serve.*`` step phases), and it is written twice: as
+  ``(name, t0_ns, t1_ns, key)`` in a bounded in-memory ring that
+  :func:`recorded` reads back (the monotonic clock), and as a
+  ``jax.profiler.TraceAnnotation`` of the same name whose event stats are
+  the same ``key`` (``engine``, ``step``, ...), which an attached profiler
+  stamps on the device trace's own clock, beside the device's operations.
+  The name and ``step`` join the two records one for one: that is how an
+  idle gap of the device is named by the engine phase it fell in, and how a
+  ring record is placed on the device's clock. Always on: with no profiler
+  attached a phase costs two clock reads, one tuple and an annotation that
+  records nothing (2.8 us on a TPU v5e host; 11 us with a profiler
+  attached).
 - **context propagation** — a :class:`TraceContext` is a 3-field JSON-able
   dict (``trace_id``, ``parent_id``, ``sampled``) that rides the existing
   transports verbatim: the router puts it in the submit payload, the
@@ -171,8 +177,13 @@ def now_ns() -> int:
 # ---------------------------------------------------------------------------
 # phases: host spans in the profiler's trace and in an in-memory ring
 
-#: records the ring keeps (a serve step writes 7 plus one per prefill; 2^16
-#: hold some 8000 steps, a quarter of an hour at 100 ms a step)
+#: records the ring keeps. A decode step writes 7 (``step``, ``admit``,
+#: ``grow``, ``build``, ``dispatch``, ``fetch``, ``emit``; 8 with a routed
+#: model's ``moe``), a prefill 3 (``prefill``, one ``prefill_call``,
+#: ``prefill_sync``) and one more a further chunk or a copy-on-write (and a
+#: routed model's ``moe`` a chunk). 2^16 hold some 8000 steps, a quarter of
+#: an hour at 100 ms a step; ``chat-r80``'s window and drain are about 3500
+#: steps
 RING_MAXLEN = 1 << 16
 _RING: "collections.deque[tuple]" = collections.deque(maxlen=RING_MAXLEN)
 _TraceAnnotation = None  # jax.profiler.TraceAnnotation, bound by the first phase
@@ -349,8 +360,12 @@ def chrome_trace(spans: Iterable[dict]) -> dict:
     microseconds of the monotonic clock, one pid/tid lane per emitting
     component (the ``component`` attr; default the span name's prefix). A
     device trace is on the profiler's clock, so the two files do not line up
-    by timestamp: the ``atpu.*`` annotations of :func:`phase` are what a
-    device trace holds of the host."""
+    by timestamp. What joins them is a :func:`phase`: it is in the ring on
+    this clock and in the device trace on the profiler's, with the same name
+    and ``step``, so a request span is placed on the device's clock through
+    the phase it was read in (a ``prefill_chunk`` opens at its
+    ``atpu.serve.prefill_call``'s start, a ``decode_step`` at its
+    ``dispatch``'s)."""
     trace_events = []
     tids: "dict[str, int]" = {}
     for s in spans:

@@ -244,7 +244,8 @@ def test_engine_records_routing_and_window_blocks_as_counted_by_hand(monkeypatch
     # the prefill kernel's walk, 2 blocks a step, one tile a chunk, a table of 16: the long
     # prompt's chunks at 0, 32 and 64 (a bucket of 16: positions 64-79) end in blocks 3, 7 and
     # 9; a window layer's walk of the last starts at position 64 - 31 = 33, in block 4
-    walks = [key for _, _, _, key in tracing.recorded("atpu.serve.prefill")]
+    walks = [key for name, _, _, key in tracing.recorded("atpu.serve.prefill")
+             if name == "atpu.serve.prefill"]
     assert [(w["walked_blocks"], w["window_walked_blocks"], w["table_blocks"]) for w in walks] == [
         (4 + 8 + 10, 4 + 8 + 6, 3 * 16), (2, 2, 16)]
     assert stats["prefill_blocks_walked"] == 24 and stats["prefill_blocks_table"] == 64

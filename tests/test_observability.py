@@ -1018,9 +1018,14 @@ class TestStampsAndSpansAgree:
             assert by_name["prefill"][-1]["attrs"]["resume"] == (req.preemptions > 0)
             # the span opens at the read its atpu.serve.prefill phase opens at
             phases = [r for r in tracing.recorded("atpu.serve.prefill")
-                      if r[3]["engine"] == engine.engine_id and r[3]["rid"] == req.rid]
+                      if r[0] == "atpu.serve.prefill"
+                      and r[3]["engine"] == engine.engine_id and r[3]["rid"] == req.rid]
             assert [p[1] for p in phases] == [s["t0_ns"] for s in by_name["prefill"]]
+            # and a chunk's at the read its atpu.serve.prefill_call opens at
+            calls = {r[1] for r in tracing.recorded("atpu.serve.prefill_call")
+                     if r[3]["engine"] == engine.engine_id}
             for chunk in by_name["prefill_chunk"]:
+                assert chunk["t0_ns"] in calls
                 assert any(p["t0_ns"] <= chunk["t0_ns"] and chunk["t1_ns"] <= p["t1_ns"]
                            for p in by_name["prefill"])
 

@@ -357,7 +357,8 @@ def test_engine_multi_chunk_prefill_through_interpreted_kernel(monkeypatch):
         assert np.array_equal(np.asarray(ref[0]), req.output_ids()), f"request {i}"
     # the walk's counter (ISSUE 30): each record's blocks are the kernel's own
     # arithmetic for its chunks, and stats() sums the records
-    records = [key for _, _, _, key in tracing.recorded("atpu.serve.prefill")[ring_before:]]
+    records = [key for name, _, _, key in tracing.recorded("atpu.serve.prefill")[ring_before:]
+               if name == "atpu.serve.prefill"]
     by_rid = {r["rid"]: r for r in records if r["engine"] == engine.engine_id}
     N = fa._prefill_group_blocks(8, CONFIG.n_kv_heads, CONFIG.head_dim, jnp.float32, 4)
     first = by_rid[reqs[0].rid]  # 21 tokens: positions 0-15 at bucket 16, 16-20 at bucket 8

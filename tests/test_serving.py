@@ -774,6 +774,7 @@ def test_zero_recompiles_through_churn_on_multidevice_mesh():
 
 PHASE = "atpu.serve."
 CHILDREN = {"admit", "prefill", "grow", "build", "dispatch", "fetch", "emit"}
+PREFILL_CHILDREN = {"prefill_call", "prefill_sync"}  # a `prefill`'s own
 
 
 def _engine_records(engine, prefix=PHASE):
@@ -812,6 +813,8 @@ def test_request_stamps_are_ordered_and_first_token_lies_in_its_own_prefill(para
     assert engine.scheduler.preemption_count >= 1
     prefills = {}
     for name, t0_ns, t1_ns, key in _engine_records(engine, PHASE + "prefill"):
+        if name != PHASE + "prefill":  # its children, `prefill_call` and `prefill_sync`
+            continue
         prefills.setdefault(key["rid"], []).append((t0_ns, t1_ns, key))
     finished = {r[3]["rid"]: r[3] for r in _engine_records(engine, "atpu.request")}
     for req in reqs:
@@ -878,7 +881,9 @@ def test_preempted_request_keeps_its_first_stamps_and_a_given_now_is_every_stamp
 def test_step_phases_are_disjoint_children_of_their_step(params, spec_tokens):
     """Every ``atpu.serve.step`` holds its children: each carries the step's
     number, lies inside it, and overlaps no sibling; a step that decoded has
-    one ``build`` and as many ``fetch`` as ``dispatch`` phases."""
+    one ``build`` and as many ``fetch`` as ``dispatch`` phases. A ``prefill``
+    holds its own likewise: a ``prefill_call`` a chunk, then one
+    ``prefill_sync``."""
     kwargs = dict(spec_tokens=spec_tokens, draft_layers=1) if spec_tokens else {}
     engine = _mixed_engine(params, **kwargs)
     _run_staggered(engine, _prompts(9, (16, 40, 14, 9)), 10)
@@ -889,7 +894,20 @@ def test_step_phases_are_disjoint_children_of_their_step(params, spec_tokens):
     assert sorted(by_step) == list(range(engine.steps))
     for step, spans in by_step.items():
         (whole,) = [s for s in spans if s[0] == "step"]
-        children = sorted((s for s in spans if s[0] != "step"), key=lambda s: s[1])
+        inner = sorted((s for s in spans if s[0] in PREFILL_CHILDREN), key=lambda s: s[1])
+        children = sorted((s for s in spans if s[0] != "step" and s not in inner),
+                          key=lambda s: s[1])
+        for prefill in (c for c in children if c[0] == "prefill"):
+            own = [s for s in inner if prefill[1] <= s[1] and s[2] <= prefill[2]]
+            names = [s[0] for s in own]
+            assert names[-1] == "prefill_sync" and names.count("prefill_sync") == 1
+            assert names.count("prefill_call") >= 1
+            assert [s[3]["start"] for s in own[:-1]] == sorted(s[3]["start"] for s in own[:-1])
+            for a, b in zip(own, own[1:]):
+                assert a[2] <= b[1], (step, a[0], b[0])
+        assert len(inner) == sum(
+            len([s for s in inner if p[1] <= s[1] and s[2] <= p[2]])
+            for p in children if p[0] == "prefill")  # each inside its prefill
         assert {c[0] for c in children} <= CHILDREN and children[0][0] == "admit"
         assert whole[1] <= children[0][1] and children[-1][2] <= whole[2]
         for a, b in zip(children, children[1:]):
@@ -952,6 +970,59 @@ def test_phase_ring_is_bounded_and_two_engines_keep_apart(params, monkeypatch):
         with tracing.phase("atpu.test.raises", engine=-1):
             raise RuntimeError("boom")
     assert tracing.recorded("atpu.test.")[-1][0] == "atpu.test.raises"  # recorded all the same
+
+
+def test_phases_are_in_the_profilers_trace_one_for_one_with_the_ring(params, tmp_path):
+    """Under ``jax.profiler`` every ``atpu.serve.*`` phase of an engine is in
+    the trace too, on the profiler's clock, with ``engine`` and ``step`` as
+    the event's stats: the two records match one for one by (name, step) and
+    their durations agree within 50 us (2-3 us at the median on a quiet CPU;
+    one pair in a hundred may lose the core between the two clock reads, and
+    has 1 ms); in the trace, each prefill's ``prefill_call`` and
+    ``prefill_sync`` lie inside it and apart."""
+    import gc
+    import glob
+
+    from jax.profiler import ProfileData
+
+    engine = _mixed_engine(params)
+    gc.disable()  # a collection between the two reads is the test's noise, not the engine's
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _run_staggered(engine, _prompts(9, (16, 40, 14, 9)), 10)
+    finally:
+        jax.profiler.stop_trace()
+        gc.enable()
+    assert engine.scheduler.preemption_count >= 1  # a resume's prefill among them
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"), recursive=True)
+    traced = {}
+    for plane in ProfileData.from_file(path).planes:
+        for e in (e for line in plane.lines for e in line.events if e.name.startswith(PHASE)):
+            stats = dict(e.stats)
+            if stats["engine"] == engine.engine_id:
+                traced.setdefault((e.name, stats["step"]), []).append(
+                    (e.start_ns, e.start_ns + e.duration_ns))
+    ring = {}
+    for name, t0_ns, t1_ns, key in _engine_records(engine):
+        ring.setdefault((name, key["step"]), []).append(t1_ns - t0_ns)
+    assert traced.keys() == ring.keys() and len(ring) > 5 * engine.steps
+    assert {name for name, _ in ring} >= {PHASE + "prefill_call", PHASE + "prefill_sync"}
+    apart = []
+    for k, durations in ring.items():
+        spans = sorted(traced[k])
+        assert len(spans) == len(durations), k
+        apart += [abs((e - s) - d) for (s, e), d in zip(spans, durations)]
+    assert sum(ns >= 50_000 for ns in apart) <= len(apart) // 100 and max(apart) < 1_000_000
+    for (name, step), prefills in traced.items():
+        if name != PHASE + "prefill":
+            continue
+        inner = sorted(traced.get((PHASE + "prefill_call", step), [])
+                       + traced.get((PHASE + "prefill_sync", step), []))
+        for s, e in prefills:
+            own = [(a, b) for a, b in inner if s <= a and b <= e]
+            assert len(own) >= 2 and own[-1] in traced[(PHASE + "prefill_sync", step)]
+            assert all(b <= a for (_, b), (a, _) in zip(own, own[1:]))
+        assert sum(1 for a, b in inner if any(s <= a and b <= e for s, e in prefills)) == len(inner)
 
 
 GOLDEN_GREEDY = os.path.join(
