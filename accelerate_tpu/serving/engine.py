@@ -204,11 +204,8 @@ class ServingEngine:
         self._prefix_cache_asked = prefix_cache
         prefix_cache = prefix_cache and self.state_shape is None
         self.prefix_cache = prefix_cache
-        self.allocator = BlockAllocator(
-            num_blocks, block_size, prefix_caching=prefix_cache
-        )
         if max_blocks_per_seq is None:
-            max_blocks_per_seq = self.allocator.usable_blocks
+            max_blocks_per_seq = num_blocks - 1  # every block but the null one
         max_prefill_len = max_prefill_len or min(
             config.max_seq_len, max_blocks_per_seq * block_size
         )
@@ -220,6 +217,12 @@ class ServingEngine:
         self.lattice = lattice or BucketLattice.from_limits(
             max_slots, max_blocks_per_seq, max_prefill_len
         )
+        # a sequence's table lives in the allocator's row of its batch slot,
+        # as wide as the widest bucket; the row past the last slot stays null
+        self.allocator = BlockAllocator(
+            num_blocks, block_size, prefix_caching=prefix_cache,
+            max_seqs=max_slots, max_seq_blocks=self.lattice.block_buckets[-1],
+        )
         self.scheduler = Scheduler(
             self.allocator, max_slots,
             continuous=continuous, admit_watermark_blocks=admit_watermark_blocks,
@@ -229,6 +232,14 @@ class ServingEngine:
             max_seq_blocks=self.lattice.block_buckets[-1],
             max_seq_tokens=config.max_seq_len,
         )
+        # a decode batch's inputs, a row a batch slot (the allocator keeps the
+        # block tables the same way): written where they change, read with one
+        # index over the running slots (:meth:`_decode_inputs`). The row past
+        # the last slot is a padding row's, and stays zero
+        self._last = np.zeros((max_slots + 1,), np.int32)  # the token the next step reads
+        self._positions = np.zeros((max_slots + 1,), np.int32)  # that token's position
+        self._keys = np.zeros((max_slots + 1, 2), np.uint32)  # the request's PRNG key
+        self._token_idx = np.zeros((max_slots + 1,), np.int32)  # the next token's fold index
         self.pool = init_block_pool(
             config, num_blocks, block_size, cache_dtype, state_rows=max_slots + 1)
         if mesh is not None:
@@ -367,6 +378,9 @@ class ServingEngine:
         #: kernel's work) and entries of the bucketed tables they were handed
         self.decode_blocks_live = 0
         self.decode_blocks_walked = 0
+        #: block-table entries the allocator wrote into the slots' rows, up to
+        #: the last decode batch's build (``rows_written`` on ``atpu.serve.build``)
+        self.decode_table_entries_written = 0
         #: of the live ones, the blocks a window layer walked (a model with a window)
         self.decode_blocks_window = 0
         #: blocks the paged prefill kernel's grid visits for one full layer of
@@ -870,7 +884,11 @@ class ServingEngine:
         one ``atpu.serve.prefill_sync`` round the one transfer of the sampled
         token and the chunks' routing counts. What is left is the host's own
         preparation: the table, the ids, the request's key (whose first
-        computation is a device program of its own)."""
+        computation is a device program of its own).
+
+        Last, it writes the request's slot of the decode inputs whole (the
+        token, its position, the key, the next fold index): admission is the
+        one event that sets them; each decode step's emit moves them on."""
         prefix = req.output_ids()
         start = int(req.cached_tokens)
         W = self.lattice.prefill_points()[0][1]
@@ -917,7 +935,7 @@ class ServingEngine:
             elif req.generated:
                 self.resume_prefill_tokens += int(prefix.size) - start
             chunk_counts = []  # a model's own counts, one a chunk: [(tokens, counts)]
-            rows = self._state_rows([req], 1)
+            rows = self._state_rows([req.slot], 1)
             if rows and start == 0:  # always: such a model takes no prefix hit
                 self.state_resets += 1
             for start, size, Sb in chunks:
@@ -949,6 +967,12 @@ class ServingEngine:
                 _tracing.span_close(span_prefill, t1_ns=t_ns)
         req.generated.append(tok)
         self.prefill_calls += 1
+        # the slot's decode inputs, whole: its next step reads this token
+        slot = req.slot
+        self._last[slot] = tok
+        self._positions[slot] = req.prefix_len - 1
+        self._keys[slot] = key
+        self._token_idx[slot] = len(req.generated)
 
     def _prefill_plan(self, start: int, end: int, W: int) -> "tuple[list, dict]":
         """The chunks of a prefill of positions ``start..end-1`` as ``(start,
@@ -982,41 +1006,63 @@ class ServingEngine:
         self.prefill_blocks_window += walk.get("window_walked_blocks", 0)
         return chunks, walk
 
-    def _decode_bucket(self, running: "list[Request]") -> "tuple[int, int, dict]":
-        """The lattice point of a decode batch and the blocks its rows hold:
-        ``(slot_bucket, block_bucket, {"live_blocks": ...})``. The paged decode
+    def _decode_bucket(self, slots: np.ndarray) -> "tuple[int, int, dict]":
+        """The lattice point of a decode batch over the running ``slots`` and
+        the blocks its rows hold: ``(slot_bucket, block_bucket, {"live_blocks":
+        ..., "rows_written": ...})``, from the slots' rows alone (the
+        allocator's block counts, the engine's positions). The paged decode
         kernel's work follows ``live_blocks``; ``slot_bucket * block_bucket``
         is the table it is handed (``decode_blocks_live`` /
-        ``decode_blocks_walked`` in :meth:`stats`). For a model with window
-        layers the dict also holds ``window_blocks``: the blocks such a layer
-        walks, a row's from the one that holds position ``kv_len - window``
+        ``decode_blocks_walked`` in :meth:`stats`). ``rows_written``: the
+        table entries the allocator wrote into rows since the last build, a
+        whole table an admission and one entry a block boundary crossed
+        (``decode_table_entries_written``). For a model with window layers
+        the dict also holds ``window_blocks``: the blocks such a layer walks,
+        a row's from the one that holds position ``kv_len - window``
         (``decode_blocks_window`` in :meth:`stats`)."""
-        blocks = [self.allocator.num_seq_blocks(r.rid) for r in running]
-        Bb = self.lattice.slot_bucket(len(running))
-        W = self.lattice.block_bucket(max(blocks))
-        held = {"live_blocks": sum(blocks)}
+        blocks = self.allocator.row_blocks[slots]
+        Bb = self.lattice.slot_bucket(len(slots))
+        W = self.lattice.block_bucket(int(blocks.max()))
+        written = self.allocator.row_entries_written
+        held = {"live_blocks": int(blocks.sum()),
+                "rows_written": written - self.decode_table_entries_written}
+        self.decode_table_entries_written = written
         if self.state_shape is not None:
-            held["state_rows"] = len(running)  # the batch's live rows of `pool["state"]`
+            held["state_rows"] = len(slots)  # the batch's live rows of `pool["state"]`
         self.decode_blocks_live += held["live_blocks"]
         self.decode_blocks_walked += Bb * W
         if self.window is not None:
-            held["window_blocks"] = sum(
-                n - max(r.prefix_len - self.window, 0) // self.block_size
-                for n, r in zip(blocks, running))
+            kv_len = self._positions[slots] + 1
+            held["window_blocks"] = int(
+                (blocks - np.maximum(kv_len - self.window, 0) // self.block_size).sum())
             self.decode_blocks_window += held["window_blocks"]
         return Bb, W, held
 
-    def _state_rows(self, requests, pad_to: int) -> tuple:
+    def _decode_inputs(self, slots: np.ndarray, Bb: int, W: int) -> tuple:
+        """A decode batch's arrays for the running ``slots``, padded to
+        ``Bb`` rows: ``(last, tables [Bb, W], positions, keys, token_idx,
+        *rows)``, one index over the per-slot rows. Who writes them: the
+        allocator the tables (admission, a block boundary, free); the
+        request's prefill token, position, key and fold index
+        (:meth:`_prefill_request`); each step's emit the next token, and
+        the position and fold index forward (:meth:`_decode_batch`,
+        :meth:`_spec_emit`). A padding row reads the row past the last slot:
+        token 0, the null table, position 0, a zero key."""
+        idx = np.full((Bb,), self.max_slots)
+        idx[: len(slots)] = slots
+        return (self._last[idx], self.allocator.row_tables[idx, :W], self._positions[idx],
+                self._keys[idx], self._token_idx[idx], *self._state_rows(slots, Bb))
+
+    def _state_rows(self, slots, pad_to: int) -> tuple:
         """The last argument of a step program of a model with per-sequence
-        state, as a tuple to splat: ``(rows [pad_to],)``, request ``i``'s row
-        of ``pool["state"]`` (its batch slot + 1, held from admission to finish
-        or preemption) and the null row for the padding. Empty for every
-        other model: its programs take no such argument."""
+        state, as a tuple to splat: ``(rows [pad_to],)``, the row of
+        ``pool["state"]`` of each of the batch ``slots`` (slot + 1, held from
+        admission to finish or preemption) and the null row for the padding.
+        Empty for every other model: its programs take no such argument."""
         if self.state_shape is None:
             return ()
         rows = np.full((pad_to,), NULL_STATE_ROW, np.int32)
-        for i, req in enumerate(requests):
-            rows[i] = req.slot + 1
+        rows[: len(slots)] = np.asarray(slots) + 1
         return (rows,)
 
     def _record_state(self, req: Request, slot: int, why: str, t_ns: int) -> None:
@@ -1054,31 +1100,27 @@ class ServingEngine:
         self.moe["max_expert_load"] = max(self.moe["max_expert_load"], *load)
 
     def _decode_batch(self, running: "list[Request]", finished: "list[Request]") -> None:
-        Bb, W, held = self._decode_bucket(running)
+        """One decode step for the ``running`` requests (in slot order). Its
+        build reads the batch's arrays off the per-slot rows
+        (:meth:`_decode_inputs`) and touches no request; its emit hands each
+        request its token and writes the slots' next token, position + 1 and
+        fold index + 1 over the whole batch at once."""
+        slots = self.scheduler.running_slots()
+        Bb, W, held = self._decode_bucket(slots)
         with self._phase(
-            "build", batch=len(running), slot_bucket=Bb, block_bucket=W, **held
+            "build", batch=len(slots), slot_bucket=Bb, block_bucket=W, **held
         ):
-            last = np.zeros((Bb,), np.int32)
-            tables = np.full((Bb, W), NULL_BLOCK, np.int32)
-            positions = np.zeros((Bb,), np.int32)
-            keys = np.zeros((Bb, 2), np.uint32)
-            token_idx = np.zeros((Bb,), np.int32)
-            for i, req in enumerate(running):
-                last[i] = req.generated[-1]
-                tables[i] = self.allocator.block_table(req.rid, pad_to=W)
-                positions[i] = req.prefix_len - 1
-                keys[i] = self._request_key(req)
-                token_idx[i] = len(req.generated)
-            rows = self._state_rows(running, Bb)
+            inputs = self._decode_inputs(slots, Bb, W)
         fn = self._aot.get(("decode", Bb, W), self.decode_fn)
         with self._phase("dispatch") as decode_t0:
-            self.pool, out = fn(
-                self.params, self.pool, last, tables, positions, keys, token_idx, *rows
-            )
+            self.pool, out = fn(self.params, self.pool, *inputs)
         with self._phase("fetch"):  # the host waits for the device here
             toks, counts = jax.device_get(out)  # the step's one fetch
             toks = np.asarray(toks)
         with self._phase("emit") as decode_t1:
+            self._last[slots] = toks[: len(slots)]
+            self._positions[slots] += 1
+            self._token_idx[slots] += 1
             self._record_counts("decode", len(running), counts, decode_t1)
             for i, req in enumerate(running):
                 # gate on the request's own context, not the local arming state
@@ -1124,25 +1166,15 @@ class ServingEngine:
         prefix and are position-masked out of every read until the next
         step's scatter overwrites them."""
         k = self.spec_tokens
-        Bb, W, held = self._decode_bucket(running)
+        slots = self.scheduler.running_slots()
+        Bb, W, held = self._decode_bucket(slots)
         with self._phase(
-            "build", batch=len(running), slot_bucket=Bb, block_bucket=W, **held
+            "build", batch=len(slots), slot_bucket=Bb, block_bucket=W, **held
         ):
-            last = np.zeros((Bb,), np.int32)
-            tables = np.full((Bb, W), NULL_BLOCK, np.int32)
-            positions = np.zeros((Bb,), np.int32)
-            keys = np.zeros((Bb, 2), np.uint32)
-            token_idx = np.zeros((Bb,), np.int32)
-            rows = np.ones((Bb,), np.int32)
-            for i, req in enumerate(running):
-                last[i] = req.generated[-1]
-                tables[i] = self.allocator.block_table(req.rid, pad_to=W)
-                positions[i] = req.prefix_len - 1
-                keys[i] = self._request_key(req)
-                token_idx[i] = len(req.generated)
-                # emit at most as many rows as the grow phase reserved KV room
-                # for (clamped by the request's remaining new-token budget)
-                rows[i] = self.allocator.tokens(req.rid) - (req.prefix_len - 1)
+            last, tables, positions, keys, token_idx = self._decode_inputs(slots, Bb, W)
+            # emit at most as many rows as the grow phase reserved KV room
+            # for (clamped by the request's remaining new-token budget)
+            rows = self.allocator.row_tokens[slots] - self._positions[slots]
             cand = np.zeros((Bb, k + 1), np.int32)
             cand[:, 0] = last
         dfn = self._aot.get(("draft", Bb, W), self.draft_fn)
@@ -1166,14 +1198,16 @@ class ServingEngine:
         with self._phase("fetch"):
             sel = np.asarray(jax.device_get(sel))
         with self._phase("emit") as decode_t1:
-            self._spec_emit(running, finished, sel, cand, rows, token_idx, decode_t0, decode_t1)
+            self._spec_emit(running, slots, finished, sel, cand, rows, token_idx,
+                            decode_t0, decode_t1)
 
-    def _spec_emit(self, running, finished, sel, cand, rows, token_idx, decode_t0,
+    def _spec_emit(self, running, slots, finished, sel, cand, rows, token_idx, decode_t0,
                    decode_t1) -> None:
         """The host half of a speculative round: accept, per request, the
-        longest candidate prefix the verifier's own selections confirm."""
+        longest candidate prefix the verifier's own selections confirm, and
+        move the ``slots``' rows past what each emitted."""
         k = self.spec_tokens
-        emitted = 0
+        counts = np.zeros((len(running),), np.int32)  # tokens each row emitted
         for i, req in enumerate(running):
             r_i = int(min(rows[i], k + 1))
             before = req.prefix_len - 1
@@ -1181,13 +1215,14 @@ class ServingEngine:
             for j in range(r_i):
                 tok = int(sel[i, j])
                 req.generated.append(tok)
-                emitted += 1
                 if req.done:
                     break
                 if j + 1 < r_i and int(cand[i, j + 1]) == tok:
                     n_acc += 1
                     continue
                 break
+            counts[i] = req.prefix_len - 1 - before
+            self._last[slots[i]] = req.generated[-1]
             if req.trace is not None and req.trace.get("sampled"):  # as in _decode_batch
                 req.trace_spans.append(_tracing.make_span(
                     req.trace, "decode_step", decode_t0, decode_t1,
@@ -1212,7 +1247,9 @@ class ServingEngine:
                     self.allocator.register_full_blocks(
                         req.rid, req.output_ids()[:-1]
                     )
-        self.decode_tokens += emitted
+        self._positions[slots] += counts
+        self._token_idx[slots] += counts
+        self.decode_tokens += int(counts.sum())
         self._complete_done(running, finished)
 
     def _close_trace(self, req: Request, outcome: str, t1_ns: Optional[int] = None) -> None:
@@ -1289,6 +1326,7 @@ class ServingEngine:
             ),
             "decode_blocks_live": self.decode_blocks_live,
             "decode_blocks_walked": self.decode_blocks_walked,
+            "decode_table_entries_written": self.decode_table_entries_written,
             "prefill_blocks_walked": self.prefill_blocks_walked,
             "prefill_blocks_table": self.prefill_blocks_table,
             **self.jit_cache_sizes(),

@@ -137,9 +137,25 @@ class BlockAllocator:
     cached blocks park in an LRU pool reclaimed on demand before any
     exhaustion error. ``prefix_caching=False`` keeps every legacy code path
     byte-identical (refcounts exist but are always exactly one).
+
+    **Rows.** A sequence's table and counts are a row of three arrays, and
+    are kept nowhere else: :attr:`row_tables` ``[max_seqs + 1,
+    max_seq_blocks]`` (the table, padded with the null block),
+    :attr:`row_blocks` and :attr:`row_tokens`. The allocator writes them and
+    nobody else, at the events that change them: the whole table at
+    :meth:`allocate` / :meth:`allocate_with_prefix` (the copy-on-write's
+    private block included), each new block and the token count at
+    :meth:`append`, the null table back at :meth:`free`. The last row holds
+    no sequence and stays null: a batch's padding reads it. The scheduler
+    gives a sequence its batch slot as its row, so the engine reads a decode
+    batch's tables with one index over the running slots;
+    :attr:`row_entries_written` counts the table entries written. By default
+    there is a row for as many sequences as the pool has blocks, each as
+    wide as the pool (every sequence holds a block no other does).
     """
 
-    def __init__(self, num_blocks: int, block_size: int, *, prefix_caching: bool = False):
+    def __init__(self, num_blocks: int, block_size: int, *, prefix_caching: bool = False,
+                 max_seqs: Optional[int] = None, max_seq_blocks: Optional[int] = None):
         if num_blocks < 2:
             raise ValueError("need >= 2 blocks (block 0 is the reserved null block)")
         if block_size < 1:
@@ -150,8 +166,14 @@ class BlockAllocator:
         # LIFO: lowest ids are handed out first at start, re-frees come back
         # on top. Block 0 is never on the list (reserved null block).
         self._free: "list[int]" = list(range(num_blocks - 1, 0, -1))
-        self._tables: "dict[object, list[int]]" = {}
-        self._tokens: "dict[object, int]" = {}
+        self._row: "dict[object, int]" = {}  # live sequence -> its row
+        max_seqs = max_seqs or self.usable_blocks
+        width = max_seq_blocks or self.usable_blocks
+        self.row_tables = np.full((max_seqs + 1, width), NULL_BLOCK, np.int32)
+        self.row_blocks = np.zeros((max_seqs + 1,), np.int32)
+        self.row_tokens = np.zeros((max_seqs + 1,), np.int32)
+        #: table entries written into rows so far (a row's reset to null not counted)
+        self.row_entries_written = 0
         # prefix-cache state (inert when prefix_caching is False):
         self._ref: "dict[int, int]" = {}  # physical block -> reference count
         self._cached: "dict[bytes, int]" = {}  # chain hash -> physical block
@@ -166,6 +188,38 @@ class BlockAllocator:
         self.prefix_hit_tokens = 0
         self.cow_copies = 0
         self.reclaimed_blocks = 0
+
+    def _free_row(self, row: Optional[int], n_blocks: int) -> int:
+        """The row a new sequence of ``n_blocks`` takes: ``row``, else the
+        lowest free one. Refuses, before anything is allocated, a row that is
+        taken or a table wider than the rows."""
+        if row is None:
+            free = np.flatnonzero(self.row_blocks[:-1] == 0)
+            if not free.size:
+                raise BlockAllocatorError("every row holds a sequence")
+            row = int(free[0])
+        elif self.row_blocks[row]:  # a live sequence holds at least one block
+            raise BlockAllocatorError(f"row {row} already holds a sequence")
+        self._fits(n_blocks)
+        return row
+
+    def _fits(self, n_blocks: int) -> None:
+        if n_blocks > self.row_tables.shape[1]:
+            raise ValueError(
+                f"table of {n_blocks} block(s) does not fit a row of {self.row_tables.shape[1]}")
+
+    def _write_row(self, row: int, blocks: "list[int]", n_tokens: int) -> None:
+        """Append ``blocks`` to the row's table and set its token count."""
+        have = int(self.row_blocks[row])
+        if blocks:
+            self.row_tables[row, have : have + len(blocks)] = blocks
+            self.row_blocks[row] = have + len(blocks)
+            self.row_entries_written += len(blocks)
+        self.row_tokens[row] = n_tokens
+
+    def _table(self, seq_id) -> "list[int]":
+        row = self._row[seq_id]
+        return self.row_tables[row, : self.row_blocks[row]].tolist()
 
     # -- capacity ------------------------------------------------------------
 
@@ -272,13 +326,14 @@ class BlockAllocator:
 
     # -- lifecycle -----------------------------------------------------------
 
-    def allocate(self, seq_id, n_tokens: int) -> "list[int]":
+    def allocate(self, seq_id, n_tokens: int, row: Optional[int] = None) -> "list[int]":
         """Create a sequence holding ``n_tokens`` (its prompt) from fresh
-        blocks only; returns the block table. :class:`BlockPoolExhausted`
-        when the pool can't cover it (nothing is allocated on failure —
-        all-or-nothing). Prefix-aware admission goes through
-        :meth:`allocate_with_prefix` instead."""
-        if seq_id in self._tables:
+        blocks only; returns the block table, written whole into ``row``
+        (the lowest free row when None; class docstring).
+        :class:`BlockPoolExhausted` when the pool can't cover it (nothing is
+        allocated on failure — all-or-nothing). Prefix-aware admission goes
+        through :meth:`allocate_with_prefix` instead."""
+        if seq_id in self._row:
             raise BlockAllocatorError(f"sequence {seq_id!r} already allocated")
         need = self.blocks_for(n_tokens)
         if need > self.available_blocks:
@@ -286,20 +341,24 @@ class BlockAllocator:
                 f"need {need} block(s) for {n_tokens} token(s), "
                 f"only {self.available_blocks} free"
             )
+        row = self._free_row(row, need)
         table = [self._take_block() for _ in range(need)]
         for blk in table:
             self._ref[blk] = 1
-        self._tables[seq_id] = table
-        self._tokens[seq_id] = n_tokens
+        self._row[seq_id] = row
+        self._write_row(row, table, n_tokens)
         self._chain[seq_id] = []
-        return list(table)
+        return table
 
     def allocate_with_prefix(
-        self, seq_id, token_ids, plan: "Optional[PrefixPlan]" = None
+        self, seq_id, token_ids, plan: "Optional[PrefixPlan]" = None,
+        row: Optional[int] = None,
     ) -> PrefixAllocation:
         """Create a sequence for ``token_ids``, mapping the longest cached
         block-aligned prefix into its table (refcount++) and taking fresh
-        blocks only for the uncached tail. All-or-nothing on exhaustion.
+        blocks only for the uncached tail; the table, copy-on-write block
+        and all, is written whole into ``row`` as by :meth:`allocate`.
+        All-or-nothing on exhaustion.
         With caching off this is exactly :meth:`allocate`. ``plan`` skips
         re-hashing when the caller just ran :meth:`plan_prefix` for the SAME
         tokens with no allocator mutation in between (the scheduler's
@@ -307,8 +366,8 @@ class BlockAllocator:
         token_ids = np.asarray(token_ids, np.int32).reshape(-1)
         n = int(token_ids.size)
         if not self.prefix_caching:
-            return PrefixAllocation(self.allocate(seq_id, n), 0, None)
-        if seq_id in self._tables:
+            return PrefixAllocation(self.allocate(seq_id, n, row), 0, None)
+        if seq_id in self._row:
             raise BlockAllocatorError(f"sequence {seq_id!r} already allocated")
         if plan is None:
             plan = self.plan_prefix(token_ids)
@@ -321,6 +380,7 @@ class BlockAllocator:
                 f"({len(plan.matched)} cached), only "
                 f"{self.available_blocks - plan.lru_pinned} available"
             )
+        row = self._free_row(row, self.blocks_for(n))
         for blk in plan.matched:
             self._ref[blk] = self._ref.get(blk, 0) + 1
             self._lru.pop(blk, None)
@@ -341,8 +401,8 @@ class BlockAllocator:
             blk = self._take_block()
             self._ref[blk] = 1
             table.append(blk)
-        self._tables[seq_id] = table
-        self._tokens[seq_id] = n
+        self._row[seq_id] = row
+        self._write_row(row, table, n)
         self._chain[seq_id] = list(plan.hashes)
         # content-index the full blocks of the UNCACHED tail right now, not
         # after prefill: a request admitted later in the SAME engine step can
@@ -377,12 +437,12 @@ class BlockAllocator:
         with caching off. Returns how many blocks were newly indexed."""
         if not self.prefix_caching:
             return 0
-        if seq_id not in self._tables:
+        if seq_id not in self._row:
             raise BlockAllocatorError(
                 f"register on unknown/freed sequence {seq_id!r} (use-after-free?)"
             )
         written = np.asarray(written_token_ids, np.int32).reshape(-1)
-        table = self._tables[seq_id]
+        table = self._table(seq_id)
         chain = self._chain[seq_id]
         n_full = min(int(written.size) // self.block_size, len(table))
         new = 0
@@ -407,7 +467,7 @@ class BlockAllocator:
         """The sequence's registered full-block chain hashes, oldest first —
         the content addresses a KV handoff ships (``serving/disagg.py``).
         Raises on an unknown/freed sequence like every other lookup."""
-        if seq_id not in self._tables:
+        if seq_id not in self._row:
             raise BlockAllocatorError(
                 f"chain_hashes of unknown/freed sequence {seq_id!r} (use-after-free?)"
             )
@@ -439,36 +499,43 @@ class BlockAllocator:
     def append(self, seq_id, n_tokens: int = 1) -> "list[int]":
         """Grow a sequence by ``n_tokens``; allocates new block(s) only when
         the count crosses a block boundary. Returns the block ids newly
-        allocated (often empty). On exhaustion the sequence is left unchanged
-        and :class:`BlockPoolExhausted` propagates — the scheduler preempts."""
-        if seq_id not in self._tables:
+        allocated (often empty), written into the sequence's row with its new
+        token count. On exhaustion the sequence is left unchanged and
+        :class:`BlockPoolExhausted` propagates — the scheduler preempts."""
+        if seq_id not in self._row:
             raise BlockAllocatorError(
                 f"append on unknown/freed sequence {seq_id!r} (use-after-free?)"
             )
-        have = len(self._tables[seq_id])
-        need = self.blocks_for(self._tokens[seq_id] + n_tokens) - have
+        row = self._row[seq_id]
+        n = int(self.row_tokens[row]) + n_tokens
+        have = int(self.row_blocks[row])
+        need = self.blocks_for(n) - have
         if need > self.available_blocks:
             raise BlockPoolExhausted(
                 f"sequence {seq_id!r} needs {need} more block(s), "
                 f"only {self.available_blocks} free"
             )
+        if need > 0:
+            self._fits(have + need)
         new = [self._take_block() for _ in range(max(0, need))]
         for blk in new:
             self._ref[blk] = 1
-        self._tables[seq_id].extend(new)
-        self._tokens[seq_id] += n_tokens
+        self._write_row(row, new, n)
         return new
 
     def free(self, seq_id) -> int:
         """Drop all of a sequence's references; returns how many blocks it
         held. A block is only actually released when its reference count
         hits zero — cached blocks park in the LRU pool (still matchable),
-        unregistered ones return to the free list. Double-free raises
+        unregistered ones return to the free list. The sequence's row goes
+        back to the null table. Double-free raises
         :class:`BlockAllocatorError`."""
-        if seq_id not in self._tables:
+        if seq_id not in self._row:
             raise BlockAllocatorError(f"double free of sequence {seq_id!r}")
-        table = self._tables.pop(seq_id)
-        del self._tokens[seq_id]
+        table = self._table(seq_id)
+        row = self._row.pop(seq_id)
+        self.row_tables[row, : len(table)] = NULL_BLOCK
+        self.row_blocks[row] = self.row_tokens[row] = 0
         self._chain.pop(seq_id, None)
         for blk in reversed(table):  # LIFO: first-allocated reused last
             self._unref(blk)
@@ -478,31 +545,32 @@ class BlockAllocator:
 
     def block_table(self, seq_id, pad_to: Optional[int] = None) -> np.ndarray:
         """The sequence's physical block ids (logical order) as int32,
-        padded with the null block to ``pad_to`` (the bucketed table width)."""
-        if seq_id not in self._tables:
+        padded with the null block to ``pad_to`` (the bucketed table width,
+        at most the rows'): a copy of the front of its row."""
+        if seq_id not in self._row:
             raise BlockAllocatorError(
                 f"block_table of unknown/freed sequence {seq_id!r} (use-after-free?)"
             )
-        table = self._tables[seq_id]
-        width = len(table) if pad_to is None else pad_to
-        if len(table) > width:
-            raise ValueError(f"table of {len(table)} block(s) does not fit pad_to={pad_to}")
-        out = np.full((width,), NULL_BLOCK, np.int32)
-        out[: len(table)] = table
-        return out
+        row = self._row[seq_id]
+        n = int(self.row_blocks[row])
+        width = n if pad_to is None else pad_to
+        if n > width or width > self.row_tables.shape[1]:
+            raise ValueError(f"table of {n} block(s) in a row of "
+                             f"{self.row_tables.shape[1]} does not fit pad_to={pad_to}")
+        return self.row_tables[row, :width].copy()
 
     def tokens(self, seq_id) -> int:
-        if seq_id not in self._tokens:
+        if seq_id not in self._row:
             raise BlockAllocatorError(f"tokens of unknown/freed sequence {seq_id!r}")
-        return self._tokens[seq_id]
+        return int(self.row_tokens[self._row[seq_id]])
 
     def num_seq_blocks(self, seq_id) -> int:
-        if seq_id not in self._tables:
+        if seq_id not in self._row:
             raise BlockAllocatorError(f"blocks of unknown/freed sequence {seq_id!r}")
-        return len(self._tables[seq_id])
+        return int(self.row_blocks[self._row[seq_id]])
 
     def live_sequences(self) -> "list":
-        return list(self._tables)
+        return list(self._row)
 
     def occupancy(self) -> float:
         """Fraction of usable blocks currently allocated."""
@@ -517,7 +585,7 @@ class BlockAllocator:
         allocated_slots = self.used_blocks * self.block_size
         if not allocated_slots:
             return 0.0
-        live_tokens = sum(self._tokens.values())
+        live_tokens = int(self.row_tokens.sum())
         return max(0.0, (allocated_slots - live_tokens) / allocated_slots)
 
     def shared_blocks(self) -> int:
@@ -530,8 +598,8 @@ class BlockAllocator:
             "usable_blocks": self.usable_blocks,
             "free_blocks": self.free_blocks,
             "used_blocks": self.used_blocks,
-            "sequences": len(self._tables),
-            "live_tokens": sum(self._tokens.values()),
+            "sequences": len(self._row),
+            "live_tokens": int(self.row_tokens.sum()),
             "occupancy": round(self.occupancy(), 6),
             "fragmentation": round(self.fragmentation(), 6),
         }

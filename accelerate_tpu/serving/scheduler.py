@@ -19,7 +19,10 @@ in-flight batching loop (Orca/vLLM style):
 A request's batch slot is its own from admission to completion or preemption
 (``_release`` gives slot and blocks back together), so it also names what the
 engine keeps a SEQUENCE rather than a token: for a model with per-sequence
-state, row ``slot + 1`` of the engine's ``pool["state"]`` (``engine.py``).
+state, row ``slot + 1`` of the engine's ``pool["state"]`` (``engine.py``); for
+every model, row ``slot`` of the allocator's block tables (``kv_pager.py``,
+passed at admission) and of the engine's decode inputs, so a decode batch is
+one index over :meth:`Scheduler.running_slots`.
 
 ``continuous=False`` turns the same machinery into the static-batching
 baseline for the serving benchmark: admission only happens when the engine
@@ -193,6 +196,10 @@ class Scheduler:
     def running(self) -> "list[Request]":
         return [r for r in self.slots if r is not None]
 
+    def running_slots(self) -> np.ndarray:
+        """The batch slots of :meth:`running`'s requests, in the same order."""
+        return np.flatnonzero([r is not None for r in self.slots])
+
     def idle(self) -> bool:
         return not self.queue and not self.running()
 
@@ -274,7 +281,7 @@ class Scheduler:
                 break  # pool pressure: let running sequences drain first
             self.queue.popleft()
             alloc = self.allocator.allocate_with_prefix(
-                req.rid, prefix_tokens, plan=plan
+                req.rid, prefix_tokens, plan=plan, row=slot
             )
             req.cached_tokens = alloc.cached_tokens
             req.cow_block = alloc.cow

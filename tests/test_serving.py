@@ -10,6 +10,7 @@ The two acceptance lines these tests hold:
   jit caches (the telemetry recompile detector is the oracle).
 """
 
+import collections
 import json
 import os
 import time
@@ -124,6 +125,51 @@ def test_allocator_exhaustion_leaves_sequence_unchanged():
     with pytest.raises(BlockPoolExhausted):
         alloc.append("a", 4)  # needs 2 more blocks, 0 free
     assert alloc.tokens("a") == 2 and alloc.num_seq_blocks("a") == 1
+
+
+def test_allocator_keeps_each_sequences_table_in_its_row():
+    """A sequence lives in its row, and nowhere else, from admission to
+    free: the whole table (copy-on-write block included) at allocation, one
+    entry a block boundary, the null table back at free; ``block_table``,
+    ``tokens`` and ``num_seq_blocks`` read the row, a sequence given no row
+    takes the lowest free one, and the row past the last stays null."""
+    alloc = BlockAllocator(num_blocks=17, block_size=4, prefix_caching=True,
+                           max_seqs=3, max_seq_blocks=4)
+    assert alloc.row_tables.shape == (4, 4)
+    toks = np.arange(8, dtype=np.int32)  # two full blocks
+    a = alloc.allocate_with_prefix("a", toks, row=0)
+    assert alloc.row_tables[0].tolist() == a.table + [NULL_BLOCK] * 2
+    assert (alloc.row_blocks[0], alloc.row_tokens[0], alloc.row_entries_written) == (2, 8, 2)
+    b = alloc.allocate_with_prefix("b", toks.copy(), row=2)  # all cached: copy-on-write
+    assert b.cow is not None and b.table[-1] == b.cow[1] != a.table[-1]
+    assert alloc.row_tables[2, :2].tolist() == b.table and alloc.row_entries_written == 4
+    alloc.append("a", 1)  # a third block
+    assert alloc.row_tables[0, :3].tolist() == alloc.block_table("a").tolist()
+    assert alloc.row_entries_written == 5 and alloc.num_seq_blocks("a") == 3
+    alloc.append("a", 1)  # the same block
+    assert (alloc.row_entries_written, alloc.row_tokens[0], alloc.tokens("a")) == (5, 10, 10)
+    assert alloc.block_table("a", pad_to=4).tolist() == alloc.row_tables[0].tolist()
+    with pytest.raises(ValueError, match="pad_to=6"):
+        alloc.block_table("a", pad_to=6)
+    with pytest.raises(BlockAllocatorError, match="row 0"):
+        alloc.allocate("c", 4, row=0)
+    with pytest.raises(ValueError, match="does not fit a row of 4"):
+        alloc.allocate("c", 17, row=1)  # five blocks
+    e = alloc.allocate("e", 16, row=1)
+    with pytest.raises(ValueError, match="does not fit a row of 4"):
+        alloc.append("e", 1)
+    assert "c" not in alloc.live_sequences() and alloc.tokens("e") == 16
+    assert alloc.row_tables[1].tolist() == e and alloc.row_entries_written == 9
+    free_before = alloc.free_blocks
+    alloc.free("a")
+    assert alloc.free_blocks > free_before
+    assert (alloc.row_tables[0] == NULL_BLOCK).all() and alloc.row_blocks[0] == alloc.row_tokens[0] == 0
+    (d,) = alloc.allocate("d", 4)  # no row given: the lowest free one
+    assert alloc.row_tables[0].tolist() == [d] + [NULL_BLOCK] * 3 and alloc.num_seq_blocks("d") == 1
+    assert alloc.row_entries_written == 10
+    with pytest.raises(BlockAllocatorError, match="every row"):
+        alloc.allocate("f", 4)
+    assert (alloc.row_tables[3] == NULL_BLOCK).all() and alloc.row_blocks[3] == 0
 
 
 # ---------------------------------------------------------------------------
@@ -1148,3 +1194,244 @@ def test_route_top_k_defaults_are_bitwise_what_they_were(scoring):
     lowered = [jax.jit(partial(fn, top_k=4, scoring=scoring)).lower(kernel, x).as_text()
                for fn in (before, route_top_k)]
     assert lowered[0].replace("before", "route_top_k") == lowered[1]  # the same program
+
+
+# ---------------------------------------------------------------------------
+# A decode batch's inputs live a row a batch slot: each written at the event
+# that changes it, read with one index over the running slots
+
+
+def _churn_model(name):
+    """(config, float32 params) of a tiny model of each kind the engine serves
+    differently: plain llama, window layers (mellum), per-sequence state (lfm2)."""
+    from accelerate_tpu.models.lfm2 import Lfm2Config, init_lfm2
+    from accelerate_tpu.models.mellum import MellumConfig, init_mellum
+
+    cfg, init = {
+        "llama": (LlamaConfig.tiny(), init_llama),
+        "mellum": (MellumConfig(), init_mellum),
+        "lfm2": (Lfm2Config(vocab_size=256, dim=64, n_layers=6, n_heads=8, n_kv_heads=2,
+                            num_dense_layers=2, conv_taps=3, dense_dim=96, expert_dim=32,
+                            num_experts=16, experts_per_token=4, max_seq_len=128), init_lfm2),
+    }[name]
+    return cfg, init(cfg, jax.random.PRNGKey(0))
+
+
+def _churn(engine, vocab_size):
+    """Staggered admissions, a prompt of four whole blocks sent twice (the
+    second takes the prefix cache's copy-on-write where the model has the
+    cache), rows crossing block boundaries, finishes, and a pool of 11 blocks
+    too small for the batch, so it preempts."""
+    rng = np.random.default_rng(5)
+    shared = rng.integers(0, vocab_size, (32,)).astype(np.int32)
+    prompts = [rng.integers(0, vocab_size, (n,)).astype(np.int32) for n in (16, 40, 14, 9)]
+    reqs = [engine.submit(prompts[0], 12, rng_seed=0), engine.submit(shared, 5, rng_seed=1)]
+    engine.step()
+    reqs.append(engine.submit(shared.copy(), 11, rng_seed=2))
+    for i, p in enumerate(prompts[1:], start=3):
+        engine.step()
+        reqs.append(engine.submit(p, 12, rng_seed=i))
+    engine.run()
+    return reqs
+
+
+# `_churn`'s greedy tokens as the engine emitted them while it rebuilt every
+# decode batch row by row from the requests and the allocator (float32, CPU)
+TOKENS_BEFORE_SLOT_ROWS = {
+    "llama": [[172, 322, 487, 322, 344, 228, 172, 322, 158, 324, 502, 458],
+              [20, 226, 43, 463, 11], [20, 226, 43, 463, 11, 507, 339, 328, 328, 328, 328],
+              [323, 73, 383, 50, 323, 176, 316, 20, 50, 73, 370, 323],
+              [157, 39, 50, 384, 47, 175, 88, 92, 47, 297, 47, 335],
+              [258, 478, 254, 100, 328, 144, 196, 322, 294, 474, 370, 322]],
+    "mellum": [[294, 85, 103, 52, 462, 296, 52, 323, 213, 323, 213, 323],
+               [27, 27, 27, 27, 471], [27, 27, 27, 27, 471, 27, 471, 27, 471, 27, 159],
+               [264, 74, 402, 31, 402, 264, 246, 246, 246, 402, 378, 264],
+               [474, 35, 148, 13, 148, 190, 474, 474, 362, 329, 307, 35],
+               [284, 346, 284, 346, 284, 284, 68, 284, 459, 68, 459, 333]],
+    "lfm2": [[18, 66, 80, 56, 216, 166, 207, 55, 230, 105, 18, 126],
+             [50, 190, 85, 194, 128], [50, 190, 85, 194, 128, 13, 189, 154, 1, 64, 43],
+             [202, 186, 1, 148, 210, 154, 136, 239, 206, 167, 217, 177],
+             [83, 197, 189, 220, 221, 121, 143, 130, 148, 210, 105, 18],
+             [45, 33, 209, 232, 12, 109, 41, 82, 170, 56, 15, 48]],
+}
+
+
+def _rebuilt(engine, Bb, W):
+    """A decode batch's arrays rebuilt row by row from the requests and the
+    allocator's own tables, as the engine built them before it kept them a
+    slot, and the ``atpu.serve.build`` keys such a batch carries."""
+    from accelerate_tpu.serving import NULL_STATE_ROW
+
+    running = engine.scheduler.running()
+    want = dict(last=np.zeros((Bb,), np.int32), tables=np.full((Bb, W), NULL_BLOCK, np.int32),
+                positions=np.zeros((Bb,), np.int32), keys=np.zeros((Bb, 2), np.uint32),
+                token_idx=np.zeros((Bb,), np.int32), rows=np.full((Bb,), NULL_STATE_ROW, np.int32))
+    blocks = []
+    for i, req in enumerate(running):
+        table = engine.allocator.block_table(req.rid)
+        blocks.append(len(table))
+        want["last"][i] = req.generated[-1]
+        want["tables"][i, : len(table)] = table
+        want["positions"][i] = req.prefix_len - 1
+        want["keys"][i] = np.asarray(jax.random.PRNGKey(req.rng_seed), np.uint32)
+        want["token_idx"][i] = len(req.generated)
+        want["rows"][i] = req.slot + 1
+    build = dict(batch=len(running), slot_bucket=Bb, block_bucket=W, live_blocks=sum(blocks))
+    if engine.window is not None:
+        build["window_blocks"] = sum(n - max(r.prefix_len - engine.window, 0) // engine.block_size
+                                     for n, r in zip(blocks, running))
+    if engine.state_shape is not None:
+        build["state_rows"] = len(running)
+    return want, build
+
+
+class _InputsSpy:
+    """Wraps the engine's decode, draft and verify programs: every call's
+    arrays against :func:`_rebuilt` (a draft round after the first hands
+    over the previous round's tokens, positions and fold indices, so only
+    its tables and keys are held), and the build keys each step's batch
+    should carry."""
+
+    def __init__(self, engine):
+        self.engine, self.calls, self.builds, self.first_draft = engine, 0, {}, True
+        self.real = {name: getattr(engine, name)
+                     for name in ("decode_fn", "draft_fn", "verify_fn") if hasattr(engine, name)}
+        for name, fn in self.real.items():
+            setattr(engine, name, self._wrap(name, fn))
+
+    def _wrap(self, name, fn):
+        def call(params, pool, first, tables, positions, keys, token_idx, *rows):
+            self._check(name, first, tables, positions, keys, token_idx, rows)
+            return fn(params, pool, first, tables, positions, keys, token_idx, *rows)
+        return call
+
+    def _check(self, name, first, tables, positions, keys, token_idx, rows):
+        engine = self.engine
+        want, build = _rebuilt(engine, *tables.shape)
+        got = dict(tables=tables, keys=keys)
+        if name != "draft_fn" or self.first_draft:
+            got.update(last=first[:, 0] if name == "verify_fn" else first,
+                       positions=positions, token_idx=token_idx)
+        if rows:
+            got["rows"] = rows[0]
+        for key, value in got.items():
+            assert value.dtype == want[key].dtype, (name, key, engine.steps)
+            np.testing.assert_array_equal(value, want[key], err_msg=f"{name} {key} {engine.steps}")
+        self.first_draft = name == "verify_fn"
+        self.builds[engine.steps] = build
+        self.calls += 1
+
+
+@pytest.mark.parametrize("name", ["llama", "llama-spec", "mellum", "lfm2"])
+def test_decode_inputs_equal_a_per_row_rebuild_through_churn(name):
+    """On every decode, draft and verify call of a run with staggered
+    admissions, block boundaries, a copy-on-write, preemptions and finishes,
+    the arrays the engine hands over equal, element for element and dtype for
+    dtype, those rebuilt row by row from the requests and the allocator; the
+    build records carry the same keys; the tokens are the ones the row-by-row
+    engine emitted (for llama, also a batch-1 ``greedy_generate``'s)."""
+    model = name.split("-")[0]
+    cfg, params = _churn_model(model)
+    spec = dict(spec_tokens=2, draft_layers=1) if name.endswith("spec") else {}
+    engine = ServingEngine(params, cfg, num_blocks=12, block_size=8, max_slots=4,
+                           max_blocks_per_seq=8, cache_dtype=jnp.float32,
+                           lattice=BucketLattice((2, 4), (4, 8), (16, 32)), **spec)
+    warmed = engine.warmup()
+    spy = _InputsSpy(engine)
+    reqs = _churn(engine, cfg.vocab_size)
+    assert [r.generated for r in reqs] == TOKENS_BEFORE_SLOT_ROWS[model]
+    for fn_name, fn in spy.real.items():
+        setattr(engine, fn_name, fn)
+    assert engine.jit_cache_sizes() == warmed
+    stats = engine.stats()
+    assert stats["preemptions"] >= 1 and spy.calls > engine.steps // 2
+    assert stats.get("cow_copies", 0) == (0 if model == "lfm2" else 1)
+    builds = {k["step"]: k for _, _, _, k in _engine_records(engine, PHASE + "build")}
+    assert sorted(builds) == sorted(spy.builds)
+    for step, want in spy.builds.items():
+        assert {k: builds[step][k] for k in want} == want, step
+    if model == "llama":
+        for r in reqs:
+            ref = greedy_generate(params, r.prompt[None], cfg, max_new_tokens=r.max_new_tokens,
+                                  cache_dtype=jnp.float32)
+            assert np.array_equal(np.asarray(ref[0]), r.output_ids()), r.rid
+
+
+@pytest.mark.parametrize("spec_tokens", [0, 2], ids=["decode", "spec-decode"])
+def test_build_reads_no_request_and_copies_no_block_table(params, spec_tokens, monkeypatch):
+    """From the decode bucket's first read to the end of ``build`` the engine
+    reads no attribute of a ``Request`` and calls no ``block_table``: a
+    counted stub round both sees none there, and some elsewhere."""
+    import contextlib
+
+    kwargs = dict(spec_tokens=spec_tokens, draft_layers=1) if spec_tokens else {}
+    engine = _mixed_engine(params, **kwargs)
+    inside = [False]
+    counts = {True: collections.Counter(), False: collections.Counter()}
+    real_getattribute = Request.__getattribute__
+
+    def getattribute(self, name):
+        counts[inside[0]][name] += 1
+        return real_getattribute(self, name)
+
+    real_table, real_bucket, real_phase = (
+        engine.allocator.block_table, engine._decode_bucket, engine._phase)
+
+    def block_table(*args, **kwargs):
+        counts[inside[0]]["block_table"] += 1
+        return real_table(*args, **kwargs)
+
+    def decode_bucket(*args):
+        inside[0] = True
+        return real_bucket(*args)
+
+    @contextlib.contextmanager
+    def phase(name, **key):
+        with real_phase(name, **key) as t0_ns:
+            yield t0_ns
+        if name == "build":
+            inside[0] = False
+
+    engine.allocator.block_table, engine._decode_bucket, engine._phase = (
+        block_table, decode_bucket, phase)
+    monkeypatch.setattr(Request, "__getattribute__", getattribute)
+    _run_staggered(engine, _prompts(11, (16, 40, 14, 9)), 10)
+    monkeypatch.undo()
+    assert engine.stats()["decode_tokens"] > 0 and not inside[0]
+    assert counts[True] == collections.Counter()
+    assert counts[False]["block_table"] >= 4 and counts[False]["generated"] > 0
+
+
+def test_rows_written_counts_an_admissions_whole_table_and_a_block_a_boundary(params):
+    """``rows_written`` on each ``atpu.serve.build`` is the table entries the
+    allocator wrote since the build before: an admission's whole table (a
+    resume's too) and one entry a row that crossed a block boundary;
+    ``stats()["decode_table_entries_written"]`` is their sum."""
+    engine = _mixed_engine(params)
+    alloc = engine.allocator
+    written = []  # (step, entries written, what wrote them)
+    real_allocate, real_append = alloc.allocate_with_prefix, alloc.append
+
+    def allocate(*args, **kwargs):
+        out = real_allocate(*args, **kwargs)
+        written.append((engine.steps, len(out.table), "admit"))
+        return out
+
+    def append(*args, **kwargs):
+        new = real_append(*args, **kwargs)
+        written.append((engine.steps, len(new), "append"))
+        return new
+
+    alloc.allocate_with_prefix, alloc.append = allocate, append
+    _run_staggered(engine, _prompts(11, (16, 40, 14, 9)), 10)
+    assert engine.scheduler.preemption_count >= 1
+    builds = [k for _, _, _, k in _engine_records(engine, PHASE + "build")]
+    before = -1
+    for key in builds:
+        assert key["rows_written"] == sum(n for s, n, _ in written if before < s <= key["step"])
+        before = key["step"]
+    appended = [n for _, n, what in written if what == "append"]
+    assert set(appended) == {0, 1} and max(n for _, n, what in written if what == "admit") >= 5
+    assert sum(k["rows_written"] == 0 for k in builds) >= 1
+    assert engine.stats()["decode_table_entries_written"] == sum(k["rows_written"] for k in builds)
+    assert alloc.row_entries_written == sum(n for _, n, _ in written)
