@@ -691,7 +691,12 @@ def init_block_pool(config, num_blocks: int, block_size: int, dtype=jnp.bfloat16
     :func:`paged_write_attend` alone knows. A model with ``state_shape``
     ``(layers, *row)`` keeps that much a SEQUENCE beside its blocks, whatever
     its length: the pool then also has ``"state": [layers, state_rows, *row]``,
-    one row a sequence, row ``NULL_STATE_ROW`` for the slots that hold none."""
+    one row a sequence, row ``NULL_STATE_ROW`` for the slots that hold none.
+    A block holds ``block_size`` keys and values of one kind or another: a
+    model whose attention pools what lies behind a window (``window_size``,
+    ``chunk_size``) keeps in the same blocks the raw keys of a row's open
+    window and the pooled pairs of its windows, a row then holding pooled
+    entries ahead of raw ones (:func:`pooled_row_view`)."""
     pack = kv_lane_pack(config.n_kv_heads, config.head_dim)
     shape = (getattr(config, "n_kv_layers", config.n_layers), num_blocks, block_size,
              config.n_kv_heads // pack, config.head_dim * pack)
@@ -1243,8 +1248,11 @@ def paged_attention_prefill(
     """Pallas paged chunked-prefill attention: q ``[B, S, H, D]`` (``S > 1``)
     against per-layer pools ``[num_blocks, block_size, Hkv, D]`` through
     ``block_tables [B, W]``, with per-query absolute positions
-    ``q_positions [B, S]``. The engine has already scatter-written the
-    chunk's own KV into the pool, so one walk over a row's block table covers
+    ``q_positions [B, S]`` (a query's index in its row: its position, or where
+    a row holds pooled entries ahead of raw ones, its index in the composed
+    row, :func:`pooled_row_view`; the mask is the same). The engine has
+    already scatter-written the chunk's own KV into the pool, so one walk
+    over a row's block table covers
     both the previously-landed KV and the in-chunk causal part; the per-query
     position mask is what makes the online softmax match the gather
     reference's causal masking. The gathered ``[B, W*block_size]`` cache the
@@ -1450,4 +1458,170 @@ def paged_write_attend(q, k, v, k_pool, v_pool, layer, block_tables, positions,
         q, k_flat, v_flat, base + block_tables, positions, scale, window=window)
     if pack > 1:
         attn = _unpack_heads(attn, pack, Hkv)
+    return attn, k_flat.reshape(shape), v_flat.reshape(shape)
+
+
+# ------------------------------------------------------------ pooled rows
+#
+# A model whose attention reads exact keys inside a window and ONE pooled key /
+# value pair for every `chunk` positions behind it (EVA attention,
+# ``models/evabyte.py``) keeps a row of two kinds in the same pool, the same
+# blocks, under the same allocator. The stored row (``serving/kv_pager.py``
+# writes it) is ``[raw slots][summary blocks of window 0][of window 1]...``:
+# ``window / block_size`` raw slots hold the open window's keys and values (the
+# same slots for every window: a window's blocks are handed back when it
+# closes), and behind them every window up to the open one has ``window /
+# (chunk * block_size)`` summary blocks, one pooled pair a slot. A chunk is one
+# block (``chunk == block_size``), so the chunk a pooled pair stands for is one
+# raw slot. What the paged kernels walk is the COMPOSED row,
+# ``[summary blocks of the closed windows][raw slots]``, which a step program
+# builds from the stored one (:func:`pooled_row_view`): a query's index in it
+# is what the kernels mask by, so the closed windows' pairs and the open
+# window's keys up to the query's own form ONE softmax, and neither kernel
+# knows there are two kinds.
+
+
+def pooled_row_layout(window: int, chunk: int, block_size: int) -> "tuple[int, int]":
+    """``(raw, per_window)`` of a pooled row: its raw slots (one window's
+    positions, ``window / block_size`` blocks) and the summary blocks a window
+    adds behind them (``window / chunk`` pooled pairs, ``block_size`` a block).
+    Refuses a chunk that is not one block and a window that is not whole
+    summary blocks."""
+    if chunk != block_size or window % (chunk * block_size):
+        raise ValueError(
+            f"a pooled row wants chunk == block_size and whole summary blocks a window; "
+            f"got window {window}, chunk {chunk}, block_size {block_size}")
+    return window // block_size, window // (chunk * block_size)
+
+
+def pooled_row_index(positions, window: int, chunk: int):
+    """Where each of ``positions`` (integers or arrays, numpy's or traced)
+    stands in the composed row of its window ``w = position // window``: the
+    ``window / chunk`` pooled pairs of each closed window, then its place in
+    its own window."""
+    w = positions // window
+    return window // chunk * w + positions - window * w
+
+
+def pooled_row_view(block_tables, positions, window: int, chunk: int, block_size: int):
+    """``(composed [B, W], row_positions [B, S])`` from stored pooled rows
+    ``block_tables [B, W]`` and the queries' true ``positions [B, S]`` (a
+    call's queries lie in one window, ``w = positions[:, 0] // window``): the
+    row the paged kernels walk, ``[summary blocks of windows 0..w-1][raw
+    slots]`` and null past them, and each query's index in it, ``(window /
+    chunk) * w + position - window * w`` (:func:`pooled_row_index`). A query
+    sees every pooled pair of the closed windows and its own window's keys up
+    to itself; the open window's summary blocks are not in the composed
+    row."""
+    raw, per_window = pooled_row_layout(window, chunk, block_size)
+    width = block_tables.shape[1]
+    w = positions[:, :1] // window
+    closed = per_window * w                                  # [B, 1] summary blocks before the raw
+    j = jnp.arange(width, dtype=jnp.int32)[None]
+    src = jnp.where(j < closed, raw + j, j - closed)
+    keep = (j < closed) | (j - closed < raw)
+    composed = jnp.where(
+        keep, jnp.take_along_axis(block_tables, jnp.minimum(src, width - 1), axis=1), NULL_BLOCK)
+    return composed.astype(block_tables.dtype), pooled_row_index(positions, window, chunk)
+
+
+def _pooled_fold_kernel(blocks_ref, k_ref, v_ref, mu_ref, phi_ref, kbar_ref, vbar_ref):
+    """One chunk: ``k, v [chunk, Hkv, D]`` as they lie in the pool, folded
+    into ``kbar = sum_j softmax_j(k_j . mu) k_j`` and ``vbar = sum_j
+    softmax_j(k_j . phi) v_j`` per key head, float32 all through."""
+    del blocks_ref  # read by the index maps
+    k = k_ref[0].astype(jnp.float32)
+    v = v_ref[0].astype(jnp.float32)
+
+    def pool(weights, x):
+        s = jnp.sum(k * weights, axis=-1, keepdims=True)          # [chunk, Hkv, 1]
+        p = jnp.exp(s - jnp.max(s, axis=0, keepdims=True))
+        return jnp.sum(p * x, axis=0) / jnp.sum(p, axis=0)         # [Hkv, D]
+
+    kbar_ref[0] = pool(mu_ref[...].astype(jnp.float32), k).astype(kbar_ref.dtype)
+    vbar_ref[0] = pool(phi_ref[...].astype(jnp.float32), v).astype(vbar_ref.dtype)
+
+
+def paged_fold_kernel(k_flat, v_flat, blocks, mu, phi, *, interpret=False):
+    """Pallas fold of the chunks in physical blocks ``blocks [M]`` of the flat
+    pool ``[blocks, block_size, Hkv, D]`` into their pooled pairs ``(kbar,
+    vbar) [M, Hkv, D]`` in the pool's dtype (:func:`_pooled_fold_kernel`),
+    one chunk a grid step, fetched through a BlockSpec whose index map reads
+    ``blocks``; a run of equal ids (the null block of the rows that fold
+    nothing) is fetched once. Named ``eva_fold`` in a device trace."""
+    from jax.experimental import pallas as pl_  # deferred: CPU-only installs
+    from jax.experimental.pallas import tpu as pltpu
+
+    _, block_size, Hkv, D = k_flat.shape
+    M = blocks.shape[0]
+    chunk = pl_.BlockSpec((1, block_size, Hkv, D), lambda i, b: (b[i], 0, 0, 0))
+    head = pl_.BlockSpec((Hkv, D), lambda i, b: (0, 0))
+    out = pl_.BlockSpec((1, Hkv, D), lambda i, b: (i, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(M,), in_specs=[chunk, chunk, head, head],
+        out_specs=[out, out])
+    shape = jax.ShapeDtypeStruct((M, Hkv, D), k_flat.dtype)
+    return pl_.pallas_call(
+        _pooled_fold_kernel, grid_spec=grid_spec, out_shape=[shape, shape],
+        interpret=interpret, name="eva_fold",
+    )(blocks.astype(jnp.int32), k_flat, v_flat, mu, phi)
+
+
+def paged_fold_gather(k_flat, v_flat, blocks, mu, phi):
+    """The XLA twin of :func:`paged_fold_kernel`, and its reference
+    semantics: gather the chunks, the same float32 softmax pooling."""
+    k = k_flat[blocks].astype(jnp.float32)                        # [M, chunk, Hkv, D]
+    v = v_flat[blocks].astype(jnp.float32)
+
+    def pool(weights, x):
+        p = jax.nn.softmax(jnp.sum(k * weights.astype(jnp.float32), axis=-1), axis=1)
+        return jnp.einsum("mjh,mjhd->mhd", p, x)
+
+    return pool(mu, k).astype(k_flat.dtype), pool(phi, v).astype(v_flat.dtype)
+
+
+def paged_write_fold_attend(q, k, v, k_pool, v_pool, layer, block_tables, positions, valid,
+                            mu, phi, *, window: int, chunk: int, block_size: int):
+    """One layer's step of a model with pooled rows against the whole pool:
+    :func:`paged_write_attend` over the composed row (:func:`pooled_row_view`:
+    the keys and values written into the open window's raw slots, the queries
+    attending the closed windows' pooled pairs and their own window's keys),
+    then every chunk whose last position a query of this call holds (``valid``
+    and ``position % chunk == chunk - 1``) folded from its raw block into its
+    pair in the open window's summary blocks, under the named scope
+    ``eva_fold`` (the kernel on the TPU or under
+    ``ACCELERATE_PAGED_KERNEL=interpret``, :func:`paged_fold_gather`
+    elsewhere). A call of ``S`` queries that is a whole number of chunks
+    starts at a chunk's first position (the engine cuts a prefill so), and
+    only its chunks' last queries are looked at; a decode step (``S = 1``)
+    looks at every row. Where a row folds nothing the kernel reads and the
+    scatter writes the layer's null block. ``mu``, ``phi [Hkv, D]``: the
+    layer's pooling vectors. Returns ``(attn, k_pool, v_pool)``."""
+    composed, row_pos = pooled_row_view(block_tables, positions, window, chunk, block_size)
+    attn, k_pool, v_pool = paged_write_attend(
+        q, k, v, k_pool, v_pool, layer, composed, row_pos, block_size)
+    raw, per_window = pooled_row_layout(window, chunk, block_size)
+    shape = k_pool.shape
+    if shape[-1] != k.shape[-1]:
+        raise ValueError("a pooled row wants one key head a row of the pool (kv_lane_pack 1)")
+    with jax.named_scope("eva_fold"):
+        S = positions.shape[1]
+        at = np.arange(chunk - 1, S, chunk) if S % chunk == 0 else np.arange(S)
+        pos, ok = positions[:, at], valid[:, at] & (positions[:, at] % chunk == chunk - 1)
+        w = pos // window
+        s = (pos - window * w) // chunk                              # the chunk's index in its window
+        src = jnp.take_along_axis(block_tables, s, axis=1)          # its raw slot
+        dst = jnp.take_along_axis(block_tables, raw + per_window * w + s // block_size, axis=1)
+        base = layer * shape[1]
+        src = base + jnp.where(ok, src, NULL_BLOCK).reshape(-1)
+        dst = base + jnp.where(ok, dst, NULL_BLOCK).reshape(-1)
+        off = jnp.where(ok, s % block_size, 0).reshape(-1)
+        k_flat, v_flat = k_pool.reshape(-1, *shape[2:]), v_pool.reshape(-1, *shape[2:])
+        interpret = paged_kernel_mode() == "interpret"
+        if interpret or jax.default_backend() == "tpu":
+            kbar, vbar = paged_fold_kernel(k_flat, v_flat, src, mu, phi, interpret=interpret)
+        else:
+            kbar, vbar = paged_fold_gather(k_flat, v_flat, src, mu, phi)
+        k_flat = k_flat.at[dst, off].set(kbar)
+        v_flat = v_flat.at[dst, off].set(vbar)
     return attn, k_flat.reshape(shape), v_flat.reshape(shape)

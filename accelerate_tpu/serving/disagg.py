@@ -323,12 +323,18 @@ class LocalBlockCopyTransport(KVTransport):
 def _refuse_sequence_state(engine: ServingEngine) -> None:
     """A handoff carries a request's K/V blocks and nothing else: a model
     that also keeps per-sequence state rows (``ServingEngine.state_shape``)
-    would land on the decode tier without them."""
+    would land on the decode tier without them, and a pooled row's blocks
+    (``ServingEngine.pooled``) are no prefix's content-addressed blocks."""
     if engine.state_shape is not None:
         raise TypeError(
             f"disaggregated serving hands K/V blocks over, and a "
             f"{type(engine.config).__name__} also keeps per-sequence state rows: "
             "a handoff has no place for them")
+    if engine.pooled is not None:
+        raise TypeError(
+            f"disaggregated serving hands content-addressed K/V blocks over, and a "
+            f"{type(engine.config).__name__} pools what lies behind its window: "
+            "its summaries are no prefix's blocks")
 
 
 class PrefillEngine(ServingEngine):

@@ -46,6 +46,7 @@ from ..ops.flash_attention import (
     NULL_BLOCK,
     NULL_STATE_ROW,
     init_block_pool,
+    pooled_row_index,
     prefill_tiling,
     prefill_walk_blocks,
 )
@@ -142,6 +143,26 @@ class ServingEngine:
     with it copy-on-write; speculative decoding (a rejected candidate would
     already have moved the state); disaggregated serving (a handoff carries
     blocks, not rows); a ``mesh`` (the pool's sharding is written for K/V).
+
+    A model whose attention POOLS what lies behind a window (``config.window_size``
+    and ``config.chunk_size``, ``models/evabyte.py``: exact keys inside the
+    window a query is in, one pooled key/value pair for every ``chunk_size``
+    positions of the closed windows) keeps a row of two kinds in the one pool
+    (``kv_pager.BlockAllocator``'s pooled rows): raw blocks of the open window,
+    handed back when it closes, and summary blocks that the model fills as
+    chunks complete. The step programs take the stored row as they take any
+    other and the model composes what the kernels walk
+    (``ops.flash_attention.pooled_row_view``). The engine cuts a prefill at
+    window boundaries (a call's queries lie in one window), hands back after it
+    what its last window does not need (``allocator.settle``), and records
+    each prefill and decode step as an ``atpu.serve.eva`` record
+    (``chunks_folded``, ``windows_closed``, ``raw_blocks_freed`` since the
+    record before); ``atpu.serve.build`` then carries ``window_entries`` and
+    ``summary_entries`` (the raw keys and the pooled pairs the batch attends)
+    and ``live_blocks`` counts the composed rows'. Refused: a prefix-cache hit
+    (the prefix's closed windows would need their summaries, which the cache
+    does not keep: ``prefix_cache`` is switched off), with it copy-on-write and
+    disaggregated serving, and speculative decoding.
     """
 
     def __init__(
@@ -200,9 +221,14 @@ class ServingEngine:
             raise TypeError(
                 f"a {type(config).__name__} keeps per-sequence state, and the pool's "
                 "sharding over a mesh is written for keys and values only")
-        # a prefix hit skips the prompt's head, and the state is made of it: no hit is taken
+        #: ``(window_size, chunk_size)`` of a model that pools what lies behind a window, or None
+        self.pooled = None
+        if getattr(config, "chunk_size", None) is not None:
+            self.pooled = (int(config.window_size), int(config.chunk_size))
+        # a prefix hit skips the prompt's head, and the state is made of it; a
+        # pooled row's closed windows would need their summaries: no hit is taken
         self._prefix_cache_asked = prefix_cache
-        prefix_cache = prefix_cache and self.state_shape is None
+        prefix_cache = prefix_cache and self.state_shape is None and self.pooled is None
         self.prefix_cache = prefix_cache
         if max_blocks_per_seq is None:
             max_blocks_per_seq = num_blocks - 1  # every block but the null one
@@ -217,11 +243,15 @@ class ServingEngine:
         self.lattice = lattice or BucketLattice.from_limits(
             max_slots, max_blocks_per_seq, max_prefill_len
         )
+        if self.pooled is not None and any(b % self.pooled[1] for b in self.lattice.prefill_buckets):
+            raise ValueError(f"prefill buckets {self.lattice.prefill_buckets} are not whole "
+                             f"chunks of {self.pooled[1]}: a pooled prefill starts every chunk")
         # a sequence's table lives in the allocator's row of its batch slot,
         # as wide as the widest bucket; the row past the last slot stays null
         self.allocator = BlockAllocator(
             num_blocks, block_size, prefix_caching=prefix_cache,
             max_seqs=max_slots, max_seq_blocks=self.lattice.block_buckets[-1],
+            pooled=self.pooled,
         )
         self.scheduler = Scheduler(
             self.allocator, max_slots,
@@ -398,6 +428,14 @@ class ServingEngine:
         #: zeros, and the bytes of ``pool["state"]``
         self.state_resets = 0
         self.state_bytes = int(self.pool["state"].nbytes) if self.state_shape is not None else 0
+        #: a model with pooled rows: chunks folded, over prefills and decode steps;
+        #: the raw keys and pooled pairs the decode batches attended
+        self.eva_chunks_folded = 0
+        self.decode_window_entries = 0
+        self.decode_summary_entries = 0
+        #: the allocator's (windows_closed, raw_blocks_freed) at the last
+        #: ``atpu.serve.eva`` record: each record carries what changed since
+        self._eva_seen = (0, 0)
         #: speculative decoding: draft tokens proposed / accepted, and the
         #: accepted-per-step histogram (index = draft tokens accepted that
         #: slot-step, 0..k) the report's serving section renders
@@ -955,12 +993,16 @@ class ServingEngine:
                         parent_id=span_prefill["span_id"], component="engine",
                         start=int(start), tokens=int(chunk.size), bucket=int(Sb),
                     ))
+            self.allocator.settle(req.rid)  # after the calls that needed it are issued
             with self._phase("prefill_sync"):  # the sampled token is on the host from here
                 tok, counts = jax.device_get((tok, [c for _, c in chunk_counts]))
                 tok = int(tok)
             t_ns, t = self._clock()
             for (tokens, _), chunk_count in zip(chunk_counts, counts):
                 self._record_counts("prefill", tokens, chunk_count, t_ns, rid=int(req.rid))
+            if self.pooled is not None:  # every chunk the prefix completes
+                self._record_eva("prefill", int(prefix.size) // self.pooled[1], t_ns,
+                                 rid=int(req.rid))
             if req.first_token_t is None:
                 req.first_token_t = t
             if span_prefill is not None:
@@ -987,6 +1029,8 @@ class ServingEngine:
         chunks, cap = [], self.lattice.prefill_buckets[-1]
         while start < end:
             size = min(cap, end - start)
+            if self.pooled is not None:  # a call's queries lie in one window
+                size = min(size, self.pooled[0] - start % self.pooled[0])
             chunks.append((start, size, self.lattice.prefill_bucket(size)))
             start += size
         c = self.config
@@ -997,7 +1041,8 @@ class ServingEngine:
             Sq, N = prefill_tiling(
                 Sb, c.n_heads, c.n_kv_heads, c.head_dim, self.block_size, self.pool["k"].dtype, W)
             walk["table_blocks"] += Sb // Sq * W
-            walk["walked_blocks"] += prefill_walk_blocks(at, Sb, Sq, N, W, self.block_size)
+            walk["walked_blocks"] += prefill_walk_blocks(
+                self._row_index(at), Sb, Sq, N, W, self.block_size)
             if self.window is not None:
                 walk["window_walked_blocks"] += prefill_walk_blocks(
                     at, Sb, Sq, N, W, self.block_size, self.window)
@@ -1005,6 +1050,12 @@ class ServingEngine:
         self.prefill_blocks_table += walk["table_blocks"]
         self.prefill_blocks_window += walk.get("window_walked_blocks", 0)
         return chunks, walk
+
+    def _row_index(self, position):
+        """Where position ``position`` stands in the row the paged kernels
+        walk: itself, or its index in a pooled row's composed row
+        (``ops.flash_attention.pooled_row_index``)."""
+        return position if self.pooled is None else pooled_row_index(position, *self.pooled)
 
     def _decode_bucket(self, slots: np.ndarray) -> "tuple[int, int, dict]":
         """The lattice point of a decode batch over the running ``slots`` and
@@ -1019,13 +1070,26 @@ class ServingEngine:
         (``decode_table_entries_written``). For a model with window layers
         the dict also holds ``window_blocks``: the blocks such a layer walks,
         a row's from the one that holds position ``kv_len - window``
-        (``decode_blocks_window`` in :meth:`stats`)."""
+        (``decode_blocks_window`` in :meth:`stats`). For a model with pooled
+        rows it holds ``window_entries`` and ``summary_entries``, the raw keys
+        and the pooled pairs the batch attends, and ``live_blocks`` counts the
+        composed rows' blocks (the stored row's extent picks the bucket)."""
         blocks = self.allocator.row_blocks[slots]
         Bb = self.lattice.slot_bucket(len(slots))
         W = self.lattice.block_bucket(int(blocks.max()))
         written = self.allocator.row_entries_written
         held = {"live_blocks": int(blocks.sum()),
                 "rows_written": written - self.decode_table_entries_written}
+        if self.pooled is not None:
+            window, chunk = self.pooled
+            positions = self._positions[slots]
+            closed = positions // window
+            in_window = positions - window * closed + 1
+            held["window_entries"] = int(in_window.sum())
+            held["summary_entries"] = int((window // chunk * closed).sum())
+            held["live_blocks"] = int(-(-(self._row_index(positions) + 1) // self.block_size).sum())
+            self.decode_window_entries += held["window_entries"]
+            self.decode_summary_entries += held["summary_entries"]
         self.decode_table_entries_written = written
         if self.state_shape is not None:
             held["state_rows"] = len(slots)  # the batch's live rows of `pool["state"]`
@@ -1099,6 +1163,20 @@ class ServingEngine:
         self.moe["calls"] += len(pairs)
         self.moe["max_expert_load"] = max(self.moe["max_expert_load"], *load)
 
+    def _record_eva(self, kind: str, chunks_folded: int, t_ns: int, **key) -> None:
+        """One ``atpu.serve.eva`` record for a ``prefill`` or a ``decode``
+        step of a model with pooled rows: the chunks it folded, and the
+        windows the allocator closed and the raw blocks it handed back since
+        the record before (a decode step's grow closes; a prefill's settle
+        hands back)."""
+        closed, freed = self.allocator.windows_closed, self.allocator.raw_blocks_freed
+        _tracing.record(
+            "atpu.serve.eva", t_ns, t_ns, engine=self.engine_id, step=self.steps, kind=kind,
+            chunks_folded=chunks_folded, windows_closed=closed - self._eva_seen[0],
+            raw_blocks_freed=freed - self._eva_seen[1], **key)
+        self._eva_seen = (closed, freed)
+        self.eva_chunks_folded += chunks_folded
+
     def _decode_batch(self, running: "list[Request]", finished: "list[Request]") -> None:
         """One decode step for the ``running`` requests (in slot order). Its
         build reads the batch's arrays off the per-slot rows
@@ -1118,6 +1196,10 @@ class ServingEngine:
             toks, counts = jax.device_get(out)  # the step's one fetch
             toks = np.asarray(toks)
         with self._phase("emit") as decode_t1:
+            if self.pooled is not None:  # the rows that wrote a chunk's last position
+                chunk = self.pooled[1]
+                folded = int((self._positions[slots] % chunk == chunk - 1).sum())
+                self._record_eva("decode", folded, decode_t1)
             self._last[slots] = toks[: len(slots)]
             self._positions[slots] += 1
             self._token_idx[slots] += 1
@@ -1339,6 +1421,10 @@ class ServingEngine:
             out.update({"moe_" + name: total for name, total in self.moe.items()})
         if self.state_shape is not None:
             out.update(state_resets=self.state_resets, state_bytes=self.state_bytes)
+        if self.pooled is not None:  # beside the allocator's windows_closed / raw_blocks_freed
+            out.update(eva_chunks_folded=self.eva_chunks_folded,
+                       decode_window_entries=self.decode_window_entries,
+                       decode_summary_entries=self.decode_summary_entries)
         if self.spec_tokens > 0:
             out.update(
                 spec_tokens=self.spec_tokens,

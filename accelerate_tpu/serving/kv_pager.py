@@ -52,7 +52,7 @@ from typing import Optional
 
 import numpy as np
 
-from ..ops.flash_attention import NULL_BLOCK
+from ..ops.flash_attention import NULL_BLOCK, pooled_row_layout
 from ..telemetry import metrics as _metrics
 
 __all__ = [
@@ -152,10 +152,32 @@ class BlockAllocator:
     :attr:`row_entries_written` counts the table entries written. By default
     there is a row for as many sequences as the pool has blocks, each as
     wide as the pool (every sequence holds a block no other does).
+
+    **Pooled rows** (``pooled=(window, chunk)``: a model that attends exact
+    keys inside a window and one pooled pair for every ``chunk`` positions
+    behind it, ``models/evabyte.py``). A row then holds blocks of two kinds
+    and may hold null entries: ``window / block_size`` raw slots at its head,
+    the open window's keys and values, and behind them ``window / (chunk *
+    block_size)`` summary blocks a window, the closed windows' and the open
+    one's (``ops.flash_attention.pooled_row_layout``; the step programs
+    compose the row the kernels walk, pooled entries ahead of raw ones). At
+    ``n`` tokens a sequence has ``c = (n - 1) // window`` closed windows and
+    holds ``ceil((n - c * window) / block_size)`` raw blocks and ``(c + 1)``
+    windows' summary blocks; :attr:`row_blocks` is the row's extent (raw
+    slots and summary blocks), not a count of blocks. :meth:`append` closes a
+    window when the count crosses into the next one: its raw blocks go back
+    to the pool (:attr:`raw_blocks_freed`, :attr:`windows_closed`), the next
+    window's summary blocks are appended, and its first raw block is taken.
+    :meth:`allocate` takes what a prefill of ``n`` tokens needs at its peak,
+    a whole window's raw slots once a window closes in it (the prefill reuses
+    them window after window), and :meth:`settle` hands back, after the
+    prefill, what the last window does not need. No prefix caching: a hit
+    would need the summaries of the prefix's closed windows.
     """
 
     def __init__(self, num_blocks: int, block_size: int, *, prefix_caching: bool = False,
-                 max_seqs: Optional[int] = None, max_seq_blocks: Optional[int] = None):
+                 max_seqs: Optional[int] = None, max_seq_blocks: Optional[int] = None,
+                 pooled: "Optional[tuple[int, int]]" = None):
         if num_blocks < 2:
             raise ValueError("need >= 2 blocks (block 0 is the reserved null block)")
         if block_size < 1:
@@ -188,6 +210,18 @@ class BlockAllocator:
         self.prefix_hit_tokens = 0
         self.cow_copies = 0
         self.reclaimed_blocks = 0
+        #: ``(window, raw slots, summary blocks a window)`` of a pooled row, else None
+        self.pooled = None
+        if pooled is not None:
+            if prefix_caching:
+                raise ValueError("a pooled row takes no prefix hit: the prefix's closed "
+                                 "windows would need their summaries")
+            window, chunk = pooled
+            self.pooled = (window, *pooled_row_layout(window, chunk, block_size))
+        #: pooled rows: windows closed by :meth:`append`, raw blocks handed back
+        #: (at a close, and by :meth:`settle` after a prefill)
+        self.windows_closed = 0
+        self.raw_blocks_freed = 0
 
     def _free_row(self, row: Optional[int], n_blocks: int) -> int:
         """The row a new sequence of ``n_blocks`` takes: ``row``, else the
@@ -209,7 +243,8 @@ class BlockAllocator:
                 f"table of {n_blocks} block(s) does not fit a row of {self.row_tables.shape[1]}")
 
     def _write_row(self, row: int, blocks: "list[int]", n_tokens: int) -> None:
-        """Append ``blocks`` to the row's table and set its token count."""
+        """Append ``blocks`` to the row's table and set its token count (a
+        plain row; a pooled row's entries stand by kind, :meth:`_put`)."""
         have = int(self.row_blocks[row])
         if blocks:
             self.row_tables[row, have : have + len(blocks)] = blocks
@@ -251,8 +286,30 @@ class BlockAllocator:
         return self.usable_blocks - self.available_blocks
 
     def blocks_for(self, n_tokens: int) -> int:
-        """Blocks needed to hold ``n_tokens``."""
-        return max(1, -(-n_tokens // self.block_size))
+        """Blocks an allocation of ``n_tokens`` takes: those that hold them,
+        and for a pooled row the peak of their prefill (class docstring)."""
+        if self.pooled is None:
+            return max(1, -(-n_tokens // self.block_size))
+        _, raw, per_window = self.pooled
+        closed = self._closed(n_tokens)
+        return per_window * (closed + 1) + (raw if closed else self._raw_for(n_tokens))
+
+    def row_width(self, n_tokens: int) -> int:
+        """Table entries a sequence of ``n_tokens`` spans: its blocks, and for
+        a pooled row its raw slots and its windows' summary blocks."""
+        if self.pooled is None:
+            return self.blocks_for(n_tokens)
+        _, raw, per_window = self.pooled
+        return raw + per_window * (self._closed(n_tokens) + 1)
+
+    def _closed(self, n_tokens: int) -> int:
+        """A pooled row's closed windows at ``n_tokens``: those behind the
+        window of position ``n_tokens - 1``."""
+        return max(0, n_tokens - 1) // self.pooled[0]
+
+    def _raw_for(self, n_tokens: int) -> int:
+        """A pooled row's raw blocks at ``n_tokens``: its open window's."""
+        return -(-(n_tokens - self._closed(n_tokens) * self.pooled[0]) // self.block_size)
 
     def can_allocate(self, n_tokens: int) -> bool:
         return self.blocks_for(n_tokens) <= self.available_blocks
@@ -332,7 +389,10 @@ class BlockAllocator:
         (the lowest free row when None; class docstring).
         :class:`BlockPoolExhausted` when the pool can't cover it (nothing is
         allocated on failure — all-or-nothing). Prefix-aware admission goes
-        through :meth:`allocate_with_prefix` instead."""
+        through :meth:`allocate_with_prefix` instead. A pooled row (class
+        docstring) is written as the kernels' row is not: summary blocks of
+        every window the prefill meets behind the raw slots, the step
+        programs composing pooled entries ahead of raw ones."""
         if seq_id in self._row:
             raise BlockAllocatorError(f"sequence {seq_id!r} already allocated")
         need = self.blocks_for(n_tokens)
@@ -341,14 +401,48 @@ class BlockAllocator:
                 f"need {need} block(s) for {n_tokens} token(s), "
                 f"only {self.available_blocks} free"
             )
-        row = self._free_row(row, need)
+        row = self._free_row(row, self.row_width(n_tokens))
         table = [self._take_block() for _ in range(need)]
         for blk in table:
             self._ref[blk] = 1
         self._row[seq_id] = row
-        self._write_row(row, table, n_tokens)
+        if self.pooled is None:
+            self._write_row(row, table, n_tokens)
+        else:  # the summary blocks of every window the prefill meets, then its raw slots
+            raw = self.pooled[1]
+            summaries = self.row_width(n_tokens) - raw
+            self._put(row, raw, table[:summaries])
+            self._put(row, 0, table[summaries:])
+            self.row_blocks[row] = raw + summaries
+            self.row_tokens[row] = n_tokens
         self._chain[seq_id] = []
         return table
+
+    def _put(self, row: int, at: int, blocks: "list[int]") -> None:
+        """Write ``blocks`` into entries ``at..`` of a pooled row."""
+        self.row_tables[row, at : at + len(blocks)] = blocks
+        self.row_entries_written += len(blocks)
+
+    def _drop_raw(self, row: int, keep: int) -> int:
+        """Hand back a pooled row's raw blocks from slot ``keep`` on; their
+        entries go back to null. Returns how many."""
+        slots = self.row_tables[row, keep : self.pooled[1]]
+        held = slots[slots != NULL_BLOCK].tolist()
+        slots[:] = NULL_BLOCK
+        for blk in reversed(held):
+            self._unref(blk)
+        self.raw_blocks_freed += len(held)
+        return len(held)
+
+    def settle(self, seq_id) -> int:
+        """After a sequence's prefill: hand back what it needed and its row
+        no longer does (a pooled row's raw slots past its last window's
+        blocks). Returns how many blocks; 0 for a plain row. The prefill's
+        programs are issued before, and run before whatever takes them next."""
+        if self.pooled is None:
+            return 0
+        row = self._row[seq_id]
+        return self._drop_raw(row, self._raw_for(int(self.row_tokens[row])))
 
     def allocate_with_prefix(
         self, seq_id, token_ids, plan: "Optional[PrefixPlan]" = None,
@@ -500,14 +594,17 @@ class BlockAllocator:
         """Grow a sequence by ``n_tokens``; allocates new block(s) only when
         the count crosses a block boundary. Returns the block ids newly
         allocated (often empty), written into the sequence's row with its new
-        token count. On exhaustion the sequence is left unchanged and
-        :class:`BlockPoolExhausted` propagates — the scheduler preempts."""
+        token count; a pooled row closes the windows the count crosses into
+        (:meth:`_append_pooled`). On exhaustion the sequence is left unchanged
+        and :class:`BlockPoolExhausted` propagates — the scheduler preempts."""
         if seq_id not in self._row:
             raise BlockAllocatorError(
                 f"append on unknown/freed sequence {seq_id!r} (use-after-free?)"
             )
         row = self._row[seq_id]
         n = int(self.row_tokens[row]) + n_tokens
+        if self.pooled is not None:
+            return self._append_pooled(seq_id, row, n)
         have = int(self.row_blocks[row])
         need = self.blocks_for(n) - have
         if need > self.available_blocks:
@@ -521,6 +618,38 @@ class BlockAllocator:
         for blk in new:
             self._ref[blk] = 1
         self._write_row(row, new, n)
+        return new
+
+    def _append_pooled(self, seq_id, row: int, n: int) -> "list[int]":
+        """:meth:`append` of a pooled row to ``n`` tokens: close every window
+        it crosses into (hand back the raw blocks, append the summary blocks
+        of the windows it enters), then take the raw blocks ``n`` needs.
+        All or nothing, as :meth:`append`."""
+        _, raw, per_window = self.pooled
+        closes = self._closed(n) - self._closed(int(self.row_tokens[row]))
+        slots = self.row_tables[row, :raw]
+        held = int((slots != NULL_BLOCK).sum())
+        missing = self._raw_for(n) if closes else int((slots[: self._raw_for(n)] == NULL_BLOCK).sum())
+        take = per_window * closes + missing
+        if take - (held if closes else 0) > self.available_blocks:
+            raise BlockPoolExhausted(
+                f"sequence {seq_id!r} needs {take} more block(s), "
+                f"only {self.available_blocks} free")
+        self._fits(self.row_width(n))
+        if closes:
+            self._drop_raw(row, 0)
+            self.windows_closed += closes
+        new = [self._take_block() for _ in range(take)]
+        for blk in new:
+            self._ref[blk] = 1
+        extent = int(self.row_blocks[row])
+        self._put(row, extent, new[: per_window * closes])
+        self.row_blocks[row] = extent + per_window * closes
+        fresh = iter(new[per_window * closes :])
+        for j in np.flatnonzero(slots[: self._raw_for(n)] == NULL_BLOCK):
+            self.row_tables[row, j] = next(fresh)
+            self.row_entries_written += 1
+        self.row_tokens[row] = n
         return new
 
     def free(self, seq_id) -> int:
@@ -537,6 +666,7 @@ class BlockAllocator:
         self.row_tables[row, : len(table)] = NULL_BLOCK
         self.row_blocks[row] = self.row_tokens[row] = 0
         self._chain.pop(seq_id, None)
+        table = [blk for blk in table if blk != NULL_BLOCK]  # a pooled row's empty raw slots
         for blk in reversed(table):  # LIFO: first-allocated reused last
             self._unref(blk)
         return len(table)
@@ -603,6 +733,8 @@ class BlockAllocator:
             "occupancy": round(self.occupancy(), 6),
             "fragmentation": round(self.fragmentation(), 6),
         }
+        if self.pooled is not None:
+            out.update(windows_closed=self.windows_closed, raw_blocks_freed=self.raw_blocks_freed)
         if self.prefix_caching:
             out.update(
                 cached_blocks=len(self._block_hash),

@@ -252,9 +252,10 @@ class Scheduler:
             remaining = max(0, req.max_new_tokens - len(req.generated))
             worst_tokens = req.prefix_len + remaining
             # the block-WIDTH cap charges the full table (shared blocks widen
-            # the gather exactly like private ones); only the pool check is
+            # the gather exactly like private ones, a pooled row's raw slots
+            # and summary blocks are its width); only the pool check is
             # prefix-aware
-            worst = self.allocator.blocks_for(worst_tokens)
+            worst = self.allocator.row_width(worst_tokens)
             reason = None
             if self.max_seq_tokens is not None and worst_tokens > self.max_seq_tokens:
                 reason = (

@@ -195,6 +195,18 @@ def test_paged_kernels_compile_at_128_query_heads_with_and_without_a_window(one_
         assert "paged_prefill" + ("_win" if window else "") in text
 
 
+@pytest.mark.parametrize("chunks", [32, 128], ids=["decode32", "chunk2048"])
+def test_fold_kernel_compiles_for_v5e(one_chip, chunks):
+    """``eva_fold`` at ``evabyte-6.5b``'s widths: a decode batch's 32 rows, a
+    2048-position prefill call's 128 chunks, 32 key heads of 128 a block of 16."""
+    def fold(k, v, blocks, mu, phi):
+        return fa.paged_fold_kernel(k, v, blocks, mu, phi)
+
+    text = _compile(fold, one_chip, ((4601, 16, 32, 128), BF16), ((4601, 16, 32, 128), BF16),
+                    ((chunks,), jnp.int32), ((32, 128), BF16), ((32, 128), BF16))
+    assert "eva_fold" in text
+
+
 @pytest.mark.parametrize("rows", [256, 2048, 4096], ids=["decode32x8", "chunk256x8", "chunk512x8"])
 def test_grouped_matmul_compiles_for_v5e(one_chip, rows):
     gm = importlib.import_module("accelerate_tpu.ops.grouped_matmul")
@@ -262,12 +274,22 @@ def _mellum_cell():
     return config, init_mellum, 14401, 1056, 32  # code-sat's
 
 
+def _evabyte_cell():
+    from accelerate_tpu.models.evabyte import EvaByteConfig, init_evabyte
+
+    config = EvaByteConfig(  # evabyte-6.5b's (benchmarks/chip/configs/evabyte-6.5b.json)
+        vocab_size=320, dim=4096, n_layers=POOL_LAYERS, n_heads=32, ffn_dim=11008,
+        window_size=2048, chunk_size=16, max_seq_len=32768)
+    return config, init_evabyte, 4601, 256, 32  # bytedoc-sat's: pooled rows of 128 + 16 x 8
+
+
 # kind -> () -> (config, init, blocks in the pool, the table's width, decode rows)
 POOL_KINDS = {
     "llama-unrolled": lambda: _llama_cell(True),
     "llama-scanned": lambda: _llama_cell(False),
     "cohere2_moe": _cohere_cell,  # its layers are a Python loop
     "mellum": _mellum_cell,       # likewise, through `llama_layer`
+    "evabyte": _evabyte_cell,     # pooled rows: the composed row, the fold kernel and its scatter
 }
 
 
